@@ -214,7 +214,6 @@ class MIPSIndex:
         quantized = self.precision == "int8"
 
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             n_shards = self.mesh.shape[self.axis_name]
@@ -242,12 +241,12 @@ class MIPSIndex:
             # alongside the rows; the f32 program is untouched
             scale_specs = (P(self.axis_name),) if quantized else ()
             scale_args = (self.item_scales,) if quantized else ()
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 local_topk,
                 mesh=self.mesh,
                 in_specs=(P(), P(self.axis_name, None)) + scale_specs,
                 out_specs=(P(None, self.axis_name), P(None, self.axis_name)),
-                check_rep=False,
+                check_vma=False,
             )
 
             @jax.jit

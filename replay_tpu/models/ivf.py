@@ -506,7 +506,6 @@ def make_search_fn(state: IVFState, k: int):
 
         return search
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = state.n_shards
@@ -550,12 +549,12 @@ def make_search_fn(state: IVFState, k: int):
         payload_arrays = (state.storage,)
         payload_specs = (P(axis, None),)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_search,
         mesh=state.mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(axis)) + payload_specs,
         out_specs=(P(None, axis), P(None, axis)),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

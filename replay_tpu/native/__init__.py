@@ -3,13 +3,18 @@
 The compute path is JAX/XLA; this package holds the runtime pieces the reference
 implements natively (its ragged-column dataloader kernels ride torch's C++ —
 SURVEY.md §2.8). The extension builds on first use with the in-image g++ via a
-direct compiler invocation (no pip); ``gather_pad`` transparently falls back to
-a numpy implementation when the build is unavailable.
+direct compiler invocation (no pip). The artifact's file name carries a hash of
+``ragged.cpp``, so what is loaded was always built from the source in the tree;
+``gather_pad`` falls back to a numpy implementation (and says so at WARNING)
+when the build is unavailable.
 """
 
 from __future__ import annotations
 
+import hashlib
+import importlib.util
 import logging
+import os
 import subprocess
 import sysconfig
 from pathlib import Path
@@ -20,84 +25,65 @@ import numpy as np
 logger = logging.getLogger("replay_tpu")
 
 _HERE = Path(__file__).parent
-_SO_PATH = _HERE / "_ragged.so"
+_SOURCE = _HERE / "ragged.cpp"
 _native = None
-_build_attempted = False
+_build_attempted = False  # one load-or-build attempt per process
 
 
-def _build() -> Optional[object]:
-    """Compile ragged.cpp into an importable extension (idempotent).
+def _artifact_path() -> Path:
+    """``_ragged_<hash>.so``: keyed on the source bytes and the interpreter ABI,
+    so a binary left behind by another version of either is never loaded."""
+    key = _SOURCE.read_bytes() + str(sysconfig.get_config_var("SOABI")).encode()
+    return _HERE / f"_ragged_{hashlib.sha256(key).hexdigest()[:16]}.so"
 
-    Builds into a temp file and replaces atomically so a failed rebuild never
-    destroys a previously working artifact."""
-    global _build_attempted
-    if _build_attempted:
-        return None
-    _build_attempted = True
-    include = sysconfig.get_paths()["include"]
-    staging = _SO_PATH.with_suffix(".building.so")
+
+def _build(target: Path) -> bool:
+    """Compile ragged.cpp into ``target``: built into a per-process staging file
+    and renamed into place, so a concurrent reader never sees a partial binary."""
+    staging = target.with_suffix(f".{os.getpid()}.building")
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-        f"-I{include}",
-        str(_HERE / "ragged.cpp"),
+        f"-I{sysconfig.get_paths()['include']}",
+        str(_SOURCE),
         "-o", str(staging),
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        staging.replace(_SO_PATH)
+        staging.replace(target)
     except (subprocess.SubprocessError, FileNotFoundError, OSError) as error:
-        logger.info("native ragged kernel build failed (%s); using numpy fallback", error)
+        logger.warning("native ragged kernel build failed (%s); using numpy fallback", error)
         staging.unlink(missing_ok=True)
-        return None
-    return _load()
+        return False
+    for stale in _HERE.glob("_ragged*.so"):
+        if stale != target:
+            stale.unlink(missing_ok=True)
+    return True
 
 
-def _load() -> Optional[object]:
-    import importlib.util
-
-    if not _SO_PATH.exists():
-        return None
-    spec = importlib.util.spec_from_file_location("replay_tpu.native._ragged", _SO_PATH)
+def _load(target: Path) -> Optional[object]:
+    spec = importlib.util.spec_from_file_location("replay_tpu.native._ragged", target)
     module = importlib.util.module_from_spec(spec)
     try:
         spec.loader.exec_module(module)
     except ImportError as error:
-        # stale/ABI-incompatible artifact: rebuild (or fall back to numpy)
-        logger.info("stale native kernel (%s); rebuilding", error)
-        _SO_PATH.unlink(missing_ok=True)
+        logger.warning("native ragged kernel failed to load (%s); using numpy fallback", error)
         return None
     return module
 
 
-_rebuild_tried = False
-
-
 def native_available() -> bool:
-    global _native, _rebuild_tried
-    if _native is None:
-        _native = _load() or _build()
-    if (
-        _native is not None
-        and not all(
-            hasattr(_native, name)
-            for name in ("gather_pad_spans_i64", "gather_pad_2d_i64")
-        )
-        and not _rebuild_tried
-    ):
-        # artifact from an older kernel source. Rebuild ONCE so future processes
-        # load the full kernel; THIS process keeps the old module (CPython caches
-        # extension modules by name, a reload would return the stale one) — its
-        # gather_pad still runs native and span calls take the numpy fallback
-        # via the per-function guard.
-        global _build_attempted
-        _rebuild_tried = True
-        _build_attempted = False
-        _build()
+    global _native, _build_attempted
+    if _native is None and not _build_attempted:
+        _build_attempted = True
+        target = _artifact_path()
+        if target.exists() or _build(target):
+            _native = _load(target)
     return _native is not None
 
 
-def _native_has(function_name: str) -> bool:
-    return native_available() and hasattr(_native, function_name)
+def native_artifact() -> Optional[Path]:
+    """Path of the loaded extension, or None on the numpy fallback."""
+    return Path(_native.__file__) if native_available() else None
 
 
 def gather_pad(
@@ -174,7 +160,7 @@ def gather_pad_2d(
     batch = len(indices)
     floating = np.issubdtype(values.dtype, np.floating)
     mask = np.empty((batch, max_len), np.uint8)
-    if _native_has("gather_pad_2d_i64"):
+    if native_available():
         if floating:
             payload = np.ascontiguousarray(values, np.float64).view(np.int64)
             pad_bits = np.float64(pad_value).view(np.int64)
@@ -235,7 +221,7 @@ def gather_pad_spans(
     batch = len(rows)
     floating = np.issubdtype(values.dtype, np.floating)
     mask = np.empty((batch, max_len), np.uint8)
-    if _native_has("gather_pad_spans_i64"):
+    if native_available():
         if floating:
             payload = np.ascontiguousarray(values, np.float64).view(np.int64)
             pad_bits = np.float64(pad_value).view(np.int64)

@@ -84,8 +84,8 @@ def dot_product_attention(
     if use_flash == "tiled":
         # length-tiled kernel: O(L·block) memory, mask computed in-kernel from
         # (causal, padding) — callers skip building the [B, 1, L, L] tensor
+        from replay_tpu.ops.flash_attention import pallas_interpret
         from replay_tpu.ops.flash_tiled import flash_attention_tiled, padding_mask_bias
-        from replay_tpu.ops.flash_attention import fused_attention_available
 
         if padding_mask is None:
             msg = "use_flash='tiled' needs the [B, L] padding_mask"
@@ -98,16 +98,24 @@ def dot_product_attention(
             msg = "use_flash='tiled' cannot honor an additive mask; pass mask=None"
             raise ValueError(msg)
         return flash_attention_tiled(
-            q, k, v, padding_mask_bias(padding_mask), causal,
-            interpret=not fused_attention_available(),
+            q, k, v, padding_mask_bias(padding_mask), causal, interpret=pallas_interpret()
         ).astype(q.dtype)
     if use_flash:
         # pallas fused kernel: no [B, H, L, L] HBM materialization
-        from replay_tpu.ops.flash_attention import flash_attention, fused_attention_available
+        from replay_tpu.ops.flash_attention import (
+            MAX_SINGLE_BLOCK_LENGTH,
+            flash_attention,
+            pallas_interpret,
+        )
 
-        return flash_attention(
-            q, k, v, mask, interpret=not fused_attention_available()
-        ).astype(q.dtype)
+        if q.shape[-2] > MAX_SINGLE_BLOCK_LENGTH:
+            msg = (
+                f"use_flash=True holds one whole [L, L] block in VMEM and the "
+                f"TPU compiler refuses it past L={MAX_SINGLE_BLOCK_LENGTH} "
+                f"(got L={q.shape[-2]}); use use_flash='tiled' for long sequences"
+            )
+            raise ValueError(msg)
+        return flash_attention(q, k, v, mask, interpret=pallas_interpret()).astype(q.dtype)
     scale = 1.0 / jnp.sqrt(jnp.array(q.shape[-1], dtype=q.dtype))
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale + mask.astype(q.dtype)
     weights = nn.softmax(scores, axis=-1)

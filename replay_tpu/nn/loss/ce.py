@@ -47,8 +47,9 @@ class CEFused(CE):
     Bitwise-equivalent math to :class:`CE` up to f32-vs-bf16 softmax precision
     (the fused path accumulates in f32 inside VMEM), but the ``[B, L, I]``
     logits tensor never reaches HBM — the dominant train-step traffic at
-    full-catalog scales. Falls back to interpreter mode off-TPU; prefer it via
-    ``Trainer(loss=CEFused())`` when ``jax.default_backend() == "tpu"``.
+    full-catalog scales. Compiled on the ``"tpu"`` backend; on ``"cpu"`` the
+    Pallas interpreter stands in (logged once at WARNING); any other backend
+    raises unless ``interpret=`` is given (``ops.flash_attention.pallas_interpret``).
 
     Contract: the loss reconstructs logits as ``hidden · get_item_weights()ᵀ``,
     so it matches :class:`CE` only for models whose ``get_logits`` is a
@@ -120,9 +121,9 @@ class CEFused(CE):
             raise ValueError(msg)
 
     def _resolve_interpret(self) -> bool:
-        return (
-            jax.default_backend() != "tpu" if self.interpret is None else self.interpret
-        )
+        from replay_tpu.ops import pallas_interpret
+
+        return pallas_interpret() if self.interpret is None else self.interpret
 
     def _lse(self, hidden2d: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
         """``[N]`` catalog logsumexp — the seam :class:`CEFusedTP` overrides."""

@@ -442,9 +442,9 @@ def _local_rows(array: jnp.ndarray) -> np.ndarray:
 
 
 def _globalize_scalars(mesh: Mesh, tree: Any) -> Any:
-    """Multi-host: promote process-local leaves (e.g. adam's ``count`` scalar,
-    created by ``tx.init`` outside any mesh) to replicated GLOBAL arrays; leaves
-    that already carry a mesh sharding pass through."""
+    """Promote leaves created outside any mesh (e.g. adam's ``count`` scalar
+    from ``tx.init``) to replicated arrays ON the mesh — global arrays when
+    multi-host; leaves that already carry a mesh sharding pass through."""
     replicated = NamedSharding(mesh, P())
 
     def globalize(x):
@@ -749,23 +749,17 @@ class Trainer:
 
         shardings = params_shardings(self.mesh, params, self.sharding_rules)
         params = _place_tree(jax.tree.map(np.asarray, params), shardings)
-        opt_state = self._tx.init(params)
-        if jax.process_count() > 1:
-            opt_state = _globalize_scalars(self.mesh, opt_state)
-            replicated = NamedSharding(self.mesh, P())
-            step, rng, bad_steps = (
-                jax.make_array_from_process_local_data(replicated, np.asarray(v))
-                for v in (jnp.zeros((), jnp.int32), state_rng, jnp.zeros((), jnp.int32))
-            )
-            return TrainState(
-                step=step, params=params, opt_state=opt_state, rng=rng, bad_steps=bad_steps
-            )
+        # every leaf is placed on the mesh, scalars included: a leaf created
+        # outside it carries no mesh in its type, the step's outputs do, and the
+        # second dispatch of each program would retrace and compile again
+        opt_state = _globalize_scalars(self.mesh, self._tx.init(params))
+        replicated = NamedSharding(self.mesh, P())
+        step, rng, bad_steps = (
+            jax.make_array_from_process_local_data(replicated, np.asarray(v))
+            for v in (jnp.zeros((), jnp.int32), state_rng, jnp.zeros((), jnp.int32))
+        )
         return TrainState(
-            step=jnp.zeros((), jnp.int32),
-            params=params,
-            opt_state=opt_state,
-            rng=state_rng,
-            bad_steps=jnp.zeros((), jnp.int32),
+            step=step, params=params, opt_state=opt_state, rng=rng, bad_steps=bad_steps
         )
 
     def _forward_kwargs(self, batch: Batch, **overrides) -> Dict[str, Any]:

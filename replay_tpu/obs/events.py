@@ -158,7 +158,7 @@ class JsonlLogger(RunLogger):
 
     Lines are flushed as written so a crashed run keeps its telemetry. The
     same sink doubles as a raw-record writer (:meth:`log_record`) for driver
-    artifacts like ``BENCH_TPU_SIDECAR.json`` that are single records rather
+    artifacts (a bench record) that are single records rather
     than event streams (``mode="w"``).
 
     Thread-safe: the serve stack emits from client threads (``on_shed``/

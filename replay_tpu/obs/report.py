@@ -27,7 +27,7 @@ per-host step time, the cross-host skew and the straggler index
 (max/median per-host step time). ``on_slo_violation`` events (obs.slo) are
 counted and gated lower-better. ``--compare`` diffs two runs —
 either run may be a run directory, a raw ``events.jsonl``, or a single-record
-bench JSON (``BENCH_*.json`` / ``BENCH_TPU_SIDECAR.json``) — and exits
+bench JSON (one record as a ``bench*.py`` prints it) — and exits
 non-zero when the candidate regresses beyond ``--threshold`` (relative):
 throughput/MFU drops, new retraces, ``peak_memory_bytes`` growth beyond
 ``--memory-threshold``, ``compile_seconds`` growth beyond
@@ -522,7 +522,7 @@ def summarize_events(
             for key in (
                 "metric", "value", "unit", "vs_baseline", "backend", "mfu",
                 "tflops_per_sec", "step_ms", "dispatch_step_ms", "scan_k",
-                "compile_seconds", "device_kind", "source", "stale",
+                "compile_seconds", "platform", "device_kind", "device_count",
                 # the end-to-end Trainer.fit(scan_chunk=...) loop and its
                 # variant flags (a fit measured with a different chunk size or
                 # the feed disabled must not read as the baseline)
@@ -1263,7 +1263,6 @@ def render(summary: Mapping[str, Any]) -> str:
         lines.append(
             f"  bench: {bench.get('metric')} = {bench.get('value')} {bench.get('unit', '')}"
             + (f" (vs_baseline {bench.get('vs_baseline')})" if "vs_baseline" in bench else "")
-            + (" [stale sidecar]" if bench.get("stale") else "")
         )
         if bench.get("fit_samples_per_sec") is not None:
             gap = bench.get("dispatch_gap_closed")

@@ -7,7 +7,7 @@ program. The roofline model (flops ÷ bytes = arithmetic intensity, ceiling =
 min(peak FLOPs, intensity × peak bandwidth)) turns the same two cost-model
 numbers into the *honest* target: "achieved X% of the roofline-predicted
 ceiling". PR 7's memory-wall fix was diagnosed by hand from exactly this
-arithmetic in a doc (BENCH_NOTES.md); this module makes the framework do it
+arithmetic by hand; this module makes the framework do it
 for every compiled program — per-step fit, scan chunk, CompiledInference
 buckets, the CEFused/CEFusedTP heads — from XLA's own ``cost_analysis()``
 (flops, bytes accessed) and ``memory_analysis()`` (argument/output/temp

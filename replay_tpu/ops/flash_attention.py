@@ -17,16 +17,28 @@ the analytic softmax-attention gradients.
 The additive mask stays [B, 1, L, L]; the grid reads the same mask block for
 every head via its index map instead of broadcasting to [B, H, L, L] in HBM.
 
-On non-TPU backends the kernel runs in interpreter mode (tests) — call sites
-should prefer it only when ``jax.default_backend() == "tpu"``.
+The kernel is compiled by Mosaic on the ``"tpu"`` backend. On ``"cpu"`` (the
+test environment) the Pallas interpreter stands in for it and says so once at
+WARNING; on any other backend :func:`pallas_interpret` raises — the interpreter
+is never chosen silently on an accelerator.
 """
 
 from __future__ import annotations
 
-from functools import partial
+import logging
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
+
+logger = logging.getLogger("replay_tpu")
+
+# One (batch, head) program holds the [L, L] f32 mask block (double-buffered)
+# and the scores in VMEM. AOT compiles for a described v5e (jax 0.9.0 / libtpu
+# 0.0.34, bf16): D=64 compiles up to L=1152 and is refused at 1216 ("Scoped
+# allocation with size 18.84M and limit 16.00M" at 1280); D=128 compiles at
+# 1024 and is refused at 1088. Past this length use the tiled kernel.
+MAX_SINGLE_BLOCK_LENGTH = 1024
 
 
 def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref):
@@ -110,6 +122,36 @@ def _flash_bwd(interpret, residuals, grad_out):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+@cache
+def _warn_interpreter() -> None:
+    logger.warning(
+        "pallas kernels run in INTERPRET mode on the cpu backend: results are "
+        "valid, timings say nothing about the compiled TPU kernel"
+    )
+
+
+def pallas_interpret() -> bool:
+    """Whether pallas kernels must run interpreted on the default backend.
+
+    ``"tpu"`` compiles (False); ``"cpu"`` interprets (True, logged once at
+    WARNING); any other backend raises rather than silently interpreting a
+    kernel on an accelerator and reporting it as the kernel.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        _warn_interpreter()
+        return True
+    msg = (
+        f"replay_tpu's pallas kernels are written for the TPU backend; the "
+        f"default backend is {backend!r}. Use the XLA routes (CE, "
+        "use_flash=False), or pass interpret= explicitly to the kernel."
+    )
+    raise RuntimeError(msg)
+
+
 def fused_attention_available() -> bool:
-    """True when the real (compiled) kernel can run on the current backend."""
-    return jax.default_backend() == "tpu"
+    """True when the compiled kernel runs on the default backend (``"tpu"``);
+    False when the interpreter stands in (``"cpu"``); raises elsewhere."""
+    return not pallas_interpret()

@@ -2,7 +2,7 @@
 
 The single-block kernel (ops/flash_attention.py) holds the whole [L, L] score
 matrix of one (batch, head) in VMEM — past L≈1024 that exceeds the ~16 MB VMEM
-budget (BENCH_NOTES round-3 A/B). This kernel implements the standard flash
+budget (ops.flash_attention.MAX_SINGLE_BLOCK_LENGTH). This kernel implements the standard flash
 recipe instead: grid ``(B, H, q_blocks, kv_blocks)`` with the kv axis innermost
 (sequential on TPU), carrying the online-softmax state (running max, running
 sum, output accumulator) in VMEM scratch across kv steps. VMEM peak is
@@ -48,7 +48,7 @@ def _kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref, m_ref, l_ref, acc_r
         v = v_ref[0, 0].astype(jnp.float32)  # [bk, D]
         scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
         scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        scores = scores + bias_ref[0][None, :]  # per-key bias (padding)
+        scores = scores + bias_ref[0]  # [1, bk] per-key bias (padding)
         if causal:
             rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
             cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -104,14 +104,17 @@ def _forward(q, k, v, kv_bias, causal, block_q, block_k, interpret):
     qp = _pad_to(q, 2, block_q)
     kp = _pad_to(k, 2, block_k)
     vp = _pad_to(v, 2, block_k)
-    bias = _pad_to(kv_bias.astype(jnp.float32), 1, block_k, value=NEG_INF)
+    # the bias rides as [B, 1, Lk]: Mosaic wants a block's last two dims to be
+    # (8, 128)-aligned or the whole array's, and a (1, block_k) block of a
+    # [B, Lk] array is neither once B > 1
+    bias = _pad_to(kv_bias.astype(jnp.float32), 1, block_k, value=NEG_INF)[:, None, :]
     lq, lk = qp.shape[2], kp.shape[2]
     num_q, num_k = lq // block_q, lk // block_k
 
     grid = (batch, heads, num_q, num_k)
     qspec = pl.BlockSpec((1, 1, block_q, dim), lambda b, h, i, j: (b, h, i, 0))
     kspec = pl.BlockSpec((1, 1, block_k, dim), lambda b, h, i, j: (b, h, j, 0))
-    bspec = pl.BlockSpec((1, block_k), lambda b, h, i, j: (b, j))
+    bspec = pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, j))
     out_spec = pl.BlockSpec((1, 1, block_q, dim), lambda b, h, i, j: (b, h, i, 0))
     lse_spec = pl.BlockSpec((1, 1, block_q, 128), lambda b, h, i, j: (b, h, i, 0))
 

@@ -36,8 +36,10 @@ Two provisions for callers beyond the single-device case:
   failing at Mosaic compile time (the round-3 16 MB bwd-kernel incident); one
   warning is logged per shrunk configuration.
 
-On non-TPU backends the kernels run in interpreter mode (tests); call sites
-should prefer them only when ``jax.default_backend() == "tpu"``.
+``interpret`` is the caller's decision and defaults to False (compile). The
+loss heads resolve it with ``ops.flash_attention.pallas_interpret``: compiled
+on ``"tpu"``, interpreted (and logged at WARNING) on ``"cpu"``, an error on any
+other backend.
 """
 
 from __future__ import annotations

@@ -83,7 +83,7 @@ def _int_env(*names: str) -> Optional[int]:
 
 def _on_tpu_pod() -> bool:
     """Heuristic: MULTI-worker TPU runtimes list several worker hostnames —
-    single-host setups (including one-chip dev tunnels) must not initialize."""
+    single-host setups (including one-chip dev machines) must not initialize."""
     hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
     return len([h for h in hostnames.split(",") if h.strip()]) > 1 and os.environ.get(
         "JAX_PLATFORMS", ""

@@ -23,7 +23,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -113,12 +112,12 @@ def ring_attention(
 
     pad = padding_mask if padding_mask is not None else jnp.ones(q.shape[:2], bool)
     spec = P(data_axis, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec, P(data_axis, axis_name)),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, pad)
 
 

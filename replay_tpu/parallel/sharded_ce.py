@@ -39,11 +39,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from replay_tpu.ops.fused_ce import fused_lse
 
-try:  # jax >= 0.4.35 re-homed shard_map; keep both import paths working
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.sharding import shard_map  # type: ignore[attr-defined]
-
 
 def sharded_fused_lse(
     hidden: jnp.ndarray,
@@ -110,12 +105,12 @@ def sharded_fused_lse(
 
     row_spec = P(data_axis, None) if data_axis is not None else P(None, None)
     out_spec = P(data_axis) if data_axis is not None else P()
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(row_spec, P(axis_name, None)),
         out_specs=out_spec,
         # pallas_call has no replication rule; correctness is covered by the
         # parity tests on the virtual 8-device mesh (tests/ops)
-        check_rep=False,
+        check_vma=False,
     )(hidden, table)

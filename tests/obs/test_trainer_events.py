@@ -218,13 +218,10 @@ def test_fit_lr_schedule_events_report_applied_rate(tmp_path):
 @pytest.mark.jax
 @pytest.mark.smoke
 def test_bench_json_line_carries_obs_fields(tmp_path):
-    """bench.py (CPU-fallback import path, toy shapes) still prints exactly one
-    JSON line; metric/value/vs_baseline schema unchanged, obs fields additive."""
-    sidecar = os.path.join(REPO, "BENCH_TPU_SIDECAR.json")
-    sidecar_before = open(sidecar).read() if os.path.exists(sidecar) else None
+    """bench.py (on the CPU, toy shapes) still prints exactly one JSON line;
+    metric/value/vs_baseline schema unchanged, obs fields additive."""
     env = {
         **os.environ,
-        "REPLAY_TPU_BENCH_FALLBACK": "1",  # skip the backend health probe
         "REPLAY_TPU_BENCH_BATCH": "8",
         "REPLAY_TPU_BENCH_SEQ_LEN": "8",
         "REPLAY_TPU_BENCH_NUM_ITEMS": "64",
@@ -253,6 +250,5 @@ def test_bench_json_line_carries_obs_fields(tmp_path):
     assert record["compile_seconds"] > 0
     assert "peak_memory_bytes" in record  # null on CPU, bytes on TPU
     assert record["shape_override"]["B"] == 8
-    # a toy-shape run must never overwrite the real-silicon sidecar evidence
-    sidecar_after = open(sidecar).read() if os.path.exists(sidecar) else None
-    assert sidecar_after == sidecar_before
+    # a CPU run names itself: it can never pass for a device number
+    assert record["platform"] == "cpu" and record["device_count"] >= 1

@@ -36,12 +36,19 @@ def make_data(n, items, embed, seed=0):
 
 
 def assert_parity(mesh, h, w, g, item_tile=None, data_axis="data"):
-    """Sharded fwd/grads vs replicated fused_lse vs plain jnp logsumexp."""
+    """Sharded fwd/grads vs replicated fused_lse vs plain jnp logsumexp.
+
+    Every kernel call is jitted, as the trainer runs it: dispatched eagerly the
+    interpreted shard_map costs ~3 s a call on the 8-device CPU mesh."""
+
+    def sharded_lse(h, w):
+        return sharded_fused_lse(
+            h, w, mesh, data_axis=data_axis, tile=8, item_tile=item_tile, interpret=True
+        )
+
     want = jax.nn.logsumexp(h @ w.T, axis=-1)
-    replicated = fused_lse(h, w, 8, item_tile, True)
-    got = sharded_fused_lse(
-        h, w, mesh, data_axis=data_axis, tile=8, item_tile=item_tile, interpret=True
-    )
+    replicated = jax.jit(lambda h, w: fused_lse(h, w, 8, item_tile, True))(h, w)
+    got = jax.jit(sharded_lse)(h, w)
     np.testing.assert_allclose(np.asarray(replicated), np.asarray(want), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
@@ -49,16 +56,10 @@ def assert_parity(mesh, h, w, g, item_tile=None, data_axis="data"):
         return jnp.sum(jax.nn.logsumexp(h @ w.T, axis=-1) * g)
 
     def sharded(h, w):
-        return jnp.sum(
-            sharded_fused_lse(
-                h, w, mesh, data_axis=data_axis, tile=8, item_tile=item_tile,
-                interpret=True,
-            )
-            * g
-        )
+        return jnp.sum(sharded_lse(h, w) * g)
 
-    ref_dh, ref_dw = jax.grad(ref, argnums=(0, 1))(h, w)
-    got_dh, got_dw = jax.grad(sharded, argnums=(0, 1))(h, w)
+    ref_dh, ref_dw = jax.jit(jax.grad(ref, argnums=(0, 1)))(h, w)
+    got_dh, got_dw = jax.jit(jax.grad(sharded, argnums=(0, 1)))(h, w)
     np.testing.assert_allclose(np.asarray(got_dh), np.asarray(ref_dh), rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(got_dw), np.asarray(ref_dw), rtol=2e-4, atol=2e-5)
 

@@ -1,0 +1,132 @@
+"""AOT compiles of the main-path Pallas kernels for a DESCRIBED v5e (no chip).
+
+Interpret mode cannot see what Mosaic refuses: block shapes that break the
+(8, 128) tiling rule, kernels that outgrow VMEM. ``flash_attention_tiled`` passed
+every interpret-mode test for fifteen PRs and had never lowered for a TPU. These
+cases hand the chip's own compiler the real widths and assert a
+``tpu_custom_call`` comes out. Nothing runs: a compile that passes is not a chip
+run (``chip_smoke.py`` is).
+
+The topology is described inside a fixture of THIS file only (one process may
+load libtpu; see the on-chip-measurement guide §2): never at import, in a
+``skipif`` or in ``parametrize`` arguments.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from replay_tpu.nn.attention import dot_product_attention
+from replay_tpu.ops.flash_attention import flash_attention
+from replay_tpu.ops.flash_tiled import flash_attention_tiled
+from replay_tpu.ops.fused_ce import fused_lse
+from replay_tpu.parallel import sharded_fused_lse
+
+pytestmark = pytest.mark.jax
+
+bf16, f32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        described = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as error:  # no libtpu here, or another process holds its lock
+        pytest.skip(f"no v5e:2x2 topology can be described here: {error}")
+    # a compile for a described chip is written to the persistent cache but can
+    # never be read back without one: keep these out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def assert_mosaic(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def fwd_or_grad(fn, grad, argnums):
+    if not grad:
+        return fn
+    return jax.grad(lambda *args: jnp.sum(fn(*args).astype(f32)), argnums=argnums)
+
+
+# (rows, embed, items, table dtype): notebook-09 SASRec on the ML-20M catalog
+# (B512·L50 rows), BERT4Rec notebook-10 width (B512·L100, d=300 — the VMEM guard
+# must shrink item_tile), the million-item north star, and the bf16-compute /
+# f32-master-table split Trainer(precision="bf16") actually feeds the kernel
+FUSED_CE_SHAPES = [
+    (25600, 64, 27278, bf16),
+    (51200, 300, 27279, bf16),
+    (25600, 64, 1_000_000, bf16),
+    (25600, 64, 27278, f32),
+]
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("rows,embed,items,table_dtype", FUSED_CE_SHAPES)
+def test_fused_lse_lowers(one_chip, rows, embed, items, table_dtype, grad):
+    hidden = jax.ShapeDtypeStruct((rows, embed), bf16, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((items, embed), table_dtype, sharding=one_chip)
+    assert_mosaic(fwd_or_grad(fused_lse, grad, (0, 1)), hidden, table)
+
+
+# (B, H, L, D): the SASRec bench shape, two mid lengths, and the long-context
+# shape chip_smoke.py runs; every one was refused before the [B, 1, Lk] bias
+TILED_SHAPES = [(512, 2, 50, 32), (64, 2, 200, 32), (64, 2, 1024, 64), (8, 2, 4096, 64)]
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("shape", TILED_SHAPES, ids=lambda s: f"L{s[2]}")
+def test_flash_tiled_lowers(one_chip, shape, grad):
+    qkv = jax.ShapeDtypeStruct(shape, bf16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((shape[0], shape[2]), f32, sharding=one_chip)
+    assert_mosaic(fwd_or_grad(flash_attention_tiled, grad, (0, 1, 2)), qkv, qkv, qkv, bias)
+
+
+def test_flash_single_block_lowers_at_its_bound(one_chip):
+    qkv = jax.ShapeDtypeStruct((8, 2, 1024, 64), bf16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((8, 1, 1024, 1024), f32, sharding=one_chip)
+    assert_mosaic(flash_attention, qkv, qkv, qkv, mask)
+
+
+def test_single_block_route_refuses_long_sequences():
+    """Past the bound the chip's compiler refuses the kernel (VMEM); the route
+    says so itself and names the tiled kernel."""
+    qkv = jax.ShapeDtypeStruct((8, 2, 4096, 64), bf16)
+    mask = jax.ShapeDtypeStruct((8, 1, 4096, 4096), f32)
+    with pytest.raises(ValueError, match="use_flash='tiled'"):
+        jax.eval_shape(
+            lambda q, k, v, m: dot_product_attention(q, k, v, m, use_flash=True),
+            qkv, qkv, qkv, mask,
+        )
+
+
+def test_sharded_fused_lse_grad_lowers_on_2x2(topo):
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    hidden = jax.ShapeDtypeStruct(
+        (25600, 64), bf16, sharding=NamedSharding(mesh, P("data", None))
+    )
+    table = jax.ShapeDtypeStruct(
+        (27278, 64), f32, sharding=NamedSharding(mesh, P("model", None))
+    )
+    assert_mosaic(
+        fwd_or_grad(lambda h, w: sharded_fused_lse(h, w, mesh), True, (0, 1)), hidden, table
+    )

@@ -11,7 +11,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from replay_tpu.data import FeatureHint, FeatureType
 from replay_tpu.data.nn import TensorFeatureInfo, TensorSchema
@@ -147,7 +146,7 @@ def test_metrics_state_psums_across_devices():
 
     specs_in = jax.tree.map(lambda _: P("d"), stacked)
     specs_out = jax.tree.map(lambda _: P(), stacked)
-    total_state = shard_map(
+    total_state = jax.shard_map(
         reduce_states, mesh=mesh, in_specs=(specs_in,), out_specs=specs_out
     )(stacked)
     # shard_map with in_specs P('d') leaves a leading per-device axis of size 1
