@@ -16,17 +16,17 @@ NamedSharding in the trainer.
 
 from __future__ import annotations
 
-import contextlib
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-if TYPE_CHECKING:  # import-light: the tracer is optional, duck-typed at runtime
+if TYPE_CHECKING:
     from replay_tpu.obs.trace import Tracer
 
 from replay_tpu.data.nn.partitioning import Partitioning
 from replay_tpu.data.nn.sequential_dataset import SequentialDataset
+from replay_tpu.obs.trace import stage
 
 # id-set padding sentinels for validation batches (MetricsBuilder's contract).
 # The reference needs distinct -1/-2 because its ground-truth and train id
@@ -68,11 +68,14 @@ class SequenceBatcher:
         program per distinct shape — a handful of buckets, not per-batch
         dynamic shapes. ``max_sequence_length`` remains the top bucket.
         Incompatible with the scan-chunked fit (see :attr:`scan_compatible`).
-    :param tracer: optional :class:`replay_tpu.obs.Tracer`: every batch
-        assembly is recorded as a ``batch_build`` span. Share the trainer's
-        tracer to see, inside its ``data_wait`` phase, how much is THIS
-        batcher (gather/pad) versus upstream iteration — on a prefetch
-        thread the spans land on that thread's timeline in ``trace.json``.
+    :param tracer: optional :class:`replay_tpu.obs.Tracer`. Every batch
+        assembly is a ``batch_build`` stage (``obs.trace.stage``: in any
+        profiler capture and in the chunk stage log without this argument);
+        it is recorded by this tracer when given, else by the tracer of the
+        traced ``fit`` that consumes the batches — inside its ``data_wait``
+        phase it shows how much is THIS batcher (gather/pad) versus upstream
+        iteration; on a prefetch or feeder thread the spans land on that
+        thread's timeline in ``trace.json``.
     """
 
     dataset: SequentialDataset
@@ -186,11 +189,8 @@ class SequenceBatcher:
         sample = self.dataset.get_sequence(0, name) if len(self.dataset) else np.zeros(0)
         return np.int32 if np.issubdtype(np.asarray(sample).dtype, np.integer) else np.float32
 
-    def _span(self, name: str):
-        return self.tracer.span(name) if self.tracer is not None else contextlib.nullcontext()
-
     def _make_batch(self, chunk: np.ndarray, L: int, dtypes: Dict) -> Batch:
-        with self._span("batch_build"):
+        with stage("batch_build", tracer=self.tracer):
             return self._assemble_batch(chunk, L, dtypes)
 
     def _assemble_batch(self, chunk: np.ndarray, L: int, dtypes: Dict) -> Batch:

@@ -31,6 +31,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from replay_tpu.data.nn.iterator import Batch, SequenceBatcher
+from replay_tpu.obs.trace import stage
 
 
 def bucketed_length(length: int, capacity: int, boundaries: Optional[Sequence[int]]) -> int:
@@ -231,8 +232,9 @@ class PackedSequenceBatcher(SequenceBatcher):
         rows = self._packed_rows(order)
         for start in range(0, len(rows), self.batch_size):
             chunk = rows[start : start + self.batch_size]
-            with self._span("batch_build"):
-                yield self._assemble_packed(chunk, dtypes)
+            with stage("batch_build", tracer=self.tracer):
+                batch = self._assemble_packed(chunk, dtypes)
+            yield batch
 
     # -- padding accounting -------------------------------------------------- #
     def packing_summary(self) -> Dict[str, float]:

@@ -36,6 +36,8 @@ import threading
 import time
 from typing import Any, Callable, Iterable, Iterator, Optional, Tuple
 
+from replay_tpu.obs.trace import claimed_chunk, stage
+
 logger = logging.getLogger("replay_tpu")
 
 _SENTINEL = object()
@@ -82,7 +84,13 @@ def _pipeline(
         try:
             for item in source:
                 payload = item if transform is None else (item, transform(item))
-                if not emit(payload):
+                # the producer's slack: how long it had nothing to do. It
+                # carries the ordinal of the chunk ``transform`` just claimed
+                # (obs.trace.claim_chunk), so the wait reads in that chunk's
+                # record of the stage log
+                with stage("feed_full", **claimed_chunk()):
+                    alive = emit(payload)
+                if not alive:
                     return
         except BaseException as error:  # noqa: BLE001 - relayed to the consumer
             emit((_SENTINEL, error))
@@ -142,9 +150,10 @@ class DevicePrefetcher:
     it enqueues proceed concurrently with the main thread's running
     computation. It may return ``None`` for items that should pass through
     unplaced (the trainer's short-tail / health single steps, which the
-    per-step path places itself). Wrap tracing inside ``place`` — its spans
-    then land on the feeder thread's timeline (``trace.json``), not in the
-    consumer's goodput fractions.
+    per-step path places itself). Stage spans inside ``place`` land on the
+    feeder thread's timeline (``trace.json``, a profiler capture), not in the
+    consumer's goodput fractions; the blocking put after it is the
+    ``feed_full`` stage.
 
     Donation safety: ``place`` must produce arrays the consumer's computation
     does NOT donate. The trainer's scan program donates only its TrainState

@@ -24,6 +24,7 @@ from __future__ import annotations
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from replay_tpu.data.nn.schema import TensorMap, TensorSchema
@@ -95,47 +96,52 @@ class Bert4RecBody(nn.Module):
         deterministic: bool = True,
         segment_ids: Optional[jnp.ndarray] = None,  # [B, L] int, packed batches
     ) -> jnp.ndarray:
-        embeddings = self.embedder(feature_tensors)
-        total = sum(embeddings[name] for name in sorted(embeddings))
-        if token_mask is not None:
-            visible = token_mask.reshape(token_mask.shape[0], token_mask.shape[1])
-            total = jnp.where(
-                visible[..., None], total, self.mask_embedding.astype(total.dtype)
+        # named scopes label the HLO per stage, as SasRecBody's do: a device
+        # profile splits the forward into embed / encoder / final_norm
+        with jax.named_scope("embed"):
+            embeddings = self.embedder(feature_tensors)
+            total = sum(embeddings[name] for name in sorted(embeddings))
+            if token_mask is not None:
+                visible = token_mask.reshape(token_mask.shape[0], token_mask.shape[1])
+                total = jnp.where(
+                    visible[..., None], total, self.mask_embedding.astype(total.dtype)
+                )
+            seq_len = total.shape[1]
+            if seq_len > self.max_sequence_length:
+                msg = (
+                    f"Sequence length {seq_len} exceeds positional table size "
+                    f"{self.max_sequence_length}"
+                )
+                raise ValueError(msg)
+            # left-padded inputs: the most recent position maps to the last table row
+            x = total + self.positional_embedding[
+                self.max_sequence_length - seq_len :
+            ].astype(total.dtype)
+            x = self.input_dropout(self.input_norm(x), deterministic=deterministic)
+            # rule-table activation constraint: [B, L, E] pinned to the (batch,
+            # length, embed) rules under the trainer's sharding scope (the SP
+            # layout between ring-attention blocks); a no-op outside any scope
+            x = shard_activation(x, "batch", "length", "embed")
+            # model-health stage stats (no-op unless `intermediates` is mutable)
+            sow_stage_stats(self, "embed", x)
+        with jax.named_scope("encoder"):
+            # packed rows (segment_ids) get the block-diagonal bidirectional
+            # mask: attention never crosses a packed segment boundary
+            attention_mask = attention_mask_for_route(
+                self.use_flash, padding_mask, causal=False,
+                deterministic=deterministic, dtype=self.dtype,
+                segment_ids=segment_ids,
             )
-        seq_len = total.shape[1]
-        if seq_len > self.max_sequence_length:
-            msg = (
-                f"Sequence length {seq_len} exceeds positional table size "
-                f"{self.max_sequence_length}"
-            )
-            raise ValueError(msg)
-        # left-padded inputs: the most recent position maps to the last table row
-        x = total + self.positional_embedding[self.max_sequence_length - seq_len :].astype(
-            total.dtype
-        )
-        x = self.input_dropout(self.input_norm(x), deterministic=deterministic)
-        # rule-table activation constraint: [B, L, E] pinned to the (batch,
-        # length, embed) rules under the trainer's sharding scope (the SP
-        # layout between ring-attention blocks); a no-op outside any scope
-        x = shard_activation(x, "batch", "length", "embed")
-        # model-health stage stats (no-op unless `intermediates` is mutable)
-        sow_stage_stats(self, "embed", x)
-        # packed rows (segment_ids) get the block-diagonal bidirectional
-        # mask: attention never crosses a packed segment boundary
-        attention_mask = attention_mask_for_route(
-            self.use_flash, padding_mask, causal=False,
-            deterministic=deterministic, dtype=self.dtype,
-            segment_ids=segment_ids,
-        )
-        for _ in range(self.num_passes_over_block):
-            x = self.encoder(
-                x, attention_mask, padding_mask,
-                deterministic=deterministic, causal=False,
-            )
-        out = self.final_norm(x)
-        out = shard_activation(out, "batch", "length", "embed")
-        sow_stage_stats(self, "final_norm", out)
-        return out
+            for _ in range(self.num_passes_over_block):
+                x = self.encoder(
+                    x, attention_mask, padding_mask,
+                    deterministic=deterministic, causal=False,
+                )
+        with jax.named_scope("final_norm"):
+            out = self.final_norm(x)
+            out = shard_activation(out, "batch", "length", "embed")
+            sow_stage_stats(self, "final_norm", out)
+            return out
 
 
 class Bert4Rec(nn.Module):

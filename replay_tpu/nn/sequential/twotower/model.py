@@ -24,6 +24,7 @@ from __future__ import annotations
 from typing import Any, Dict, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from replay_tpu.data.nn.schema import TensorMap, TensorSchema
@@ -143,14 +144,18 @@ class TwoTower(nn.Module):
         deterministic: bool = True,
     ) -> jnp.ndarray:
         """Query hidden states [B, L, E]."""
-        embeddings = self.embedder(feature_tensors)
-        x = self.aggregator(embeddings, deterministic=deterministic)
-        attention_mask = attention_mask_for_route(
-            self.use_flash, padding_mask, causal=True,
-            deterministic=deterministic, dtype=self.dtype,
-        )
-        x = self.encoder(x, attention_mask, padding_mask, deterministic=deterministic)
-        x = self.final_norm(x)
+        # named scopes label the HLO per stage, as SasRecBody's do
+        with jax.named_scope("embed"):
+            embeddings = self.embedder(feature_tensors)
+            x = self.aggregator(embeddings, deterministic=deterministic)
+        with jax.named_scope("encoder"):
+            attention_mask = attention_mask_for_route(
+                self.use_flash, padding_mask, causal=True,
+                deterministic=deterministic, dtype=self.dtype,
+            )
+            x = self.encoder(x, attention_mask, padding_mask, deterministic=deterministic)
+        with jax.named_scope("final_norm"):
+            x = self.final_norm(x)
         if self.context_merger is not None:
             x = self.context_merger(x, feature_tensors)
         return x

@@ -63,6 +63,13 @@ from replay_tpu.obs import (
     traced_iterator,
 )
 from replay_tpu.obs.health import health_metrics
+from replay_tpu.obs.trace import (
+    ChunkStages,
+    attach_tracer,
+    attached_tracer,
+    claim_chunk,
+    stage,
+)
 
 logger = logging.getLogger("replay_tpu")
 
@@ -586,8 +593,10 @@ class Trainer:
     compile_tracker: CompileTracker = field(default_factory=CompileTracker)
     # host-side span tracer (obs.trace): an ENABLED Tracer here (or passed to
     # fit as tracer=...) records data_wait/h2d/compile/train_step/validation/
-    # checkpoint/recovery spans, a trace.json Chrome trace and per-epoch
-    # goodput breakdowns; None = tracing off, the span hooks cost ~nothing
+    # checkpoint/recovery spans (and the chunked path's stage spans), a
+    # trace.json Chrome trace and per-epoch goodput breakdowns; None = no
+    # Tracer record: the stage spans still feed any profiler capture and the
+    # chunk stage log (obs.trace.chunk_stage_log)
     tracer: Optional[Tracer] = None
     # in-graph model-health diagnostics (obs.health): a HealthConfig here
     # extends the jitted train step with per-group grad/param/update norms,
@@ -723,28 +732,9 @@ class Trainer:
         path (replay_tpu.nn.vocab): the reference rebuilds its optimizer the
         same way after ``set_item_embeddings_*``.
         """
-        rng = jax.random.PRNGKey(self.seed)
-        init_rng, state_rng = jax.random.split(rng)
-        kwargs = self._forward_kwargs(example_batch)
-        logits_extra = {
-            name: example_batch[name] for name in self._logits_extra_params if name in example_batch
-        }
-
-        def init_fn(module):
-            # touch EVERY parameter path: the training forward plus the scoring
-            # head (which owns e.g. TwoTower's item tower)
-            hidden = module(**kwargs)
-            if hasattr(module, "get_logits"):
-                module.get_logits(hidden, None, **logits_extra)
-            return hidden
-
+        state_rng = jax.random.split(jax.random.PRNGKey(self.seed))[1]
         if params is None:
-            from replay_tpu.parallel.sharding import sharding_scope
-
-            with sharding_scope(self.sharding_rules, self.mesh):
-                params = self.model.init(
-                    {"params": init_rng, "dropout": init_rng}, method=init_fn
-                )["params"]
+            params = self._init_params(example_batch)
         from replay_tpu.parallel.sharding import params_shardings
 
         shardings = params_shardings(self.mesh, params, self.sharding_rules)
@@ -761,6 +751,30 @@ class Trainer:
         return TrainState(
             step=step, params=params, opt_state=opt_state, rng=rng, bad_steps=bad_steps
         )
+
+    def _init_params(self, example_batch: Batch) -> Any:
+        """A fresh flax init of the model's parameters from :attr:`seed` (pure:
+        the tests run it under ``jax.jit`` as one program)."""
+        init_rng = jax.random.split(jax.random.PRNGKey(self.seed))[0]
+        kwargs = self._forward_kwargs(example_batch)
+        logits_extra = {
+            name: example_batch[name] for name in self._logits_extra_params if name in example_batch
+        }
+
+        def init_fn(module):
+            # touch EVERY parameter path: the training forward plus the scoring
+            # head (which owns e.g. TwoTower's item tower)
+            hidden = module(**kwargs)
+            if hasattr(module, "get_logits"):
+                module.get_logits(hidden, None, **logits_extra)
+            return hidden
+
+        from replay_tpu.parallel.sharding import sharding_scope
+
+        with sharding_scope(self.sharding_rules, self.mesh):
+            return self.model.init(
+                {"params": init_rng, "dropout": init_rng}, method=init_fn
+            )["params"]
 
     def _forward_kwargs(self, batch: Batch, **overrides) -> Dict[str, Any]:
         """Filter the batch down to the model's forward signature (the reference
@@ -1096,10 +1110,9 @@ class Trainer:
         return self._scoped(train_step)
 
     def _h2d_span(self):
-        """A ``h2d`` span when an enabled tracer is attached, else a no-op."""
-        if self.tracer is not None and self.tracer.enabled:
-            return self.tracer.span("h2d")
-        return contextlib.nullcontext()
+        """The per-step paths' ``h2d`` stage (recorded by the attached tracer
+        when one is enabled)."""
+        return stage("h2d", tracer=self.tracer)
 
     def traced_train_step(
         self, state: TrainState, batch: Batch
@@ -1131,18 +1144,23 @@ class Trainer:
         sentinel's ``good`` flag and ``grad_norm``, all device scalars — stay
         readable on :attr:`last_step_metrics` until the next step.
         """
+        step_fn = self._ensure_train_step()
+        with self._h2d_span():
+            placed = self._put_batch(batch)
+        self._record_template("train_step", step_fn, state, placed)
+        with self.compile_tracker.observe("train_step"):
+            new_state, metrics = step_fn(state, placed)
+        self.last_step_metrics = metrics
+        return new_state, metrics["loss"]
+
+    def _ensure_train_step(self):
+        """The jitted per-step program, built lazily (see :meth:`_ensure_train_scan`)."""
         if self._train_step is None:
             self._train_step = jax.jit(
                 self.compile_tracker.wrap(self._build_train_step(self.health), "train_step"),
                 donate_argnums=0,
             )
-        with self._h2d_span():
-            placed = self._put_batch(batch)
-        self._record_template("train_step", self._train_step, state, placed)
-        with self.compile_tracker.observe("train_step"):
-            new_state, metrics = self._train_step(state, placed)
-        self.last_step_metrics = metrics
-        return new_state, metrics["loss"]
+        return self._train_step
 
     def _ensure_train_scan(self):
         """The jitted K-step ``lax.scan`` program, built lazily (and rebuilt
@@ -1257,35 +1275,48 @@ class Trainer:
 
         return jax.tree.map(place, stacked)
 
-    def _chunk_placer(self, tracer: Optional[Tracer]):
+    def _chunk_placer(self, tracer: Optional[Tracer], first_chunk: int = 0):
         """The device-feed ``place`` callable for the scan-chunked fit: stack
         + place a chunk on the FEEDER thread, so the next chunk's H2D copy
         overlaps the running chunk's compute. Single-step items pass through
         unplaced — the per-step path places its own batch (pre-placing would
         make ``_put_batch``'s ``np.asarray`` round-trip them back to host).
-        The ``h2d`` span lands on the feeder thread's timeline: ``trace.json``
-        shows the overlap, while the fit thread's goodput fractions count only
-        what the feed could NOT hide."""
+
+        Two stage spans, each with the chunk's ordinal (``first_chunk`` on):
+        ``stack`` is ``_stack_chunk`` alone, whose ``np.asarray`` of a leaf
+        that arrived as a device array is a D2H read that WAITS for whatever
+        runs on the device (``device_leaves`` counts them); ``h2d`` is the copy
+        and its fence. They land on the feeder thread's timeline, so the fit
+        thread's goodput fractions count only what the feed could NOT hide.
+        Returns ``(placed, record)``: the record (``obs.trace.claim_chunk``)
+        holds this thread's stage seconds for the chunk and travels with it."""
+        ordinals = itertools.count(first_chunk)
 
         def place(item):
             kind, payload = item
             if kind != "scan":
                 return None
-            span = (
-                tracer.span("h2d", steps=len(payload))
-                if tracer is not None and tracer.enabled
-                else contextlib.nullcontext()
+            chunk = next(ordinals)
+            record = claim_chunk(chunk)
+            record["device_leaves"] = device_leaves = sum(
+                isinstance(leaf, jax.Array) for leaf in jax.tree.leaves(list(payload))
             )
-            with span:
-                placed = self._put_stacked(self._stack_chunk(payload))
-                # fence on the feeder thread: the span times the real copy,
+            with stage("stack", tracer=tracer, chunk=chunk, device_leaves=device_leaves):
+                stacked = self._stack_chunk(payload)
+            record["h2d_bytes"] = nbytes = sum(
+                leaf.nbytes for leaf in jax.tree.leaves(stacked)
+            )
+            with stage("h2d", tracer=tracer, chunk=chunk, steps=len(payload), bytes=nbytes):
+                placed = self._put_stacked(stacked)
+                # fence on the placing thread: the span times the real copy,
                 # and the consumer dispatches on already-resident buffers
                 jax.block_until_ready(placed)
-            return placed
+            return placed, record
 
         return place
 
     def fit(self, *args, **kwargs) -> TrainState:
+        attached = attached_tracer()  # a traced fit attaches its own
         try:
             return self._fit_impl(*args, **kwargs)
         except BaseException:
@@ -1298,6 +1329,8 @@ class Trainer:
                 self.metrics_exporter.close()
                 self.metrics_exporter = None
             raise
+        finally:
+            attach_tracer(attached)
 
     def _fit_impl(
         self,
@@ -1733,6 +1766,12 @@ class Trainer:
             self.tracer = tracer  # train_step's h2d spans route through it too
         trace = self.tracer if self.tracer is not None and self.tracer.enabled else None
         tracing = trace is not None
+        # stages built without a tracer (Compose, the batcher, the feeder's
+        # put) record into this fit's, if it has one; fit() restores what was
+        # attached
+        attach_tracer(trace)
+        # the fit thread's side of the chunk stage log (scan-chunked epochs)
+        chunk_stages = ChunkStages(trace)
         if tracing and trace_path is None:
             queue: List[RunLogger] = list(explicit_loggers)
             while queue:  # MultiLogger nests sinks: search them too
@@ -2380,18 +2419,21 @@ class Trainer:
                         items = _chunk_schedule(
                             stream, scan_chunk, health_every, start=measured_total
                         )
+                        place = self._chunk_placer(trace, chunk_stages.chunk)
                         feed = (
-                            DevicePrefetcher(items, self._chunk_placer(trace), depth=1)
+                            DevicePrefetcher(items, place, depth=1)
                             if device_feed
-                            # feed off: items pass through unplaced and the
-                            # scan branch below places them on the FIT thread
-                            # (h2d lands in the goodput fractions — the A/B
+                            # feed off: the same stack + placement runs on the
+                            # FIT thread, inside its wait on the stream (stack
+                            # and h2d land in the goodput fractions — the A/B
                             # shows exactly what the feed would have hidden)
-                            else ((item, None) for item in items)
+                            else ((item, place(item)) for item in items)
                         )
-                        feed_stream = traced_iterator(feed, trace) if tracing else feed
+                        chunk_stages.new_epoch()
                         try:
-                            for item, placed in feed_stream:
+                            # every pull is a data_wait stage: the fit
+                            # thread's wait on the feed
+                            for item, fed in chunk_stages.feed(feed):
                                 if epoch_needs_mark:
                                     telemetry.mark()
                                     epoch_needs_mark = False
@@ -2442,40 +2484,41 @@ class Trainer:
                                         )
                                         profile_active = True
                                     scan_fn = self._ensure_train_scan()
-                                    if placed is None:
-                                        # device_feed=False: synchronous
-                                        # stack + placement on the fit thread
-                                        with self._h2d_span():
-                                            placed = self._put_stacked(
-                                                self._stack_chunk(chunk)
-                                            )
+                                    placed, feeder = fed
                                     compile_before = (
                                         self.compile_tracker.total_compile_seconds
                                     )
-                                    span_cm = (
-                                        trace.span("train_step", steps=k)
-                                        if tracing
-                                        else contextlib.nullcontext()
+                                    # train_step = dispatch (the enqueue; a
+                                    # compile is carved out of it) + device_wait
+                                    # (the chunk's ONE host sync: the [K]
+                                    # per-step metrics fence the span and feed
+                                    # the fan-out accounting below)
+                                    with chunk_stages.stage("train_step", steps=k):
+                                        with chunk_stages.stage("dispatch") as dispatch:
+                                            self._record_template(
+                                                "train_scan", scan_fn, state, placed
+                                            )
+                                            with self.compile_tracker.observe("train_scan"):
+                                                state, chunk_metrics = scan_fn(state, placed)
+                                        with chunk_stages.stage("device_wait") as device_wait:
+                                            losses = np.asarray(chunk_metrics["loss"])
+                                            goods = np.asarray(chunk_metrics["good"])
+                                            grad_norms = np.asarray(chunk_metrics["grad_norm"])
+                                    compile_delta = (
+                                        self.compile_tracker.total_compile_seconds
+                                        - compile_before
                                     )
-                                    self._record_template(
-                                        "train_scan", scan_fn, state, placed
+                                    # the chunk's record goes to the stage log
+                                    # and `account` opens: everything from here
+                                    # to the next pull on the feed
+                                    chunk_stages.synced(
+                                        k, dispatch, device_wait, compile_delta > 0, feeder
                                     )
-                                    with span_cm as step_span:
-                                        with self.compile_tracker.observe("train_scan"):
-                                            state, chunk_metrics = scan_fn(state, placed)
-                                        # ONE host sync per chunk: the [K]
-                                        # per-step metrics fence the span and
-                                        # feed the fan-out accounting below
-                                        losses = np.asarray(chunk_metrics["loss"])
-                                        goods = np.asarray(chunk_metrics["good"])
-                                        grad_norms = np.asarray(chunk_metrics["grad_norm"])
-                                    if tracing:
-                                        compile_delta = (
-                                            self.compile_tracker.total_compile_seconds
-                                            - compile_before
-                                        )
-                                        if compile_delta > 0:
-                                            trace.carve(step_span, "compile", compile_delta)
+                                    # the scan has read the chunk's device
+                                    # copy: let it go here, inside `account`
+                                    placed = fed = None
+                                    if tracing and compile_delta > 0:
+                                        trace.carve(dispatch.span, "compile", compile_delta)
                                     self.last_step_metrics = chunk_metrics
                                     # chunk-boundary HBM sample: the scan path
                                     # otherwise only snapshots memory per
@@ -2556,6 +2599,7 @@ class Trainer:
                                          **fit_end_payload())
                                     return state
                         finally:
+                            chunk_stages.close()
                             if isinstance(feed, DevicePrefetcher):
                                 feed.close()
                     epoch_batches = ()  # the per-step loop below is skipped

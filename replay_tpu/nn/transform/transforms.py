@@ -17,6 +17,8 @@ from typing import Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
+from replay_tpu.obs.trace import stage
+
 DEFAULT_MASK_POSTFIX = "_mask"
 Batch = Dict[str, jnp.ndarray]
 
@@ -31,7 +33,11 @@ class Transform:
 
 
 class Compose(Transform):
-    """Apply transforms in order, splitting the rng across the stochastic ones."""
+    """Apply transforms in order, splitting the rng across the stochastic ones.
+
+    Each transform runs as a ``transform`` stage (``obs.trace.stage``) that
+    carries its class name: in a profiler capture and in the chunk stage log's
+    ``transform_by_name`` the cost of the input pipeline reads per transform."""
 
     def __init__(self, transforms: Sequence[Transform]) -> None:
         self.transforms = list(transforms)
@@ -42,14 +48,15 @@ class Compose(Transform):
 
     def __call__(self, batch: Batch, rng: Optional[jax.Array] = None) -> Batch:
         for transform in self.transforms:
-            if transform.needs_rng:
-                if rng is None:
-                    msg = f"{type(transform).__name__} needs an rng key"
-                    raise ValueError(msg)
-                rng, sub = jax.random.split(rng)
-                batch = transform(batch, sub)
-            else:
-                batch = transform(batch)
+            with stage("transform", transform=type(transform).__name__):
+                if transform.needs_rng:
+                    if rng is None:
+                        msg = f"{type(transform).__name__} needs an rng key"
+                        raise ValueError(msg)
+                    rng, sub = jax.random.split(rng)
+                    batch = transform(batch, sub)
+                else:
+                    batch = transform(batch)
         return batch
 
 

@@ -77,9 +77,11 @@ from .trace import (
     SERVE_GOODPUT_SPANS,
     TraceContext,
     Tracer,
+    chunk_stage_log,
     goodput_breakdown,
     lifecycle_span,
     merge_traces,
+    stage,
     tail_attribution,
     traced_iterator,
 )
@@ -120,6 +122,7 @@ __all__ = [
     "analyze_program",
     "attribute_capture",
     "canary_quality_rules",
+    "chunk_stage_log",
     "classify",
     "cost_analysis",
     "federate_snapshots",
@@ -140,6 +143,7 @@ __all__ = [
     "read_flight",
     "scope_of",
     "scrape_snapshot",
+    "stage",
     "tail_attribution",
     "traced_iterator",
 ]

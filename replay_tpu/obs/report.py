@@ -248,8 +248,12 @@ def summarize_run(path: str) -> Dict[str, Any]:
         # total h2d time ACROSS threads: in a chunked run the device feed
         # places chunks on a feeder thread, so most of this never shows up in
         # the fit thread's goodput fractions — the delta IS the overlap win
+        # (stacking a chunk is its own `stack` span next to the copy: both are
+        # the placement the feed hides, as the goodput fold counts them)
         if "h2d" in trace:
-            summary["h2d_seconds"] = float(trace["h2d"]["seconds"])
+            summary["h2d_seconds"] = float(trace["h2d"]["seconds"]) + float(
+                trace.get("stack", {}).get("seconds", 0.0)
+            )
         # tail attribution (fleet traces): decompose the slow tail of traced
         # requests into per-hop fractions — None for training traces, whose
         # spans carry no request roots

@@ -17,37 +17,64 @@ Design:
   Disabled tracers return a shared null context — near-zero overhead, safe to
   leave the instrumentation in hot paths.
 * Exports Chrome trace-event JSON (:meth:`Tracer.save` → ``trace.json``),
-  loadable in Perfetto / ``chrome://tracing`` next to a ``jax.profiler``
-  device trace; wrap device-side blocks in ``jax.named_scope`` so the two
-  correlate by name.
+  loadable in Perfetto / ``chrome://tracing``. Bounded: the event store keeps
+  the newest ``maxlen`` records while per-name running totals stay exact.
+* :class:`stage` is the ONE call site per boundary of the fit path. It always
+  enters a ``jax.profiler.TraceAnnotation`` (so any profiler session holds the
+  span on the device ops' clock, plane ``/host:CPU``), always adds its seconds
+  to the **chunk stage log** (:func:`chunk_stage_log`: a fixed-size ring of one
+  record per scan chunk), and records into a :class:`Tracer` when one is
+  attached. :class:`ChunkStages` is the fit thread's side of that log.
 * :meth:`Tracer.summary` aggregates per-name **inclusive** and **exclusive**
   (self) time; :func:`goodput_breakdown` turns an exclusive-time snapshot
   diff into the epoch/fit goodput record carried by ``on_epoch_end`` /
   ``on_fit_end`` events.
 
 The module is import-light on purpose (no jax, no numpy): the report CLI and
-the core-tier tests run it host-only.
+the core-tier tests run it host-only. The annotation class is taken from
+``sys.modules["jax"]`` only once something else has imported jax.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterable, Iterator, List, Mapping, Optional, Sequence, Tuple
+from typing import (
+    Any,
+    Deque,
+    Dict,
+    Iterable,
+    Iterator,
+    List,
+    Mapping,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 __all__ = [
+    "CHUNK_STAGES",
+    "ChunkStages",
     "GOODPUT_SPANS",
     "REQUEST_HOP_SPANS",
     "SERVE_GOODPUT_SPANS",
     "TraceContext",
     "Tracer",
+    "attach_tracer",
+    "attached_tracer",
+    "chunk_stage_log",
+    "claim_chunk",
+    "claimed_chunk",
     "goodput_breakdown",
     "lifecycle_span",
     "merge_traces",
+    "stage",
     "tail_attribution",
     "traced_iterator",
 ]
@@ -58,10 +85,13 @@ __all__ = [
 # batcher's assembly work (SequenceBatcher(tracer=...)): when the batcher runs
 # on the consuming thread its spans nest inside data_wait — listing it here
 # keeps that time counted as input time rather than leaking into "other".
-# "h2d" covers device placement; under fit(scan_chunk=...) the device feed
-# records it on the FEEDER thread, so it appears in trace.json but drops out
-# of the fit thread's fractions — the drop is the overlap the feed bought
-# (obs.report renders the across-thread total next to the in-loop share).
+# "h2d" is the host-to-device copy and its fence; stacking a chunk's K batches
+# (and the D2H wait on leaves that arrived as device arrays) is the separate
+# "stack" span, folded into "h2d" here (_GOODPUT_FOLD). Under
+# fit(scan_chunk=...) the device feed records both on the FEEDER thread, so
+# they appear in trace.json but drop out of the fit thread's fractions — the
+# drop is the overlap the feed bought (obs.report renders the across-thread
+# total next to the in-loop share).
 GOODPUT_SPANS = (
     "data_wait",
     "batch_build",
@@ -105,6 +135,16 @@ REQUEST_HOP_SPANS = (
     "backoff_wait",
     "hedge_wait",
 )
+
+# stage spans that split a goodput phase: their self time counts as the
+# parent phase's, so the fractions read what they read before the split
+# ("transform" runs inside the consumer's wait when no thread hides it)
+_GOODPUT_FOLD = {
+    "dispatch": "train_step",
+    "device_wait": "train_step",
+    "stack": "h2d",
+    "transform": "data_wait",
+}
 
 # the spans that make up the stepping pipeline: the denominator of the
 # input-starvation metric (time the step loop spent waiting on the batcher
@@ -207,15 +247,23 @@ class Tracer:
 
     :param enabled: ``False`` turns every :meth:`span` into a shared null
         context manager — the instrumentation stays in place at near-zero cost.
+    :param maxlen: how many span records the event store keeps (the newest;
+        ``None`` = unbounded). Per-name, per-thread running totals are kept
+        beside it, so :meth:`summary`, :meth:`snapshot` and the goodput
+        fractions stay exact after old records have fallen off; only
+        ``trace.json`` is then a tail of the run.
     """
 
-    def __init__(self, enabled: bool = True) -> None:
+    def __init__(self, enabled: bool = True, maxlen: Optional[int] = 65536) -> None:
         self.enabled = bool(enabled)
         self._clock = time.perf_counter
         self._t0 = self._clock()
         self._wall0 = time.time()
         self._lock = threading.Lock()
-        self._events: List[Dict[str, Any]] = []
+        self._events: Deque[Dict[str, Any]] = collections.deque(maxlen=maxlen)
+        # (tid, name) -> [count, seconds, self_seconds] over every span ever
+        # recorded, evicted ones included
+        self._totals: Dict[Tuple[int, str], List[float]] = {}
         self._local = threading.local()
 
     # -- span recording ----------------------------------------------------- #
@@ -249,8 +297,15 @@ class Tracer:
         span.record = record
         if stack:
             stack[-1].child_seconds += duration
+        self._record(record)
+
+    def _record(self, record: Dict[str, Any]) -> None:
         with self._lock:
             self._events.append(record)
+            total = self._totals.setdefault((record["tid"], record["name"]), [0, 0.0, 0.0])
+            total[0] += 1
+            total[1] += record["dur"]
+            total[2] += record["self"]
 
     def span(self, name: str, **args: Any):
         """Context manager timing the enclosed block as span ``name``.
@@ -270,17 +325,16 @@ class Tracer:
         if not self.enabled:
             return
         duration = max(float(duration_seconds), 0.0)
-        with self._lock:
-            self._events.append(
-                {
-                    "name": name,
-                    "tid": threading.get_ident(),
-                    "start": float(start_seconds),
-                    "dur": duration,
-                    "self": duration,
-                    "args": args,
-                }
-            )
+        self._record(
+            {
+                "name": name,
+                "tid": threading.get_ident(),
+                "start": float(start_seconds),
+                "dur": duration,
+                "self": duration,
+                "args": args,
+            }
+        )
 
     def carve(self, span: _Span, name: str, seconds: float, **args: Any) -> None:
         """Re-attribute ``seconds`` of a finished span's self time to ``name``.
@@ -297,16 +351,17 @@ class Tracer:
             return
         with self._lock:
             span.record["self"] -= seconds
-            self._events.append(
-                {
-                    "name": name,
-                    "tid": span.record["tid"],
-                    "start": span.record["start"],
-                    "dur": seconds,
-                    "self": seconds,
-                    "args": args,
-                }
-            )
+            self._totals[(span.record["tid"], span.record["name"])][2] -= seconds
+        self._record(
+            {
+                "name": name,
+                "tid": span.record["tid"],
+                "start": span.record["start"],
+                "dur": seconds,
+                "self": seconds,
+                "args": args,
+            }
+        )
 
     # -- aggregation -------------------------------------------------------- #
     def now(self) -> float:
@@ -331,17 +386,15 @@ class Tracer:
         """
         tid = threading.get_ident() if only_current_thread else None
         with self._lock:
-            events = list(self._events)
+            totals = [(key, tuple(total)) for key, total in self._totals.items()]
         out: Dict[str, Dict[str, float]] = {}
-        for event in events:
-            if tid is not None and event["tid"] != tid:
+        for (span_tid, name), (count, seconds, self_seconds) in totals:
+            if tid is not None and span_tid != tid:
                 continue
-            entry = out.setdefault(
-                event["name"], {"count": 0, "seconds": 0.0, "self_seconds": 0.0}
-            )
-            entry["count"] += 1
-            entry["seconds"] += event["dur"]
-            entry["self_seconds"] += event["self"]
+            entry = out.setdefault(name, {"count": 0, "seconds": 0.0, "self_seconds": 0.0})
+            entry["count"] += count
+            entry["seconds"] += seconds
+            entry["self_seconds"] += self_seconds
         return out
 
     def snapshot(self, only_current_thread: bool = False) -> Dict[str, float]:
@@ -355,7 +408,8 @@ class Tracer:
     # -- export ------------------------------------------------------------- #
     def to_chrome_trace(self) -> Dict[str, Any]:
         """Chrome trace-event JSON (the ``chrome://tracing`` / Perfetto format):
-        complete events (``ph="X"``) with microsecond ``ts``/``dur``."""
+        complete events (``ph="X"``) with microsecond ``ts``/``dur``, of the
+        records the store still holds (the newest ``maxlen``)."""
         with self._lock:
             events = list(self._events)
         pid = os.getpid()
@@ -386,6 +440,272 @@ class Tracer:
         with open(path, "w") as fh:
             json.dump(self.to_chrome_trace(), fh)
         return path
+
+
+# --------------------------------------------------------------------------- #
+# stage spans + the chunk stage log
+# --------------------------------------------------------------------------- #
+# the stages of the scan-chunked fit path, by the thread that runs them with
+# the device feed on (docs/observability.md "Stage spans"). The names are a
+# contract: the benchmark's per-layer readers and PERF.md go by them.
+CHUNK_STAGES = {
+    "fit": ("data_wait", "dispatch", "device_wait", "account"),
+    "feeder": ("batch_build", "transform", "stack", "h2d", "feed_full"),
+}
+_FEEDER_FIELDS = CHUNK_STAGES["feeder"]
+
+# one record per scan chunk, appended by the fit thread when the chunk's
+# metrics are on the host: 4096 chunks is a quarter of an hour of SASRec at
+# the ML-20M catalog. The lock is taken once per chunk (and by readers), never
+# per step.
+_CHUNK_LOG: Deque[Dict[str, Any]] = collections.deque(maxlen=4096)
+_CHUNK_LOG_LOCK = threading.Lock()
+_FIT_ORDINALS = itertools.count(1)
+
+# the Tracer that stages with no tracer of their own record into (a traced
+# fit attaches its tracer for its duration: Compose and the batcher then land
+# in trace.json without a constructor argument)
+_ATTACHED: Optional["Tracer"] = None
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+class _ThreadStages(threading.local):
+    """Seconds of the stages that ran on this thread: ``loose`` holds those of
+    spans that named no chunk since the last :func:`claim_chunk` (the input
+    pipeline does not know which chunk it fills), ``record`` is the chunk this
+    thread last claimed."""
+
+    def __init__(self) -> None:
+        self.loose: Dict[str, Any] = {}
+        self.record: Optional[Dict[str, Any]] = None
+
+
+_THREAD = _ThreadStages()
+
+
+def attach_tracer(tracer: Optional["Tracer"]) -> Optional["Tracer"]:
+    """Make ``tracer`` the one that :class:`stage` records into when it is
+    given none; returns the one attached before (hand it back to restore)."""
+    global _ATTACHED
+    previous, _ATTACHED = _ATTACHED, tracer
+    return previous
+
+
+def attached_tracer() -> Optional["Tracer"]:
+    return _ATTACHED
+
+
+class stage:  # noqa: N801 - used as ``with stage(name):``, like Tracer.span
+    """``with stage(name, **args):`` one boundary of the fit path, three things.
+
+    (a) always: a ``jax.profiler.TraceAnnotation(name, **args)``. With no
+    profiler session running that is well under a microsecond; when one runs
+    the span is in the capture's ``/host:CPU`` plane, one line per thread, on
+    the same clock as the device ops, ``args`` as the event's stats (so a span
+    argument cannot be called ``name``). Skipped until jax has been imported.
+    (b) always: its seconds are added to the calling thread's stage totals,
+    which :func:`claim_chunk` and :class:`ChunkStages` fold into the chunk
+    stage log (:func:`chunk_stage_log`).
+    (c) with an enabled :class:`Tracer` (``tracer=``, else the attached one):
+    the span is recorded there as ``tracer.span(name, **args)`` would; the live
+    span is :attr:`span` (for :meth:`Tracer.carve`).
+
+    After exit :attr:`seconds` is the duration and :attr:`end` the
+    ``perf_counter`` reading it closed at. A span whose args hold a key of its
+    own name (``stage("transform", transform="Mask")``) is also totalled by
+    that value under ``<name>_by_name``.
+    """
+
+    __slots__ = ("name", "args", "seconds", "end", "span", "_tracer", "_annotation", "_start")
+
+    def __init__(self, name: str, tracer: Optional["Tracer"] = None, **args: Any) -> None:
+        self.name = name
+        self.args = args
+        self.seconds = 0.0
+        self.end = 0.0
+        self.span: Optional[_Span] = None
+        self._tracer = tracer
+        self._annotation = None
+
+    def __enter__(self) -> "stage":
+        global _ANNOTATION
+        # the clock is the outermost: what the span itself costs (and a wait
+        # for the GIL that its own calls into the profiler let happen) is the
+        # stage's, so consecutive stages of a thread tile its time
+        self._start = time.perf_counter()
+        tracer = self._tracer if self._tracer is not None else _ATTACHED
+        if tracer is not None and tracer.enabled:
+            self.span = tracer.span(self.name, **self.args)
+            self.span.__enter__()
+        annotation = _ANNOTATION
+        if annotation is None:
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            if profiler is not None:
+                annotation = _ANNOTATION = profiler.TraceAnnotation
+        if annotation is not None:
+            self._annotation = annotation(self.name, **self.args)
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc_info) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc_info)
+        if self.span is not None:
+            self.span.__exit__(*exc_info)
+        self.end = time.perf_counter()
+        seconds = self.seconds = self.end - self._start
+        local = _THREAD
+        record = local.record
+        totals = (
+            record
+            if record is not None and record["chunk"] == self.args.get("chunk")
+            else local.loose
+        )
+        name = self.name
+        totals[name] = totals.get(name, 0.0) + seconds
+        key = self.args.get(name)
+        if key is not None:
+            by_name = totals.setdefault(name + "_by_name", {})
+            by_name[key] = by_name.get(key, 0.0) + seconds
+
+
+def claim_chunk(chunk: int) -> Dict[str, Any]:
+    """This thread now works for scan chunk ``chunk`` (the thread that stacks
+    the chunk calls this: the feeder, or the fit thread with the feed off).
+
+    Returns the chunk's record of this thread's stage seconds: everything the
+    thread's stages took since its previous claim without naming a chunk
+    (``batch_build``, ``transform``) is the new chunk's, and from here on
+    stages on this thread that carry ``chunk=chunk`` (``stack``, ``h2d``,
+    ``feed_full``) add to the same dict. It travels to the fit thread with the
+    placed chunk; the fit thread copies it into the log at the chunk's sync."""
+    local = _THREAD
+    record = local.record = local.loose
+    local.loose = {}
+    record["chunk"] = chunk
+    return record
+
+
+def claimed_chunk() -> Dict[str, int]:
+    """``{"chunk": n}`` for the chunk this thread last claimed, else ``{}``:
+    the args of a span that belongs to whatever chunk its thread is on."""
+    record = _THREAD.record
+    return {} if record is None else {"chunk": record["chunk"]}
+
+
+def chunk_stage_log() -> List[Dict[str, Any]]:
+    """The chunk stage log, oldest record first (copies: read-only).
+
+    One record per scan chunk of every ``fit(scan_chunk=...)`` of this
+    process, the newest 4096. A record covers one done-to-done period of the
+    fit thread: the ``account`` that followed the PREVIOUS chunk's sync, then
+    this chunk's ``data_wait``, ``dispatch`` and ``device_wait``. Fields:
+    ``chunk`` (ordinal within the fit), ``fit`` (ordinal of the fit call in
+    this process), ``steps``, ``done`` (``perf_counter`` at the sync's end),
+    ``period`` (since the previous ``done``; absent on the first chunk of an
+    epoch, whose past holds the epoch's end work), ``compiled``, the seconds
+    of the fit thread's ``data_wait``, ``dispatch``, ``device_wait``,
+    ``account`` and of the feeder's ``stack``, ``h2d``, ``feed_full``,
+    ``batch_build``, ``transform`` (total) with ``transform_by_name``,
+    ``device_leaves`` (leaves of the chunk's batches that arrived as jax
+    Arrays: each is a D2H read inside ``stack``) and ``h2d_bytes``.
+    Interleaved single steps (health cadence, an epoch's short tail) are in
+    the next chunk's ``period`` and in none of its stages.
+    """
+    with _CHUNK_LOG_LOCK:
+        return [dict(record) for record in _CHUNK_LOG]
+
+
+class ChunkStages:
+    """The fit thread's side of the chunk stage log, one per ``fit`` call.
+
+    ``for item in stages.feed(source)`` times every pull as ``data_wait`` and
+    closes the ``account`` span left open by the previous chunk;
+    ``stages.stage(name)`` is :class:`stage` with this fit's tracer and the
+    current chunk's ordinal; :meth:`synced` is called when the chunk's metrics
+    are on the host: it opens ``account`` (closed by the next pull or by
+    :meth:`close`), appends the chunk's record and moves to the next ordinal.
+    """
+
+    def __init__(self, tracer: Optional["Tracer"] = None) -> None:
+        self.fit = next(_FIT_ORDINALS)
+        self.tracer = tracer
+        self.chunk = 0
+        self._done: Optional[float] = None
+        self._account: Optional[stage] = None
+        self._account_seconds = 0.0
+        self._wait_seconds = 0.0
+        # what this thread's stages left over from earlier work is no chunk's
+        _THREAD.loose = {}
+        _THREAD.record = None
+
+    def stage(self, name: str, **args: Any) -> stage:
+        return stage(name, tracer=self.tracer, chunk=self.chunk, **args)
+
+    def close(self) -> None:
+        """Close the open ``account`` span, if any (the end of an epoch, or an
+        exit from the loop); the next chunk has no ``period``."""
+        if self._account is not None:
+            self._account.__exit__(None, None, None)
+            self._account_seconds += self._account.seconds
+            self._account = None
+
+    def new_epoch(self) -> None:
+        self.close()
+        self._done = None
+        self._account_seconds = self._wait_seconds = 0.0
+
+    def feed(self, source: Iterable[Any]) -> Iterator[Any]:
+        iterator = iter(source)
+        while True:
+            # drop the chunk just run while `account` is still open: freeing
+            # its device buffers is bookkeeping, not a hole between two stages
+            item = None
+            self.close()
+            with self.stage("data_wait") as wait:
+                try:
+                    item = next(iterator)
+                except StopIteration:
+                    return
+            self._wait_seconds += wait.seconds
+            yield item
+
+    def synced(
+        self,
+        steps: int,
+        dispatch: stage,
+        device_wait: stage,
+        compiled: bool,
+        feeder: Optional[Mapping[str, Any]] = None,
+    ) -> Dict[str, Any]:
+        self._account = self.stage("account")
+        self._account.__enter__()
+        done = device_wait.end
+        feeder = feeder or {}
+        record: Dict[str, Any] = {
+            "chunk": self.chunk,
+            "fit": self.fit,
+            "steps": int(steps),
+            "done": done,
+            "compiled": bool(compiled),
+            "data_wait": self._wait_seconds,
+            "dispatch": dispatch.seconds,
+            "device_wait": device_wait.seconds,
+            "account": self._account_seconds,
+        }
+        if self._done is not None:
+            record["period"] = done - self._done
+        for name in _FEEDER_FIELDS:
+            record[name] = float(feeder.get(name, 0.0))
+        record["transform_by_name"] = dict(feeder.get("transform_by_name", ()))
+        record["device_leaves"] = int(feeder.get("device_leaves", 0))
+        record["h2d_bytes"] = int(feeder.get("h2d_bytes", 0))
+        with _CHUNK_LOG_LOCK:
+            _CHUNK_LOG.append(record)
+        self._done = done
+        self._account_seconds = self._wait_seconds = 0.0
+        self.chunk += 1
+        return record
 
 
 def traced_iterator(
@@ -600,6 +920,17 @@ def goodput_breakdown(
     """
     spans = tuple(spans)
     wall = max(float(wall_seconds), 0.0)
+    folded = [
+        (child, parent)
+        for child, parent in _GOODPUT_FOLD.items()
+        if child in span_self_seconds and parent in spans and child not in spans
+    ]
+    if folded:
+        span_self_seconds = dict(span_self_seconds)
+        for child, parent in folded:
+            span_self_seconds[parent] = span_self_seconds.get(parent, 0.0) + max(
+                float(span_self_seconds[child]), 0.0
+            )
     fractions: Dict[str, float] = {}
     tracked = 0.0
     for name in spans:

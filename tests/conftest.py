@@ -69,3 +69,95 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(_pytest.mark.jax)
         else:
             item.add_marker(_pytest.mark.core)
+
+
+@pytest.fixture(scope="session")
+def _compile_cache_dir(tmp_path_factory):
+    return str(tmp_path_factory.mktemp("jax_compile_cache"))
+
+
+@pytest.fixture(scope="module")
+def shared_compile_cache(_compile_cache_dir):
+    """JAX's persistent compilation cache, in a directory of this session's own,
+    for the modules that ask for it (``pytestmark = pytest.mark.usefixtures(
+    "shared_compile_cache")``): their tests build many trainers of one tiny model,
+    each with jitted closures of its own, so every one traces and lowers the same
+    program again; with the cache XLA compiles it once a session and the others
+    load it. Tracing still happens, so ``compile_tracker`` counts what it counted.
+    Restored when the module ends: the compile-cache tests see their own settings."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    before = {name: getattr(jax.config, name) for name in names}
+    jax.config.update("jax_compilation_cache_dir", _compile_cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # JAX looks at the directory once, at the process's first compile, which an
+    # earlier module has made: have it look again, here and when the module ends
+    compilation_cache.reset_cache()
+    yield _compile_cache_dir
+    for name, value in before.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+class SharedPrograms:
+    """Trainers of ONE configuration run the same two jitted programs.
+
+    A test that builds three trainers of one tiny model to compare their results
+    traces and lowers ``train_step`` and ``train_scan`` three times, which is most
+    of its run time. ``adopt`` hands a new trainer the first one's jitted
+    functions: the model, loss, optimizer and mesh are the same, so the programs
+    are, and everything a trainer keeps to itself (state, history, rng, sinks)
+    stays its own. Not for a trainer whose ``compile_tracker`` counts are asserted,
+    whose programs a test rebuilds (an LR backoff, a health variant), or whose
+    configuration differs: those keep their own.
+    """
+
+    def __init__(self) -> None:
+        self.donor = None
+        self.params = {}  # (the model's repr, the seed) -> its fresh parameters
+
+    def adopt(self, trainer):
+        if self.donor is None:
+            self.donor = trainer
+        else:
+            trainer._train_step = self.donor._ensure_train_step()
+            trainer._train_scan = self.donor._ensure_train_scan()
+        return self.share_init(trainer)
+
+    def share_init(self, trainer):
+        """Every trainer of one model and seed starts from the SAME fresh
+        parameters, made once: flax's init dispatched eagerly is some ninety small
+        programs a trainer, under ``jax.jit`` it is one, and the trainers that
+        follow get a copy (the seed and the model are the same, so the init would
+        be)."""
+        import jax
+
+        init_state = trainer.init_state
+        key = (repr(trainer.model), trainer.seed)
+
+        def init_from_shared(example_batch, params=None):
+            if params is None:
+                if key not in self.params:
+                    self.params[key] = jax.device_get(
+                        jax.jit(trainer._init_params)(example_batch)
+                    )
+                params = self.params[key]
+            return init_state(example_batch, params=params)
+
+        trainer.init_state = init_from_shared
+        return trainer
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _shared_programs(request):
+    """A test module that declares ``PROGRAMS = None`` gets its own
+    :class:`SharedPrograms` there before its first test."""
+    if hasattr(request.module, "PROGRAMS"):
+        request.module.PROGRAMS = SharedPrograms()

@@ -21,6 +21,9 @@ from replay_tpu.nn.sequential.sasrec import SasRec
 from replay_tpu.obs import HealthConfig
 from replay_tpu.utils.faults import NaNInjector, SignalAtStep
 
+# one tiny model, many trainers: XLA compiles each program once a session
+pytestmark = pytest.mark.usefixtures("shared_compile_cache")
+
 NUM_ITEMS = 12
 SEQ_LEN = 8
 BATCH = 8  # divisible by the 8-device data axis
@@ -61,15 +64,25 @@ def make_batch(seed: int) -> dict:
     }
 
 
-def make_trainer(**kwargs) -> Trainer:
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
+def make_trainer(own_programs: bool = False, **kwargs) -> Trainer:
+    """A trainer of the module's one tiny model. The plain ones run the same two
+    programs and share them (traced and lowered once a module); ``own_programs``
+    is for a trainer whose compile counts are asserted or whose programs the test
+    rebuilds (recovery's LR backoff), and a configured one (``health=``) differs."""
     model = SasRec(
         schema=make_schema(), embedding_dim=16, num_blocks=1, num_heads=1,
         max_sequence_length=SEQ_LEN,
     )
-    return Trainer(
+    trainer = Trainer(
         model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2),
         mesh=make_mesh(), **kwargs,
     )
+    if own_programs or kwargs:
+        return PROGRAMS.share_init(trainer)  # the same model: the same fresh parameters
+    return PROGRAMS.adopt(trainer)
 
 
 class EventSink:
@@ -112,7 +125,7 @@ def test_chunked_fit_bitwise_parity_including_tail():
     sink_a = EventSink()
     state_a = per_step.fit(batches, epochs=2, loggers=sink_a, log_every=0)
 
-    chunked = make_trainer()
+    chunked = make_trainer(own_programs=True)  # its compile counts are asserted
     sink_b = EventSink()
     state_b = chunked.fit(batches, epochs=2, loggers=sink_b, log_every=0, scan_chunk=3)
 
@@ -185,7 +198,7 @@ def test_recovery_trigger_at_chunk_boundary_bitwise_parity():
 
     def run(scan_chunk):
         injector = NaNInjector(at_steps=(4, 5))
-        trainer = make_trainer()
+        trainer = make_trainer(own_programs=True)  # the LR backoff rebuilds them
         sink = EventSink()
         state = trainer.fit(
             lambda epoch: injector.wrap([make_batch(i) for i in range(9)]),
@@ -211,7 +224,7 @@ def test_recovery_mid_chunk_discards_rest_of_chunk():
     remaining (already-executed, pre-rollback) steps of the chunk are consumed
     but not accounted, and the run continues finite on the restored state."""
     injector = NaNInjector(at_steps=(3, 4))  # steps 4, 5 — mid-chunk of 4-6
-    trainer = make_trainer()
+    trainer = make_trainer(own_programs=True)  # the LR backoff rebuilds them
     sink = EventSink()
     state = trainer.fit(
         lambda epoch: injector.wrap([make_batch(i) for i in range(7)]),

@@ -32,6 +32,9 @@ from replay_tpu.nn.transform.template import make_default_sasrec_transforms
 from replay_tpu.obs import JsonlLogger, SLORule, Tracer
 from replay_tpu.utils.checkpoint import CheckpointManager
 
+# one tiny model, many trainers: XLA compiles each program once a session
+pytestmark = pytest.mark.usefixtures("shared_compile_cache")
+
 NUM_ITEMS = 30
 SEQ_LEN = 7  # -> [B, 6] training batches
 BATCH = 8
@@ -75,16 +78,23 @@ def stream_parquet(tmp_path_factory):
     return path
 
 
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
 def make_trainer():
+    """A trainer of the module's one tiny model: each keeps its own state and
+    history, all run the same two programs (traced and lowered once a module)."""
     schema = make_schema()
     model = SasRec(
         schema=schema, embedding_dim=8, num_blocks=1, num_heads=1,
         max_sequence_length=SEQ_LEN - 1, dropout_rate=0.0,
     )
-    return Trainer(
-        model=model, loss=CE(),
-        optimizer=OptimizerFactory(learning_rate=1e-2),
-        mesh=make_mesh(), seed=0,
+    return PROGRAMS.adopt(
+        Trainer(
+            model=model, loss=CE(),
+            optimizer=OptimizerFactory(learning_rate=1e-2),
+            mesh=make_mesh(), seed=0,
+        )
     )
 
 

@@ -17,6 +17,9 @@ import pytest
 
 from replay_tpu.obs import GOODPUT_SPANS, Tracer, goodput_breakdown, traced_iterator
 
+# one tiny model, many trainers: XLA compiles each program once a session
+pytestmark = pytest.mark.usefixtures("shared_compile_cache")
+
 
 # --------------------------------------------------------------------------- #
 # tracer core (host-only)
@@ -340,7 +343,13 @@ def test_traced_fit_writes_valid_trace_and_goodput(tmp_path):
     assert trainer.compile_tracker.traces["train_step"] == 1
 
 
-def _tiny_trainer(embedding_dim=8):
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
+def _tiny_trainer(embedding_dim=8, own_programs=False):
+    """A trainer of the module's tiny model and a batch maker. The trainers run
+    the same programs and share them (traced and lowered once a module), but for
+    one whose compile counts a test asserts (``own_programs``)."""
     from replay_tpu.data import FeatureHint, FeatureType
     from replay_tpu.data.nn import TensorFeatureInfo, TensorSchema
     from replay_tpu.nn import OptimizerFactory, Trainer, make_mesh
@@ -357,6 +366,10 @@ def _tiny_trainer(embedding_dim=8):
                    num_heads=1, max_sequence_length=seq_len)
     trainer = Trainer(model=model, loss=CE(),
                       optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh())
+    if own_programs:
+        PROGRAMS.share_init(trainer)  # the same model: the same fresh parameters
+    else:
+        PROGRAMS.adopt(trainer)
 
     def make_batch(seed):
         rng = np.random.default_rng(seed)
@@ -458,8 +471,10 @@ def test_untraced_fit_emits_no_goodput():
     )
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, num_heads=1,
                    max_sequence_length=seq_len)
-    trainer = Trainer(model=model, loss=CE(),
-                      optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh())
+    trainer = PROGRAMS.adopt(  # the tiny model's programs, as _tiny_trainer's
+        Trainer(model=model, loss=CE(),
+                optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh())
+    )
     rng = np.random.default_rng(1)
     items = rng.integers(0, num_items, size=(8, seq_len + 1)).astype(np.int32)
     mask = np.ones((8, seq_len), dtype=bool)
@@ -487,7 +502,7 @@ def test_traced_chunked_fit_goodput_sums_and_h2d_overlaps(tmp_path):
     chunked train_step spans carry their per-step attribution (steps=K)."""
     from replay_tpu.obs import JsonlLogger
 
-    trainer, make_batch = _tiny_trainer()
+    trainer, make_batch = _tiny_trainer(own_programs=True)  # its compile counts are asserted
     batches = [make_batch(i) for i in range(7)]  # two K=3 chunks + one tail step
 
     run_dir = _run_dir(tmp_path, "chunked_smoke")
@@ -602,3 +617,185 @@ def test_training_goodput_still_reports_starvation():
     spans = {"data_wait": 0.2, "train_step": 0.6}
     breakdown = goodput_breakdown(spans, 1.0)
     assert breakdown["input_starvation"] == pytest.approx(0.25)
+
+
+# --------------------------------------------------------------------------- #
+# the bound, the stage helper and the chunk stage log (host-only but for the
+# profiler capture)
+# --------------------------------------------------------------------------- #
+def _fake_clock(tracer):
+    """A clock that advances 1 ms a reading: two tracers read the same times."""
+    ticks = iter(range(10**9))
+    tracer._clock = lambda: next(ticks) * 1e-3
+    tracer._t0 = 0.0
+
+
+def test_bounded_tracer_evicts_records_and_keeps_totals_exact():
+    bounded, unbounded = Tracer(maxlen=8), Tracer(maxlen=None)
+    for tracer in (bounded, unbounded):
+        _fake_clock(tracer)
+        for i in range(50):
+            with tracer.span("train_step", i=i) as step:
+                with tracer.span("data_wait"):
+                    pass
+            if i % 10 == 0:  # carve after the parent's record may have been evicted
+                tracer.carve(step, "compile", 0.001)
+    assert len(bounded.to_chrome_trace()["traceEvents"]) == 8
+    assert len(unbounded.to_chrome_trace()["traceEvents"]) == 105
+    # the newest records are the ones kept
+    kept = [e["args"]["i"] for e in bounded.to_chrome_trace()["traceEvents"] if "args" in e]
+    assert kept == [46, 47, 48, 49]
+    assert bounded.summary() == unbounded.summary()
+    assert bounded.summary()["train_step"]["count"] == 50
+    assert bounded.summary(only_current_thread=True) == unbounded.summary(only_current_thread=True)
+    assert bounded.snapshot() == unbounded.snapshot()
+    wall = 1.0
+    assert goodput_breakdown(bounded.snapshot(), wall) == goodput_breakdown(unbounded.snapshot(), wall)
+    assert goodput_breakdown(bounded.snapshot(), wall)["fractions"]["compile"] == pytest.approx(0.005)
+    assert Tracer()._events.maxlen == 65536  # bounded unless asked otherwise
+
+
+def test_stage_with_no_tracer_allocates_no_event():
+    from replay_tpu.obs.trace import attached_tracer, claim_chunk, stage
+
+    assert attached_tracer() is None
+    bystander, disabled = Tracer(), Tracer(enabled=False)
+    claim_chunk(-1)  # what this thread's stages held before is not this test's
+    with stage("dispatch") as plain:
+        pass
+    with stage("dispatch", tracer=disabled) as off:
+        pass
+    assert plain.span is None and off.span is None
+    assert bystander.summary() == {} and disabled.summary() == {}
+    assert len(bystander._events) == len(disabled._events) == 0
+    # the seconds are kept all the same: the stage log's side
+    assert plain.seconds >= 0 and plain.end >= plain.seconds
+    assert claim_chunk(-2)["dispatch"] == pytest.approx(plain.seconds + off.seconds)
+
+
+def test_stage_records_into_its_tracer_else_the_attached_one():
+    from replay_tpu.obs.trace import attach_tracer, stage
+
+    own, attached = Tracer(), Tracer()
+    previous = attach_tracer(attached)
+    try:
+        with stage("batch_build"):
+            pass
+        with stage("batch_build", tracer=own, rows=4) as explicit:
+            pass
+    finally:
+        assert attach_tracer(previous) is attached
+    assert attached.summary()["batch_build"]["count"] == 1
+    assert own.summary()["batch_build"]["count"] == 1  # an explicit tracer wins
+    assert explicit.span.record["args"] == {"rows": 4}
+    with stage("batch_build"):
+        pass
+    assert attached.summary()["batch_build"]["count"] == 1  # detached: no more
+
+
+def test_stage_annotation_is_in_the_profiler_capture(tmp_path):
+    """Under a profiler session a stage is in the capture's host plane under its
+    name, its args as the event's stats, one line per thread."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from replay_tpu.obs.trace import stage
+
+    def feeder():
+        with stage("transform", transform="TokenMaskTransform"):
+            time.sleep(0.001)
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level, options.host_tracer_level = 0, 1  # the benchmark's options
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with stage("data_wait", chunk=3):
+            time.sleep(0.001)
+        thread = threading.Thread(target=feeder)
+        thread.start()
+        thread.join()
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    host = [p for p in ProfileData.from_file(str(xplane)).planes if p.name == "/host:CPU"]
+    assert len(host) == 1
+    found = {}
+    for index, line in enumerate(host[0].lines):
+        for event in line.events:
+            if event.name in ("data_wait", "transform"):
+                found[event.name] = (index, {k: v for k, v in event.stats}, event.duration_ns)
+    assert found["data_wait"][1] == {"chunk": 3}
+    assert found["transform"][1] == {"transform": "TokenMaskTransform"}
+    assert found["data_wait"][0] != found["transform"][0]  # two threads, two lines
+    assert all(duration >= 1e6 for _, _, duration in found.values())
+
+
+def test_goodput_folds_the_stage_children_into_their_phase():
+    """dispatch + device_wait read as train_step, stack as h2d, transform as
+    data_wait: the fractions are what one span a phase gave."""
+    split = {"train_step": 0.01, "dispatch": 0.2, "device_wait": 0.3, "h2d": 0.05,
+             "stack": 0.1, "data_wait": 0.05, "transform": 0.1, "account": 0.1}
+    whole = {"train_step": 0.51, "h2d": 0.15, "data_wait": 0.15}
+    folded = goodput_breakdown(split, 1.0)
+    assert folded["fractions"] == pytest.approx(goodput_breakdown(whole, 1.0)["fractions"])
+    assert folded["input_starvation"] == pytest.approx(goodput_breakdown(whole, 1.0)["input_starvation"])
+    assert set(folded["fractions"]) == set(GOODPUT_SPANS) | {"other"}
+    assert folded["fractions"]["other"] == pytest.approx(0.19)  # account is the loop's own
+
+
+def test_chunk_stages_tile_the_fit_threads_time_and_carry_the_feeders():
+    """The fit thread's side of the stage log against a fake feed: one record a
+    chunk, the four stages sum to the done-to-done period, the feeder's record
+    travels with the chunk, a new epoch starts a new period."""
+    import queue
+
+    from replay_tpu.obs.trace import ChunkStages, chunk_stage_log, claim_chunk, claimed_chunk, stage
+
+    buffer = queue.Queue(maxsize=1)
+
+    def feeder():
+        for chunk in range(4):
+            for _ in range(2):
+                with stage("batch_build"):
+                    time.sleep(0.002)
+                with stage("transform", transform="A"):
+                    time.sleep(0.001)
+            record = claim_chunk(chunk)
+            record["device_leaves"] = 2
+            with stage("stack", chunk=chunk):
+                time.sleep(0.001)
+            with stage("feed_full", **claimed_chunk()):
+                buffer.put(record)
+        buffer.put(None)
+
+    thread = threading.Thread(target=feeder)
+    thread.start()
+    stages = ChunkStages()
+    for record in stages.feed(iter(buffer.get, None)):
+        with stages.stage("dispatch") as dispatch:
+            time.sleep(0.002)
+        with stages.stage("device_wait") as device_wait:
+            time.sleep(0.05)
+        stages.synced(2, dispatch, device_wait, compiled=stages.chunk == 0, feeder=record)
+        time.sleep(0.005)  # bookkeeping: `account` is open
+        if stages.chunk == 2:
+            stages.new_epoch()
+    stages.close()
+    thread.join()
+    # by the fit's ordinal, not by position: the log is a ring
+    records = [r for r in chunk_stage_log() if r["fit"] == stages.fit]
+    assert [r["chunk"] for r in records] == [0, 1, 2, 3]
+    assert [r["compiled"] for r in records] == [True, False, False, False]
+    assert ["period" in r for r in records] == [False, True, False, True]
+    for record in records:
+        assert record["steps"] == 2 and record["device_leaves"] == 2
+        assert record["batch_build"] >= 0.004 and record["stack"] >= 0.001
+        assert record["transform_by_name"] == {"A": pytest.approx(record["transform"])}
+        assert record["device_wait"] >= 0.05 and record["dispatch"] >= 0.002
+    for record in (records[1], records[3]):
+        assert record["account"] >= 0.005
+        tiled = sum(record[k] for k in ("data_wait", "dispatch", "device_wait", "account"))
+        assert tiled == pytest.approx(record["period"], rel=0.03)
+    # the feeder waited on the full queue while the fit thread ran chunk 1
+    assert records[2]["feed_full"] >= 0.01
+    assert ChunkStages().fit == stages.fit + 1  # the next fit call's ordinal
