@@ -692,7 +692,7 @@ class Trainer:
         # {name: (jitted_fn, abstract arg templates)} — ShapeDtypeStruct
         # snapshots (shape/dtype/sharding, no buffers) of every dispatched
         # program's arguments, recorded once at first dispatch so the static
-        # analyses (obs.roofline / obs.profile) can re-lower the EXACT
+        # analyses (obs.roofline) can re-lower the EXACT
         # programs later without holding donated state alive
         self._programs: Dict[str, Tuple[Any, Tuple[Any, ...]]] = {}
         self._eval_logits = None
@@ -798,7 +798,7 @@ class Trainer:
 
         return scoped
 
-    # -- program introspection (obs.profile / obs.roofline) ----------------- #
+    # -- program introspection (obs.roofline) ------------------------------ #
     def _record_template(self, name: str, jitted_fn, *args) -> None:
         """Snapshot a dispatched program's argument shapes/dtypes/shardings
         (once per name; no device buffers are retained)."""
@@ -849,40 +849,6 @@ class Trainer:
             if record is not None:
                 out[name] = record
         return out
-
-    def _profile_payload(self, profile_dir: str) -> Dict[str, Any]:
-        """Post-capture analysis for a profiled fit: the per-named-scope
-        device-time attribution (obs.profile) joined against THIS trainer's
-        compiled programs, plus their roofline records — one re-compile per
-        program, shared by both analyses. Best-effort: a missing capture or
-        an analysing-free backend degrades to a partial payload with a logged
-        warning, never a failed fit."""
-        from replay_tpu.obs.mfu import program_costs
-        from replay_tpu.obs.profile import attribute_capture
-        from replay_tpu.obs.roofline import analyze_costs
-
-        payload: Dict[str, Any] = {}
-        mesh_shape = {axis: int(n) for axis, n in self.mesh.shape.items()}
-        texts: Dict[str, str] = {}
-        rooflines: Dict[str, Any] = {}
-        for name, (jitted, templates) in self._programs.items():
-            costs = program_costs(jitted, *templates)
-            if costs is None:
-                continue
-            if costs.get("hlo_text"):
-                texts[name] = costs["hlo_text"]
-            record = analyze_costs(costs, mesh_shape=mesh_shape)
-            if record is not None:
-                rooflines[name] = record
-        try:
-            payload["device_time"] = attribute_capture(profile_dir, texts)
-        except (OSError, ValueError) as exc:
-            logger.warning(
-                "device-time attribution failed for %s: %s", profile_dir, exc
-            )
-        if rooflines:
-            payload["roofline"] = rooflines
-        return payload
 
     # -- train ------------------------------------------------------------- #
     def _build_train_step(self, health: Optional[HealthConfig] = None):
@@ -1400,14 +1366,12 @@ class Trainer:
         ``profile_steps=(start, stop)`` captures a ``jax.profiler`` trace of
         the half-open step window [start, stop) — counted over steps actually
         executed by this fit call — into ``profile_dir`` (default: the first
-        JsonlLogger's ``run_dir/profile``, else ``./jax_profile``). The
-        capture is then parsed (``obs.profile``): per-``jax.named_scope``
-        DEVICE-time attribution (embed/encoder/final_norm/forward/loss) rides
-        ``on_fit_end`` as a ``device_time`` payload, next to a per-program
-        ``roofline`` record (``obs.roofline``: memory- vs compute-bound with
-        the predicted ceiling, static HBM footprint, collective bytes) —
-        rendered by ``obs.report`` as the "device attribution" and "roofline"
-        sections (docs/performance.md "Attribution and roofline").
+        JsonlLogger's ``run_dir/profile``, else ``./jax_profile``). A
+        profiled fit's ``on_fit_end`` also carries a per-program ``roofline``
+        record (``obs.roofline``: memory- vs compute-bound with the predicted
+        ceiling, static HBM footprint, collective bytes), rendered by
+        ``obs.report`` as the "roofline" section (docs/performance.md
+        "Roofline").
 
         ``checkpoint_every`` additionally saves MID-epoch every that many steps,
         recording the data-iterator position (epoch + step within the epoch) in
@@ -2052,13 +2016,12 @@ class Trainer:
             if profile_capture_dir is not None:
                 if profile_active:
                     # a window still open (fit ended/preempted inside it):
-                    # finalize the capture so the attribution reads real data
+                    # finalize the capture
                     profile_stack.close()
                     profile_active = False
-                # per-scope DEVICE-time attribution + per-program roofline
-                # (obs.profile / obs.roofline) — the on-chip half of the
-                # goodput story, joined against this fit's compiled programs
-                payload.update(self._profile_payload(profile_capture_dir))
+                rooflines = self.analyze_programs()
+                if rooflines:
+                    payload["roofline"] = rooflines
             return payload
 
         emit(

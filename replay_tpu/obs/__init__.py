@@ -11,8 +11,7 @@ throughput (:class:`StepTelemetry`) and achieved-vs-peak FLOPs (:mod:`.mfu`).
 wall-clock go BETWEEN steps — ``trace.json`` + per-epoch phase fractions),
 :mod:`.health` computes in-graph model-health diagnostics (per-group norms and
 update ratios, activation stats, attention entropy, the ``HealthWatcher``
-early warning), :mod:`.profile` parses ``jax.profiler`` captures into
-per-``named_scope`` DEVICE-time attribution, :mod:`.roofline` classifies every
+early warning), :mod:`.roofline` classifies every
 compiled program memory- vs compute-bound against the chip's peak FLOPs/
 bandwidth tables (with HBM footprint + collective-bytes introspection via
 :mod:`replay_tpu.parallel.introspect`), and :mod:`.report` is the run-report
@@ -63,7 +62,6 @@ from .mfu import (
     peak_tflops,
     program_costs,
 )
-from .profile import NAMED_SCOPES, attribute_capture, latest_capture, scope_of
 from .roofline import (
     PEAK_HBM_GBPS,
     analyze_program,
@@ -103,7 +101,6 @@ __all__ = [
     "MetricsLogger",
     "MetricsRegistry",
     "MultiLogger",
-    "NAMED_SCOPES",
     "REQUEST_HOP_SPANS",
     "SLORule",
     "SLOWatchdog",
@@ -120,7 +117,6 @@ __all__ = [
     "Tracer",
     "TrainerEvent",
     "analyze_program",
-    "attribute_capture",
     "canary_quality_rules",
     "chunk_stage_log",
     "classify",
@@ -130,7 +126,6 @@ __all__ = [
     "flops_per_step",
     "goodput_breakdown",
     "health_metrics",
-    "latest_capture",
     "lifecycle_span",
     "merge_traces",
     "mfu",
@@ -141,7 +136,6 @@ __all__ = [
     "prequential_scores",
     "program_costs",
     "read_flight",
-    "scope_of",
     "scrape_snapshot",
     "stage",
     "tail_attribution",

@@ -557,13 +557,3 @@ class ConsoleLogger(RunLogger):
                 if k in event.payload
             }
             logger.info("fit complete: %s", summary)
-            device_time = event.payload.get("device_time")
-            if isinstance(device_time, Mapping) and device_time.get("scopes"):
-                logger.info(
-                    "device attribution: %s",
-                    " ".join(
-                        f"{scope}={100.0 * float(entry.get('fraction', 0.0)):.1f}%"
-                        for scope, entry in device_time["scopes"].items()
-                        if isinstance(entry, Mapping)
-                    ),
-                )

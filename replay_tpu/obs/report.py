@@ -8,10 +8,9 @@
 Turns the telemetry artifacts every trainer/bench/dry run leaves behind into
 the one-page answer "Demystifying BERT" (PAPERS.md) says a profile must
 become: throughput, MFU, the goodput breakdown (where wall-clock went between
-steps), the DEVICE-time attribution (obs.profile: per-``named_scope`` on-chip
-time from a profiled fit) and per-program roofline records (obs.roofline:
-memory- vs compute-bound, predicted ceiling, HBM footprint, collective
-bytes), retraces, bad/recovered steps, the model-health record
+steps), per-program roofline records (obs.roofline: memory- vs
+compute-bound, predicted ceiling, HBM footprint, collective bytes), retraces,
+bad/recovered steps, the model-health record
 (obs.health: per-group norms/update ratios, activation stats, attention
 entropy, early warnings), and the serving summary (replay_tpu.serve /
 bench_serve.py: QPS, latency percentiles, batch fill, cache hit rate, plus
@@ -383,12 +382,8 @@ def summarize_events(
 
     fit_end = fit_ends[-1] if fit_ends else {}
     telemetry = fit_end.get("telemetry") or {}
-    # on-chip observability (obs.profile / obs.roofline): the per-named-scope
-    # device-time attribution and per-program roofline records a profiled fit
-    # attaches to its terminal event
-    summary["device_time"] = (
-        dict(fit_end["device_time"]) if isinstance(fit_end.get("device_time"), Mapping) else None
-    )
+    # obs.roofline: the per-program records a profiled fit attaches to its
+    # terminal event
     summary["roofline"] = (
         dict(fit_end["roofline"]) if isinstance(fit_end.get("roofline"), Mapping) else None
     )
@@ -1177,22 +1172,6 @@ def render(summary: Mapping[str, Any]) -> str:
             f"{name} {entry['seconds']:.2f}s x{entry['count']}" for name, entry in top
         )
         lines.append(f"  trace.json: {sum(e['count'] for e in trace.values())} span(s): {shown}")
-    device_time = summary.get("device_time")
-    if device_time:
-        total = _finite(device_time.get("total_device_seconds")) or 0.0
-        scopes = device_time.get("scopes") or {}
-        parts = [
-            f"{scope} {100.0 * float((entry or {}).get('fraction', 0.0)):.1f}%"
-            for scope, entry in scopes.items()
-            if isinstance(entry, Mapping)
-        ]
-        unattributed = _finite(device_time.get("unattributed_seconds"))
-        if unattributed is not None and total > 0:
-            parts.append(f"unattributed {100.0 * unattributed / total:.1f}%")
-        lines.append(
-            f"  device attribution ({1000.0 * total:.1f} ms device time in the "
-            "profiled window): " + (" · ".join(parts) if parts else "no scopes resolved")
-        )
     roofline = summary.get("roofline")
     if roofline:
         lines.append("  roofline:")

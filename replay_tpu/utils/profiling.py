@@ -24,8 +24,8 @@ def trace(log_dir: str, *, create_perfetto_link: bool = False,
     ``python_tracer_level=0`` (the default) keeps python-frame events OUT of
     the capture: a busy host loop (the scan-chunked fit's feeder + accounting
     threads) emits millions of them, flooding the profiler's event cap and
-    dropping the XLA op events that ``obs.profile``'s device-time attribution
-    needs. jax's public ``start_trace`` pins the level to 1, so when the
+    dropping the XLA op events a reader of the capture needs. jax's public
+    ``start_trace`` pins the level to 1, so when the
     xla_client ProfileOptions API is available the session is driven directly
     (same export layout); otherwise this degrades to the public API.
     """

@@ -71,38 +71,24 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(_pytest.mark.core)
 
 
-@pytest.fixture(scope="session")
-def _compile_cache_dir(tmp_path_factory):
-    return str(tmp_path_factory.mktemp("jax_compile_cache"))
-
-
-@pytest.fixture(scope="module")
-def shared_compile_cache(_compile_cache_dir):
+@pytest.fixture(scope="session", autouse=True)
+def shared_compile_cache(tmp_path_factory):
     """JAX's persistent compilation cache, in a directory of this session's own,
-    for the modules that ask for it (``pytestmark = pytest.mark.usefixtures(
-    "shared_compile_cache")``): their tests build many trainers of one tiny model,
+    for every test: the suite builds hundreds of trainers of a few tiny models,
     each with jitted closures of its own, so every one traces and lowers the same
     program again; with the cache XLA compiles it once a session and the others
     load it. Tracing still happens, so ``compile_tracker`` counts what it counted.
-    Restored when the module ends: the compile-cache tests see their own settings."""
+    A test that asserts on the cache's own settings sets and restores its own
+    (``tests/ops/test_tpu_compile.py`` turns the cache off around its compiles)."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
-    names = (
-        "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes",
-    )
-    before = {name: getattr(jax.config, name) for name in names}
-    jax.config.update("jax_compilation_cache_dir", _compile_cache_dir)
+    cache_dir = tmp_path_factory.mktemp("jax_compile_cache")
+    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    # JAX looks at the directory once, at the process's first compile, which an
-    # earlier module has made: have it look again, here and when the module ends
-    compilation_cache.reset_cache()
-    yield _compile_cache_dir
-    for name, value in before.items():
-        jax.config.update(name, value)
+    # JAX looks at the directory once, at the process's first compile: have it
+    # look again in case something compiled while the tests were collected
     compilation_cache.reset_cache()
 
 
@@ -111,24 +97,28 @@ class SharedPrograms:
 
     A test that builds three trainers of one tiny model to compare their results
     traces and lowers ``train_step`` and ``train_scan`` three times, which is most
-    of its run time. ``adopt`` hands a new trainer the first one's jitted
-    functions: the model, loss, optimizer and mesh are the same, so the programs
-    are, and everything a trainer keeps to itself (state, history, rng, sinks)
-    stays its own. Not for a trainer whose ``compile_tracker`` counts are asserted,
-    whose programs a test rebuilds (an LR backoff, a health variant), or whose
-    configuration differs: those keep their own.
+    of its run time. ``adopt`` hands a new trainer the jitted functions of the
+    first trainer adopted under the same ``key``: the model, loss, optimizer,
+    mesh, precision and health are the same, so the programs are, and everything
+    a trainer keeps to itself (state, history, rng, sinks) stays its own. A module
+    with several configurations gives each a ``key`` (whatever tells them apart:
+    the mesh, the attention route, the precision). Not for a trainer whose
+    ``compile_tracker`` counts are asserted, whose programs a test rebuilds (an
+    LR backoff, a vocabulary resize), or whose configuration no key names: those
+    keep their own.
     """
 
     def __init__(self) -> None:
-        self.donor = None
+        self.programs = {}  # key -> the first such trainer's (train_step, train_scan)
         self.params = {}  # (the model's repr, the seed) -> its fresh parameters
 
-    def adopt(self, trainer):
-        if self.donor is None:
-            self.donor = trainer
+    def adopt(self, trainer, key=None):
+        if key not in self.programs:
+            # taken now: what the first trainer does to itself later (an LR
+            # backoff rebuilds its programs) is not handed on
+            self.programs[key] = (trainer._ensure_train_step(), trainer._ensure_train_scan())
         else:
-            trainer._train_step = self.donor._ensure_train_step()
-            trainer._train_scan = self.donor._ensure_train_scan()
+            trainer._train_step, trainer._train_scan = self.programs[key]
         return self.share_init(trainer)
 
     def share_init(self, trainer):
@@ -158,6 +148,11 @@ class SharedPrograms:
 @pytest.fixture(scope="module", autouse=True)
 def _shared_programs(request):
     """A test module that declares ``PROGRAMS = None`` gets its own
-    :class:`SharedPrograms` there before its first test."""
-    if hasattr(request.module, "PROGRAMS"):
+    :class:`SharedPrograms` there before its first test, and lets it (and the
+    executables it holds) go after its last."""
+    shares = hasattr(request.module, "PROGRAMS")
+    if shares:
         request.module.PROGRAMS = SharedPrograms()
+    yield
+    if shares:
+        request.module.PROGRAMS = None

@@ -43,14 +43,20 @@ def make_raw_batch(rng: np.random.Generator):
     return {"item_id": items, "item_id_mask": items != NUM_ITEMS}
 
 
+# this module's SharedPrograms, set by tests/conftest.py: one trainer, so nothing
+# to share but the flax init under jax.jit
+PROGRAMS = None
+
+
 @pytest.fixture(scope="module")
 def trained(schema):
     rng = np.random.default_rng(0)
     pipeline = Compose(make_default_bert4rec_transforms(schema, mask_prob=0.3)["train"])
     model = Bert4Rec(schema=schema, embedding_dim=16, num_blocks=1, num_heads=2,
                      max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(),
-                      optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh())
+    trainer = PROGRAMS.share_init(Trainer(
+        model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh()
+    ))
     key = jax.random.PRNGKey(0)
     state, losses = None, []
     raw_batches = [make_raw_batch(rng) for _ in range(6)]

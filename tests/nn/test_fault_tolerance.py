@@ -81,15 +81,24 @@ def make_batch(seed: int) -> dict:
     }
 
 
-def make_trainer() -> Trainer:
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
+def make_trainer(own_programs: bool = False) -> Trainer:
+    """A trainer of the module's one tiny model. The plain ones share their two
+    programs (traced and lowered once a module); ``own_programs`` is for a
+    trainer whose fit rebuilds them (a RecoveryPolicy's LR backoff)."""
     model = SasRec(
         schema=make_schema(), embedding_dim=16, num_blocks=1, num_heads=1,
         max_sequence_length=SEQ_LEN,
     )
-    return Trainer(
+    trainer = Trainer(
         model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2),
         mesh=make_mesh(),
     )
+    if own_programs:
+        return PROGRAMS.share_init(trainer)  # the same model: the same fresh parameters
+    return PROGRAMS.adopt(trainer)
 
 
 class EventSink:
@@ -194,7 +203,7 @@ def test_detect_anomalies_defaults_off_without_loggers_or_recovery():
 @pytest.mark.smoke
 def test_recovery_rolls_back_to_checkpoint_with_lr_backoff(tmp_path):
     injector = NaNInjector(at_steps=(3, 4, 5))  # >= max_consecutive_bad in a row
-    trainer = make_trainer()
+    trainer = make_trainer(own_programs=True)
     manager = CheckpointManager(str(tmp_path / "run"), max_to_keep=10)
     sink = EventSink()
     state = trainer.fit(
@@ -225,7 +234,7 @@ def test_recovery_budget_exhausted_raises():
     instead of burning the remaining budget (no checkpoint manager → rollback
     targets the initial-state snapshot)."""
     injector = NaNInjector(at_steps=range(2, 10))
-    trainer = make_trainer()
+    trainer = make_trainer(own_programs=True)
     with pytest.raises(RuntimeError, match="budget exhausted"):
         trainer.fit(
             lambda epoch: injector.wrap([make_batch(i) for i in range(12)]),
@@ -245,7 +254,7 @@ def test_recovery_metric_blowup_triggers_rollback(tmp_path):
     def train_batches(epoch: int):
         return injector.wrap([make_batch(epoch * 10 + i) for i in range(3)])
 
-    trainer = make_trainer()
+    trainer = make_trainer(own_programs=True)
     manager = CheckpointManager(str(tmp_path / "run"), max_to_keep=10)
     sink = EventSink()
     trainer.fit(
@@ -274,7 +283,7 @@ def test_recovery_triggers_even_with_detect_anomalies_off():
     rollback trigger: the policy still counts bad steps and still bounds the
     restart budget."""
     injector = NaNInjector(at_steps=range(2, 10))
-    trainer = make_trainer()
+    trainer = make_trainer(own_programs=True)
     sink = EventSink()
     with pytest.raises(RuntimeError, match="budget exhausted"):
         trainer.fit(
@@ -392,7 +401,7 @@ def test_lr_backoff_survives_preemption_and_resume(tmp_path):
     def stream(epoch: int):
         return sig.wrap(injector.wrap([make_batch(epoch * 100 + i) for i in range(9)]))
 
-    trainer_a = make_trainer()
+    trainer_a = make_trainer(own_programs=True)
     manager = CheckpointManager(str(tmp_path / "run"), max_to_keep=100)
     policy = RecoveryPolicy(max_consecutive_bad=2, max_restarts=3, lr_backoff=0.5)
     trainer_a.fit(
@@ -402,7 +411,7 @@ def test_lr_backoff_survives_preemption_and_resume(tmp_path):
     assert trainer_a._lr_scale == pytest.approx(0.5)
     assert manager.metadata(manager.latest_step())["lr_scale"] == pytest.approx(0.5)
 
-    trainer_b = make_trainer()
+    trainer_b = make_trainer(own_programs=True)
     assert trainer_b._lr_scale == 1.0
     trainer_b.fit(
         lambda epoch: [make_batch(epoch * 100 + i) for i in range(9)],

@@ -24,7 +24,7 @@ from replay_tpu.nn.transform.template import make_default_sasrec_transforms
 from replay_tpu.obs import GOODPUT_SPANS, Tracer, chunk_stage_log
 from replay_tpu.obs.trace import CHUNK_STAGES, claim_chunk
 
-pytestmark = [pytest.mark.jax, pytest.mark.usefixtures("shared_compile_cache")]
+pytestmark = pytest.mark.jax
 
 NUM_ITEMS, SEQ_LEN, BATCH, SCAN_CHUNK = 40, 8, 8, 3
 PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py

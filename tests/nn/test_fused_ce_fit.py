@@ -69,16 +69,25 @@ def make_batch(seed: int) -> dict:
     }
 
 
-def make_trainer(loss, **kwargs) -> Trainer:
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
+def make_trainer(loss, own_programs: bool = False, **kwargs) -> Trainer:
+    """A trainer of the module's one tiny model. The plain ones of one loss share
+    their two programs (traced and lowered once a module); ``own_programs`` is
+    for the trainer whose compile counts are asserted, and any other mesh or
+    health keeps its own too."""
     model = SasRec(
         schema=make_schema(), embedding_dim=16, num_blocks=1, num_heads=1,
         max_sequence_length=SEQ_LEN,
     )
-    kwargs.setdefault("mesh", make_mesh())
-    return Trainer(
+    trainer = Trainer(
         model=model, loss=loss, optimizer=OptimizerFactory(learning_rate=1e-2),
-        **kwargs,
+        **{"mesh": make_mesh(), **kwargs},
     )
+    if own_programs or kwargs:
+        return PROGRAMS.share_init(trainer)  # the same model: the same fresh parameters
+    return PROGRAMS.adopt(trainer, key=type(loss).__name__)
 
 
 class EventSink:
@@ -106,8 +115,8 @@ def test_fused_chunked_fit_bitwise_matches_per_step_and_ce():
     plain CE to f32 softmax precision. Leaves the CI smoke artifact."""
     batches = [make_batch(i) for i in range(7)]
 
-    def run(loss, scan_chunk):
-        trainer = make_trainer(loss)
+    def run(loss, scan_chunk, own_programs=False):
+        trainer = make_trainer(loss, own_programs=own_programs)
         sink = EventSink()
         state = trainer.fit(
             batches, epochs=1, loggers=sink, log_every=0, scan_chunk=scan_chunk
@@ -116,7 +125,7 @@ def test_fused_chunked_fit_bitwise_matches_per_step_and_ce():
         return trainer, state, losses
 
     per_step, state_a, losses_a = run(CEFused(tile=8), None)
-    chunked, state_b, losses_b = run(CEFused(tile=8), 3)
+    chunked, state_b, losses_b = run(CEFused(tile=8), 3, own_programs=True)  # counted below
     _, _, losses_ce = run(CE(), 3)
 
     assert_params_bitwise_equal(state_a.params, state_b.params)

@@ -96,19 +96,27 @@ def make_val_batch(seed: int) -> dict:
     }
 
 
-def make_trainer(precision, loss=None, **kwargs) -> Trainer:
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
+def make_trainer(precision, loss=None, health=None) -> Trainer:
+    """A trainer of the module's one tiny model; those of one precision, loss and
+    health configuration share their two programs (traced and lowered once a
+    module)."""
     model = SasRec(
         schema=make_schema(), embedding_dim=16, num_blocks=1, num_heads=1,
         max_sequence_length=SEQ_LEN,
     )
-    kwargs.setdefault("mesh", make_mesh())
-    return Trainer(
+    loss = loss if loss is not None else CEFused(tile=8)
+    trainer = Trainer(
         model=model,
-        loss=loss if loss is not None else CEFused(tile=8),
+        loss=loss,
         optimizer=OptimizerFactory(learning_rate=1e-2),
         precision=precision,
-        **kwargs,
+        health=health,
+        mesh=make_mesh(),
     )
+    return PROGRAMS.adopt(trainer, key=(precision, type(loss).__name__, health))
 
 
 class EventSink:

@@ -21,9 +21,6 @@ from replay_tpu.nn.sequential.sasrec import SasRec
 from replay_tpu.obs import HealthConfig
 from replay_tpu.utils.faults import NaNInjector, SignalAtStep
 
-# one tiny model, many trainers: XLA compiles each program once a session
-pytestmark = pytest.mark.usefixtures("shared_compile_cache")
-
 NUM_ITEMS = 12
 SEQ_LEN = 8
 BATCH = 8  # divisible by the 8-device data axis

@@ -32,9 +32,6 @@ from replay_tpu.nn.transform.template import make_default_sasrec_transforms
 from replay_tpu.obs import JsonlLogger, SLORule, Tracer
 from replay_tpu.utils.checkpoint import CheckpointManager
 
-# one tiny model, many trainers: XLA compiles each program once a session
-pytestmark = pytest.mark.usefixtures("shared_compile_cache")
-
 NUM_ITEMS = 30
 SEQ_LEN = 7  # -> [B, 6] training batches
 BATCH = 8

@@ -25,6 +25,11 @@ NUM_ITEMS = 12
 SEQ_LEN = 8
 BATCH = 8  # divisible by the 8-device data axis
 
+# this module's SharedPrograms, set by tests/conftest.py: every trainer's flax
+# init runs under jax.jit; the trainers differ in loss, optimizer or dtype and
+# keep their own programs, but for the one configuration two tests build
+PROGRAMS = None
+
 
 @pytest.fixture(scope="module")
 def schema() -> TensorSchema:
@@ -66,12 +71,12 @@ def trained(schema, pipelines):
     rng = np.random.default_rng(7)
     model = SasRec(schema=schema, embedding_dim=16, num_blocks=1, num_heads=1,
                    max_sequence_length=SEQ_LEN)
-    trainer = Trainer(
+    trainer = PROGRAMS.share_init(Trainer(
         model=model,
         loss=CE(),
         optimizer=OptimizerFactory(name="adam", learning_rate=5e-2),
         mesh=make_mesh(),
-    )
+    ))
     batches = [pipelines["train"](make_raw_batch(rng)) for _ in range(6)]
     state = None
     losses = []
@@ -175,8 +180,8 @@ def test_bfloat16_training_smoke(schema, pipelines):
     rng = np.random.default_rng(17)
     model = SasRec(schema=schema, embedding_dim=16, num_blocks=1,
                    max_sequence_length=SEQ_LEN, dtype=jnp.bfloat16)
-    trainer = Trainer(model=model, loss=CE(),
-                      optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = PROGRAMS.share_init(Trainer(model=model, loss=CE(),
+                                          optimizer=OptimizerFactory(learning_rate=1e-2)))
     state, losses = None, []
     for _ in range(6):
         batch = pipelines["train"](make_raw_batch(rng))
@@ -199,11 +204,11 @@ def test_sce_loss_through_trainer(schema, pipelines):
     rng = np.random.default_rng(23)
     model = SasRec(schema=schema, embedding_dim=16, num_blocks=1,
                    max_sequence_length=SEQ_LEN)
-    trainer = Trainer(
+    trainer = PROGRAMS.share_init(Trainer(
         model=model,
         loss=SCE(SCEParams(n_buckets=4, bucket_size_x=8, bucket_size_y=6)),
         optimizer=OptimizerFactory(learning_rate=2e-2),
-    )
+    ))
     batches = [pipelines["train"](make_raw_batch(rng)) for _ in range(5)]
     state, losses = None, []
     for _ in range(6):
@@ -228,7 +233,9 @@ def test_fit_multiple_validation_streams(schema, pipelines):
     (the reference's sequential CombinedLoader over several val paths)."""
     rng = np.random.default_rng(31)
     model = SasRec(schema=schema, embedding_dim=16, num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = PROGRAMS.adopt(  # CE, lr 1e-2: one configuration, two tests
+        Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    )
 
     def make_val():
         raw = make_raw_batch(rng)
@@ -253,7 +260,9 @@ def test_monitor_early_stopping_and_best_state(schema, pipelines):
     rng = np.random.default_rng(41)
     model = SasRec(schema=schema, embedding_dim=16, num_blocks=1, max_sequence_length=SEQ_LEN)
     # a big lr makes late epochs noisy, so train_loss (mode=min) has a real best
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = PROGRAMS.adopt(  # CE, lr 1e-2: one configuration, two tests
+        Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    )
     batches = [pipelines["train"](make_raw_batch(rng)) for _ in range(3)]
     state = trainer.fit(lambda e: batches, epochs=12, monitor="train_loss",
                         mode="min", patience=3)
@@ -301,7 +310,9 @@ def test_every_loss_trains_through_trainer(loss_name, schema, pipelines):
         ]
     pipeline = Compose(transforms)
     model = SasRec(schema=schema, embedding_dim=16, num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=loss, optimizer=OptimizerFactory(learning_rate=2e-2))
+    trainer = PROGRAMS.share_init(
+        Trainer(model=model, loss=loss, optimizer=OptimizerFactory(learning_rate=2e-2))
+    )
     rng = np.random.default_rng(3)
     key = jax.random.PRNGKey(0)
     state, losses = None, []

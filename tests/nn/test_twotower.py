@@ -55,14 +55,19 @@ def make_raw_batch(rng: np.random.Generator):
     return {"item_id": items, "item_id_mask": items != NUM_ITEMS}
 
 
+# this module's SharedPrograms, set by tests/conftest.py: the two trainers are of
+# two models and keep their programs; their flax init runs under jax.jit
+PROGRAMS = None
+
+
 @pytest.fixture(scope="module")
 def trained(schema, item_schema, item_feature_tensors):
     rng = np.random.default_rng(0)
     pipeline = Compose(make_default_twotower_transforms(schema)["train"])
     model = TwoTower(schema=schema, item_schema=item_schema, embedding_dim=16,
                      num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CESampled(),
-                      optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = PROGRAMS.share_init(Trainer(model=model, loss=CESampled(),
+                                          optimizer=OptimizerFactory(learning_rate=1e-2)))
     state, losses = None, []
     raws = [make_raw_batch(rng) for _ in range(6)]
     for _ in range(10):
@@ -231,11 +236,11 @@ def test_context_merger_changes_outputs_and_trains(schema):
     scores = merged.apply(p_merged, feats, mask, method=TwoTower.forward_inference)
     assert scores.shape == (BATCH, NUM_ITEMS)
     # and it trains end-to-end through the shared Trainer
-    trainer = Trainer(
+    trainer = PROGRAMS.share_init(Trainer(
         model=merged,
         loss=CESampled(),
         optimizer=OptimizerFactory(learning_rate=1e-2),
-    )
+    ))
     pipeline = Compose(make_default_twotower_transforms(schema)["train"])
     state, losses = None, []
     for i in range(4):

@@ -36,12 +36,27 @@ def make_batch(num_items, rng):
     }
 
 
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
+def make_trainer(model, optimizer=None) -> Trainer:
+    """Each trainer keeps its own programs: the tests resize the vocabulary,
+    which rebuilds them (and changes the schema the model holds). They share
+    the jitted flax init."""
+    return PROGRAMS.share_init(Trainer(
+        model=model, loss=CE(),
+        optimizer=optimizer or OptimizerFactory(learning_rate=1e-2),
+    ))
+
+
 def test_grow_shrink_and_replace():
     schema = make_schema()
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, max_sequence_length=SEQ_LEN)
     rng = np.random.default_rng(0)
-    params = model.init(jax.random.PRNGKey(0), {"item_id": np.zeros((2, SEQ_LEN), np.int32)},
-                        np.ones((2, SEQ_LEN), bool))["params"]
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), {"item_id": np.zeros((2, SEQ_LEN), np.int32)},
+        np.ones((2, SEQ_LEN), bool),
+    )["params"]
     params = jax.tree.map(np.asarray, params)
     old_table = params["body"]["embedder"]["embedding_item_id"]["table"]["embedding"].copy()
 
@@ -69,7 +84,7 @@ def test_trainer_resize_then_train():
     """Growth mid-lifecycle: the resized state trains and scores the new items."""
     schema = make_schema()
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = make_trainer(model)
     rng = np.random.default_rng(0)
     state = trainer.init_state(make_batch(NUM_ITEMS, rng))
     for _ in range(3):
@@ -105,7 +120,7 @@ def test_reference_named_wrappers_and_old_logits_identical():
     rng = np.random.default_rng(0)
     ids = rng.integers(0, NUM_ITEMS, (3, SEQ_LEN)).astype(np.int32)
     mask = np.ones((3, SEQ_LEN), bool)
-    params = model.init(jax.random.PRNGKey(0), {"item_id": ids}, mask)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), {"item_id": ids}, mask)["params"]
     params = jax.tree.map(np.asarray, params)
     before = np.asarray(model.apply({"params": params}, {"item_id": ids}, mask,
                                     method=SasRec.forward_inference))
@@ -141,17 +156,17 @@ def test_bert4rec_surgery_and_warm_start_state():
     schema = make_schema()
     model = Bert4Rec(schema=schema, embedding_dim=8, num_blocks=1, num_heads=2,
                      max_sequence_length=SEQ_LEN)
-    params = model.init(jax.random.PRNGKey(0),
-                        {"item_id": np.zeros((2, SEQ_LEN), np.int32)},
-                        np.ones((2, SEQ_LEN), bool))["params"]
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), {"item_id": np.zeros((2, SEQ_LEN), np.int32)},
+        np.ones((2, SEQ_LEN), bool),
+    )["params"]
     params = jax.tree.map(np.asarray, params)
     grown = set_item_embeddings_by_size(params, schema, NUM_ITEMS + 2)
     assert get_item_embeddings(grown, schema).shape == (NUM_ITEMS + 2, 8)
 
     new_model = Bert4Rec(schema=schema, embedding_dim=8, num_blocks=1, num_heads=2,
                          max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=new_model, loss=CE(),
-                      optimizer=OptimizerFactory(name="sgd", learning_rate=0.1))
+    trainer = make_trainer(new_model, OptimizerFactory(name="sgd", learning_rate=0.1))
     rng = np.random.default_rng(1)
     batch = make_batch(NUM_ITEMS + 2, rng)
     state = trainer.init_state(batch, params=grown)
@@ -193,7 +208,7 @@ def test_resize_vocabulary_carries_adam_moments_in_lockstep():
     zero, the padding row's moments move to the new end with it."""
     schema = make_schema()
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = make_trainer(model)
     state = _trained_state(trainer, np.random.default_rng(0))
     before = _item_moments(state.opt_state)
     assert len(before) >= 2  # adam: mu and nu at least
@@ -218,7 +233,7 @@ def test_resize_item_embeddings_opt_state_roundtrip_and_out_of_sync_guard():
 
     schema = make_schema()
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = make_trainer(model)
     state = _trained_state(trainer, np.random.default_rng(1))
     params = jax.tree.map(np.asarray, state.params)
     opt_host = jax.tree.map(np.asarray, state.opt_state)
@@ -242,7 +257,7 @@ def test_fit_rejects_resumed_state_with_stale_opt_state():
     fit start with an error NAMING the table path."""
     schema = make_schema()
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = make_trainer(model)
     rng = np.random.default_rng(2)
     state = _trained_state(trainer, rng)
     grown_params = resize_item_embeddings(
@@ -258,7 +273,7 @@ def test_validate_optimizer_state_passes_on_consistent_pair():
 
     schema = make_schema()
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = make_trainer(model)
     state = _trained_state(trainer, np.random.default_rng(3), steps=1)
     validate_optimizer_state(state.params, state.opt_state, schema)  # no raise
     grown = trainer.resize_vocabulary(state, NUM_ITEMS + 4)
@@ -270,7 +285,7 @@ def test_finetune_entry_grows_then_fits_from_trained_state():
     catalog, optimizer moments carried, then a plain fit on the fresh tail."""
     schema = make_schema()
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2))
+    trainer = make_trainer(model)
     rng = np.random.default_rng(4)
     state = _trained_state(trainer, rng)
     old_table = np.asarray(

@@ -86,13 +86,19 @@ def make_batch(seed: int) -> dict:
     }
 
 
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
 def make_trainer(**kwargs) -> Trainer:
+    """Every trainer of this module has a health configuration, a seed or a
+    model of its own (and two have their compile counts asserted), so each keeps
+    its programs; they share the jitted flax init."""
     model = SasRec(
         schema=make_schema(), embedding_dim=16, num_blocks=2, num_heads=2,
         max_sequence_length=SEQ_LEN,
     )
     kwargs.setdefault("optimizer", OptimizerFactory(name="adam", learning_rate=1e-2))
-    return Trainer(model=model, loss=CE(), mesh=make_mesh(), **kwargs)
+    return PROGRAMS.share_init(Trainer(model=model, loss=CE(), mesh=make_mesh(), **kwargs))
 
 
 class EventSink:
@@ -176,10 +182,10 @@ def test_health_payload_on_bert4rec_body(tmp_path):
         schema=make_schema(), embedding_dim=16, num_blocks=1, num_heads=2,
         max_sequence_length=SEQ_LEN,
     )
-    trainer = Trainer(
+    trainer = PROGRAMS.share_init(Trainer(
         model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2),
         mesh=make_mesh(), health=HealthConfig(cadence=1),
-    )
+    ))
     rng = np.random.default_rng(0)
     batch = make_batch(0)
     batch["token_mask"] = rng.random((BATCH, SEQ_LEN)) > 0.2
@@ -388,13 +394,13 @@ class ToyTying(nn.Module):
 
 
 def _toy_trainer(watcher: HealthWatcher) -> Trainer:
-    return Trainer(
+    return PROGRAMS.share_init(Trainer(
         model=ToyTying(vocab=NUM_ITEMS),
         loss=CE(),
         optimizer=OptimizerFactory(name="sgd", learning_rate=20.0),  # lr blowup
         mesh=make_mesh(),
         health=HealthConfig(cadence=1, watcher=watcher),
-    )
+    ))
 
 
 @pytest.mark.jax

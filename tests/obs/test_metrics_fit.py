@@ -73,15 +73,19 @@ def make_batch(seed: int) -> dict:
     }
 
 
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
 def make_trainer() -> Trainer:
+    """The module's one configuration: its trainers share their two programs."""
     model = SasRec(
         schema=make_schema(), embedding_dim=16, num_blocks=1, num_heads=1,
         max_sequence_length=SEQ_LEN,
     )
-    return Trainer(
+    return PROGRAMS.adopt(Trainer(
         model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2),
         mesh=make_mesh(),
-    )
+    ))
 
 
 class MidFitScraper:

@@ -1,5 +1,8 @@
 """Roofline analysis (obs.roofline): peak tables, classification math, the
-compiled-program record, and the MemoryMonitor chunk-boundary sampling hook.
+compiled-program record, the MemoryMonitor chunk-boundary sampling hook, and
+the profiled fit (``fit(profile_steps=...)``: the capture it writes and the
+``roofline`` payload on ``on_fit_end`` — the run_logs/profile_smoke artifact
+CI renders and uploads).
 
 Core tier is pure arithmetic (no jax): bandwidth lookups, memory- vs
 compute-bound classification against real and assumed chips, the ceiling
@@ -8,6 +11,10 @@ formula, degradation to None for unclassifiable inputs. The jax tier runs
 and checks the memory/collective fields, and verifies the scan-chunked fit
 samples device memory at chunk boundaries (CPU-safe no-op).
 """
+
+import glob
+import json
+import os
 
 import numpy as np
 import pytest
@@ -118,7 +125,12 @@ def test_memory_monitor_observe_is_a_noop_without_allocator_stats():
 # --------------------------------------------------------------------------- #
 # jax tier: compiled-program records + the fit sampling hook
 # --------------------------------------------------------------------------- #
-def _tiny_trainer(num_items=50, seq_len=8, dim=16):
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
+def _tiny_trainer(own_programs=False, num_items=50, seq_len=8, dim=16):
+    """The module's one tiny trainer; all but ``own_programs`` share their two
+    programs (traced and lowered once a module)."""
     from replay_tpu.data import FeatureHint, FeatureType
     from replay_tpu.data.nn import TensorFeatureInfo, TensorSchema
     from replay_tpu.nn import OptimizerFactory, Trainer, make_mesh
@@ -134,8 +146,9 @@ def _tiny_trainer(num_items=50, seq_len=8, dim=16):
     )
     model = SasRec(schema=schema, embedding_dim=dim, num_blocks=1, num_heads=1,
                    max_sequence_length=seq_len)
-    return Trainer(model=model, loss=CE(),
-                   optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh())
+    trainer = Trainer(model=model, loss=CE(),
+                      optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh())
+    return PROGRAMS.share_init(trainer) if own_programs else PROGRAMS.adopt(trainer)
 
 
 def _tiny_batches(n, num_items=50, seq_len=8, batch=8, seed=0):
@@ -217,3 +230,74 @@ def test_compiled_inference_roofline_per_bucket(monkeypatch):
     for record in records.values():
         assert record["hbm_peak_bytes"] > 0
         assert record["roofline"]["bound"] in ("memory", "compute")
+
+
+# --------------------------------------------------------------------------- #
+# the profiled fit end-to-end (CI's profile_smoke artifact)
+# --------------------------------------------------------------------------- #
+def _captures(profile_dir):
+    """The ``*.xplane.pb`` files ``jax.profiler`` wrote under ``profile_dir``."""
+    return glob.glob(os.path.join(profile_dir, "plugins", "profile", "*", "*.xplane.pb"))
+
+
+@pytest.mark.jax
+@pytest.mark.smoke
+def test_profiled_fit_writes_capture_and_roofline(tmp_path, monkeypatch):
+    from replay_tpu.obs import JsonlLogger
+
+    # classify against an assumed chip on the CPU mesh (arithmetic, flagged)
+    monkeypatch.setenv("REPLAY_TPU_ROOFLINE_ASSUME_KIND", "v5e")
+    trainer = _tiny_trainer()
+    batches = _tiny_batches(5)
+    base = os.environ.get("REPLAY_TPU_RUN_DIR")
+    run_dir = os.path.join(base, "profile_smoke") if base else str(tmp_path / "profile_smoke")
+    # mode="w": REPLAY_TPU_RUN_DIR is a fixed path in CI — re-runs must not append
+    with JsonlLogger(run_dir, mode="w") as sink:
+        trainer.fit(batches, epochs=1, loggers=sink, log_every=0,
+                    profile_steps=(1, 4), scan_chunk=2)
+
+    assert _captures(os.path.join(run_dir, "profile")), "no capture under run_dir/profile"
+
+    events = [json.loads(line) for line in open(os.path.join(run_dir, "events.jsonl"))]
+    fit_end = [e for e in events if e["event"] == "on_fit_end"][-1]
+    # the roofline payload rides the terminal event: both dispatched programs
+    # classified, the full-CE step memory-bound under the assumed v5e peaks
+    roofline = fit_end["roofline"]
+    assert {"train_step", "train_scan"} <= set(roofline)
+    for record in roofline.values():
+        assert record["hbm_peak_bytes"] > 0
+        classification = record["roofline"]
+        assert classification["bound"] == "memory"
+        assert classification["peak_assumed"] == "v5e"
+        assert 0.0 < classification["ceiling_tflops"] <= classification["peak_tflops"]
+
+
+@pytest.mark.jax
+def test_profiled_per_step_fit_window(tmp_path):
+    """The per-step (unchunked) path: window [1, 3) opens/closes inside the
+    fit and leaves a capture; a window that is not [start, stop) raises."""
+    trainer = _tiny_trainer()
+    batches = _tiny_batches(4)
+    profile_dir = str(tmp_path / "prof")
+    trainer.fit(batches, epochs=1, log_every=0, profile_steps=(1, 3),
+                profile_dir=profile_dir)
+    assert _captures(profile_dir)
+    with pytest.raises(ValueError, match=r"valid \[start, stop\) window"):
+        trainer.fit(batches, epochs=1, log_every=0, profile_steps=(3, 1),
+                    profile_dir=profile_dir)
+
+
+@pytest.mark.jax
+def test_analyze_programs_and_lowered_hlo_roundtrip():
+    trainer = _tiny_trainer(own_programs=True)  # what IT dispatched is asserted
+    batches = _tiny_batches(1)
+    state = trainer.init_state(batches[0])
+    trainer.train_step(state, batches[0])
+    hlo = trainer.lowered_hlo("train_step")
+    assert "op_name" in hlo  # the scope metadata survives compilation
+    with pytest.raises(KeyError):
+        trainer.lowered_hlo("train_scan")  # never dispatched
+    records = trainer.analyze_programs()
+    assert "train_step" in records
+    assert records["train_step"]["hbm_peak_bytes"] > 0
+    assert records["train_step"]["collectives"]["count"] >= 0

@@ -17,9 +17,6 @@ import pytest
 
 from replay_tpu.obs import GOODPUT_SPANS, Tracer, goodput_breakdown, traced_iterator
 
-# one tiny model, many trainers: XLA compiles each program once a session
-pytestmark = pytest.mark.usefixtures("shared_compile_cache")
-
 
 # --------------------------------------------------------------------------- #
 # tracer core (host-only)

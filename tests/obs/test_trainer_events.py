@@ -62,12 +62,12 @@ def test_fit_event_stream_single_compile(tmp_path):
     )
     model = SasRec(schema=schema, embedding_dim=16, num_blocks=1, num_heads=1,
                    max_sequence_length=SEQ_LEN)
-    trainer = Trainer(
+    trainer = PROGRAMS.share_init(Trainer(  # its own programs: their count is asserted
         model=model,
         loss=CE(),
         optimizer=OptimizerFactory(name="adam", learning_rate=1e-2),
         mesh=make_mesh(),
-    )
+    ))
     rng = np.random.default_rng(0)
     batches = [_make_batch(rng) for _ in range(3)]
 
@@ -111,13 +111,12 @@ def test_fit_event_stream_single_compile(tmp_path):
     assert np.isfinite(fit_end["telemetry"]["samples_per_sec"])
 
 
-@pytest.mark.jax
-def test_fit_sparse_cadence_reports_finite_telemetry(caplog):
-    """log_every-only path, fit shorter than 2x the cadence: the epoch-boundary
-    flush + warmup proration must still produce real steady-state numbers in
-    the fit-end summary (not an all-NaN telemetry block)."""
-    import logging
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
 
+
+def _small_trainer(optimizer=None) -> Trainer:
+    """The d=8 model of the tests below; the trainers of the default optimizer
+    share their two programs, another optimizer keeps its own."""
     schema = TensorSchema(
         TensorFeatureInfo(
             "item_id",
@@ -131,7 +130,19 @@ def test_fit_sparse_cadence_reports_finite_telemetry(caplog):
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, num_heads=1,
                    max_sequence_length=SEQ_LEN)
     trainer = Trainer(model=model, loss=CE(),
-                      optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh())
+                      optimizer=optimizer or OptimizerFactory(learning_rate=1e-2),
+                      mesh=make_mesh())
+    return PROGRAMS.share_init(trainer) if optimizer else PROGRAMS.adopt(trainer)
+
+
+@pytest.mark.jax
+def test_fit_sparse_cadence_reports_finite_telemetry(caplog):
+    """log_every-only path, fit shorter than 2x the cadence: the epoch-boundary
+    flush + warmup proration must still produce real steady-state numbers in
+    the fit-end summary (not an all-NaN telemetry block)."""
+    import logging
+
+    trainer = _small_trainer()
     rng = np.random.default_rng(1)
     batches = [_make_batch(rng) for _ in range(4)]
     with caplog.at_level(logging.INFO, logger="replay_tpu"):
@@ -154,20 +165,7 @@ def test_fit_accepts_duck_typed_single_sink():
         def log_event(self, event):
             self.events.append(event)
 
-    schema = TensorSchema(
-        TensorFeatureInfo(
-            "item_id",
-            FeatureType.CATEGORICAL,
-            is_seq=True,
-            feature_hint=FeatureHint.ITEM_ID,
-            cardinality=NUM_ITEMS,
-            embedding_dim=8,
-        )
-    )
-    model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, num_heads=1,
-                   max_sequence_length=SEQ_LEN)
-    trainer = Trainer(model=model, loss=CE(),
-                      optimizer=OptimizerFactory(learning_rate=1e-2), mesh=make_mesh())
+    trainer = _small_trainer()
     rng = np.random.default_rng(3)
     duck = Duck()
     trainer.fit(lambda: iter([_make_batch(rng), _make_batch(rng)]), epochs=1, loggers=duck)
@@ -183,26 +181,11 @@ def test_fit_lr_schedule_events_report_applied_rate(tmp_path):
     completed before the update)."""
     from replay_tpu.nn import LRSchedulerFactory
 
-    schema = TensorSchema(
-        TensorFeatureInfo(
-            "item_id",
-            FeatureType.CATEGORICAL,
-            is_seq=True,
-            feature_hint=FeatureHint.ITEM_ID,
-            cardinality=NUM_ITEMS,
-            embedding_dim=8,
-        )
-    )
-    model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, num_heads=1,
-                   max_sequence_length=SEQ_LEN)
-    trainer = Trainer(
-        model=model,
-        loss=CE(),
-        optimizer=OptimizerFactory(
+    trainer = _small_trainer(
+        OptimizerFactory(
             learning_rate=1e-2,
             scheduler=LRSchedulerFactory(kind="warmup_linear", warmup_steps=4),
-        ),
-        mesh=make_mesh(),
+        )
     )
     rng = np.random.default_rng(2)
     batches = [_make_batch(rng) for _ in range(3)]

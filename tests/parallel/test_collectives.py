@@ -146,6 +146,9 @@ def test_cefused_tp_head_never_gathers_the_table_shard():
     assert not model_reduces, model_reduces
 
 
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
 @pytest.mark.jax
 def test_full_cefused_tp_train_scan_guard_via_trainer():
     """The same guard through the PRODUCTION program: the dryrun's chunked
@@ -170,11 +173,11 @@ def test_full_cefused_tp_train_scan_guard_via_trainer():
     )
     model = SasRec(schema=schema, embedding_dim=embed, num_blocks=1, num_heads=1,
                    max_sequence_length=seq_len)
-    trainer = Trainer(
+    trainer = PROGRAMS.share_init(Trainer(  # two init_state calls: one jitted init
         model=model, loss=CEFusedTP(tile=8, interpret=True),
         optimizer=OptimizerFactory(learning_rate=1e-2),
         mesh=make_mesh(model_parallel=n_tp), shard_vocab=True,
-    )
+    ))
     batch_size = 8
 
     def mk(seed):

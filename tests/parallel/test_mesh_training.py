@@ -52,19 +52,30 @@ def make_train_batch(seed: int) -> dict:
     }
 
 
-def run_training(mesh: Mesh, steps: int = 3, shard_vocab: bool = False):
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
+def make_trainer(mesh: Mesh, loss, shard_vocab: bool = False) -> Trainer:
+    """The module's one tiny model; trainers of one mesh, loss and table layout
+    share their programs (traced and lowered once a module)."""
     model = SasRec(schema=make_schema(), embedding_dim=16, num_blocks=1,
                    max_sequence_length=SEQ_LEN)
     # SGD: parity asserts exact-ish numerical equivalence, and adaptive optimizers
     # amplify device-count-dependent summation noise on near-zero gradients
     trainer = Trainer(
         model=model,
-        loss=CE(),
+        loss=loss,
         optimizer=OptimizerFactory(name="sgd", learning_rate=0.1),
         mesh=mesh,
         shard_vocab=shard_vocab,
         seed=0,
     )
+    key = (tuple(mesh.shape.items()), type(loss).__name__, shard_vocab)
+    return PROGRAMS.adopt(trainer, key=key)
+
+
+def run_training(mesh: Mesh, steps: int = 3, shard_vocab: bool = False):
+    trainer = make_trainer(mesh, CE(), shard_vocab)
     state = trainer.init_state(make_train_batch(0))
     if shard_vocab:
         # guard against the silent-degradation mode: a table whose row count
@@ -173,13 +184,8 @@ def test_fused_ce_composes_with_vocab_sharding():
     from replay_tpu.nn.loss import CEFused
 
     def losses_for(loss, model_parallel, shard_vocab):
-        model = SasRec(schema=make_schema(), embedding_dim=16, num_blocks=1,
-                       max_sequence_length=SEQ_LEN)
-        trainer = Trainer(
-            model=model, loss=loss,
-            optimizer=OptimizerFactory(name="sgd", learning_rate=0.1),
-            mesh=make_mesh(jax.devices(), model_parallel=model_parallel),
-            shard_vocab=shard_vocab, seed=0,
+        trainer = make_trainer(
+            make_mesh(jax.devices(), model_parallel=model_parallel), loss, shard_vocab
         )
         state = trainer.init_state(make_train_batch(0))
         out = []

@@ -28,7 +28,7 @@ def qkv():
 @pytest.mark.parametrize("causal", [False, True], ids=["bidirectional", "causal"])
 def test_matches_full_attention(mesh, qkv, causal):
     q, k, v = qkv
-    got = ring_attention(q, k, v, mesh, causal=causal)
+    got = jax.jit(lambda q, k, v: ring_attention(q, k, v, mesh, causal=causal))(q, k, v)
     want = full_attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5)
 
@@ -37,7 +37,9 @@ def test_matches_full_attention(mesh, qkv, causal):
 def test_respects_padding(mesh, qkv):
     q, k, v = qkv
     padding = jnp.asarray(np.random.default_rng(1).random((B, L)) > 0.3)
-    got = ring_attention(q, k, v, mesh, causal=True, padding_mask=padding)
+    got = jax.jit(
+        lambda q, k, v, padding: ring_attention(q, k, v, mesh, causal=True, padding_mask=padding)
+    )(q, k, v, padding)
     want = full_attention_reference(q, k, v, causal=True, padding_mask=padding)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5)
 

@@ -122,7 +122,14 @@ def make_batch(seed, batch=BATCH, num_items=NUM_ITEMS):
     }
 
 
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
 def make_trainer(mesh, use_flash=False, num_items=NUM_ITEMS, loss=None, **kwargs):
+    """Every trainer's flax init runs under ``jax.jit`` (``share_init``: eagerly
+    the ring route's ``shard_map`` is dispatched op by op on the 8-device mesh);
+    the plain one-device trainers, the one configuration built more than once,
+    share their two programs."""
     from replay_tpu.nn import OptimizerFactory, Trainer
     from replay_tpu.nn.loss import CE
     from replay_tpu.nn.sequential.sasrec import SasRec
@@ -131,7 +138,7 @@ def make_trainer(mesh, use_flash=False, num_items=NUM_ITEMS, loss=None, **kwargs
         schema=make_schema(num_items), embedding_dim=16, num_blocks=2,
         max_sequence_length=SEQ_LEN, use_flash=use_flash,
     )
-    return Trainer(
+    trainer = Trainer(
         model=model,
         loss=loss if loss is not None else CE(),
         # SGD: parity asserts near-exact equivalence; adaptive optimizers
@@ -141,6 +148,10 @@ def make_trainer(mesh, use_flash=False, num_items=NUM_ITEMS, loss=None, **kwargs
         seed=0,
         **kwargs,
     )
+    plain = mesh.size == 1 and not use_flash and num_items == NUM_ITEMS and loss is None
+    if plain and not kwargs:
+        return PROGRAMS.adopt(trainer)
+    return PROGRAMS.share_init(trainer)
 
 
 @pytest.mark.jax
@@ -256,11 +267,11 @@ def test_bert4rec_ring_sp_matches_unsharded():
             schema=make_schema(), embedding_dim=16, num_blocks=2, num_heads=2,
             max_sequence_length=SEQ_LEN, use_flash=use_flash,
         )
-        trainer = Trainer(
+        trainer = PROGRAMS.share_init(Trainer(
             model=model, loss=CE(),
             optimizer=OptimizerFactory(name="sgd", learning_rate=0.1),
             mesh=mesh, seed=0,
-        )
+        ))
         state = trainer.init_state(mlm_batch(0))
         out = []
         for step in range(3):
@@ -461,11 +472,11 @@ def test_scan_blocks_trains_and_stacks_params():
         schema=make_schema(), embedding_dim=16, num_blocks=3,
         max_sequence_length=SEQ_LEN, scan_blocks=True,
     )
-    trainer = Trainer(
+    trainer = PROGRAMS.share_init(Trainer(
         model=model, loss=CE(),
         optimizer=OptimizerFactory(name="sgd", learning_rate=0.1),
         mesh=make_mesh(jax.devices()[:1]), remat_policy="dots", seed=0,
-    )
+    ))
     state = trainer.init_state(make_batch(0))
     stacked = [
         (path, leaf)

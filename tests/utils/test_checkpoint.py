@@ -32,7 +32,12 @@ def make_batch(seed: int) -> dict:
     }
 
 
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
+
+
 def make_trainer(learning_rate: float = 1e-2) -> Trainer:
+    """The module's one tiny model; trainers of one learning rate share their
+    two programs (traced and lowered once a module)."""
     schema = TensorSchema(
         TensorFeatureInfo(
             "item_id",
@@ -44,8 +49,10 @@ def make_trainer(learning_rate: float = 1e-2) -> Trainer:
         )
     )
     model = SasRec(schema=schema, embedding_dim=8, num_blocks=1, max_sequence_length=SEQ_LEN)
-    return Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=learning_rate),
-                   mesh=make_mesh(), seed=0)
+    trainer = Trainer(model=model, loss=CE(),
+                      optimizer=OptimizerFactory(learning_rate=learning_rate),
+                      mesh=make_mesh(), seed=0)
+    return PROGRAMS.adopt(trainer, key=learning_rate)
 
 
 @pytest.mark.jax
