@@ -117,12 +117,49 @@ def dot_product_attention(
             raise ValueError(msg)
         return flash_attention(q, k, v, mask, interpret=pallas_interpret()).astype(q.dtype)
     scale = 1.0 / jnp.sqrt(jnp.array(q.shape[-1], dtype=q.dtype))
+    if k.shape[-3] != q.shape[-3]:
+        # grouped-query heads: each of the Hkv key/value heads serves H/Hkv
+        # query heads (query head h reads key/value head h // (H/Hkv)); the
+        # group is an einsum axis, so K and V are never repeated in memory
+        return _grouped_query_attention(q, k, v, mask, scale, return_weights)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale + mask.astype(q.dtype)
     weights = nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", weights, v)
     if return_weights:
         return out, weights
     return out
+
+
+def _grouped_query_attention(q, k, v, mask, scale, return_weights: bool):
+    batch, heads, length, head_dim = q.shape
+    kv_heads = k.shape[-3]
+    if heads % kv_heads:
+        msg = f"{heads} query heads do not divide over {kv_heads} key/value heads"
+        raise ValueError(msg)
+    grouped = q.reshape(batch, kv_heads, heads // kv_heads, length, head_dim)
+    scores = jnp.einsum("bhgqd,bhkd->bhgqk", grouped, k) * scale
+    weights = nn.softmax(scores + mask.astype(q.dtype)[:, :, None], axis=-1)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", weights, v).reshape(q.shape)
+    if return_weights:
+        return out, weights.reshape(batch, heads, length, length)
+    return out
+
+
+def rotary_embedding(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary positions on ``x`` [..., L, D]: the half-split ("rotate half")
+    form, pair i of (x[i], x[i + D/2]) turned by ``positions * theta**(-2i/D)``.
+    Angles and the rotation are float32; the result takes ``x``'s dtype. Being
+    relative, it needs no table and no maximum length."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [L, D/2]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x32 = x.astype(jnp.float32)
+    first, second = x32[..., :half], x32[..., half:]
+    rotated = jnp.concatenate(
+        [first * cos - second * sin, second * cos + first * sin], axis=-1
+    )
+    return rotated.astype(x.dtype)
 
 
 class MultiHeadAttention(nn.Module):
@@ -191,6 +228,44 @@ class MultiHeadAttention(nn.Module):
         out = out.swapaxes(-3, -2).reshape(*x.shape[:-1], dim)
         out = nn.Dense(dim, dtype=self.dtype, name="out")(out)
         return nn.Dropout(self.dropout_rate, deterministic=deterministic)(out)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Self-attention with fewer key/value heads than query heads, rotary
+    positions and an RMS norm over each head's width on q and k (bias-free
+    projections; the attention layer of the layer-pattern block stack,
+    replay_tpu.nn.blocks). Positions are the indices in the window: rotary
+    scores depend on their differences only, so left padding shifts nothing.
+
+    Runs on the standard route of :func:`dot_product_attention` (additive
+    ``mask`` [B, 1, L, L]); the flash kernels take one head count.
+    """
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+        length = x.shape[-2]
+
+        def heads_of(name, count):
+            proj = nn.Dense(count * self.head_dim, use_bias=False, dtype=self.dtype, name=name)(x)
+            return proj.reshape(*x.shape[:-1], count, self.head_dim)
+
+        q = RMSNorm(self.norm_eps, dtype=self.dtype, name="q_norm")(heads_of("query", self.num_heads))
+        k = RMSNorm(self.norm_eps, dtype=self.dtype, name="k_norm")(heads_of("key", self.num_kv_heads))
+        v = heads_of("value", self.num_kv_heads)
+        positions = jnp.arange(length)
+        q, k, v = (t.swapaxes(-3, -2) for t in (q, k, v))  # [B, H, L, D]
+        q = rotary_embedding(q, positions, self.rope_theta)
+        k = rotary_embedding(k, positions, self.rope_theta)
+        out = dot_product_attention(q, k, v, mask)
+        out = out.swapaxes(-3, -2).reshape(*x.shape[:-1], self.num_heads * self.head_dim)
+        return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype, name="out")(out)
 
 
 class RMSNorm(nn.Module):

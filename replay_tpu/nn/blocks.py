@@ -1,0 +1,129 @@
+"""One block definition, stacked by a layer pattern.
+
+A block is (mixer kind, feed-forward kind) around a pre-norm residual stream:
+
+    h = x + mixer(rms(x))          mixer: "full_attention" | "conv"
+    y = h + ffn(rms(h))            ffn:   dense SwiGLU | sparse experts
+
+and a model is a list of mixer kinds (``layer_types``) with the number of
+leading layers whose feed-forward is dense (``num_dense_layers``); every later
+layer routes over sparse experts. No absolute position table (attention carries
+rotary positions, the convolution needs none), no bias, RMSNorm throughout. A
+new mechanism is a new entry in :data:`MIXERS`, not a model file.
+
+Padding: the stream is zero at padding positions on entry and is zeroed there
+again after every block, so a mixer never reads them (``rms(0) = 0``; attention
+masks them as keys besides) and the expert layer leaves them out of its dispatch.
+
+Each layer kind runs under a ``jax.named_scope`` of its own (``attention``,
+``conv``, ``dense_ffn``, ``moe``) so that a device trace splits by kind.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from replay_tpu.nn.attention import GroupedQueryAttention, RMSNorm
+from replay_tpu.nn.conv import GatedShortConv
+from replay_tpu.nn.ffn import SwiGLU
+from replay_tpu.nn.moe import SparseExperts
+from replay_tpu.parallel.sharding import shard_activation
+
+MIXERS = ("full_attention", "conv")
+
+
+class PatternBlock(nn.Module):
+    """One pre-norm block of the pattern: ``mixer`` names the sequence mixer,
+    ``sparse`` picks the expert layer over the dense SwiGLU."""
+
+    mixer: str
+    sparse: bool
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float
+    conv_kernel: int
+    dense_dim: int
+    expert_dim: int
+    num_experts: int
+    experts_held: int
+    expert_offset: int
+    experts_per_token: int
+    routed_scale: float
+    norm_eps: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, attention_mask, padding_mask):
+        norm = lambda name: RMSNorm(self.norm_eps, dtype=self.dtype, name=name)  # noqa: E731
+        h = norm("mixer_norm")(x)
+        if self.mixer == "full_attention":
+            with jax.named_scope("attention"):
+                h = GroupedQueryAttention(
+                    num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+                    head_dim=self.head_dim, rope_theta=self.rope_theta,
+                    norm_eps=self.norm_eps, dtype=self.dtype, name="attention",
+                )(h, attention_mask)
+        elif self.mixer == "conv":
+            with jax.named_scope("conv"):
+                h = GatedShortConv(self.conv_kernel, dtype=self.dtype, name="conv")(h)
+        else:
+            msg = f"unknown layer type {self.mixer!r}; known: {MIXERS}"
+            raise ValueError(msg)
+        x = x + h
+        h = norm("ffn_norm")(x)
+        if self.sparse:
+            with jax.named_scope("moe"):
+                h = SparseExperts(
+                    num_experts=self.num_experts, experts_held=self.experts_held,
+                    expert_offset=self.expert_offset, top_k=self.experts_per_token,
+                    hidden_dim=self.expert_dim, scale=self.routed_scale,
+                    dtype=self.dtype, name="moe",
+                )(h, token_mask=padding_mask)
+        else:
+            with jax.named_scope("dense_ffn"):
+                h = SwiGLU(self.dense_dim, x.shape[-1], dtype=self.dtype, name="dense_ffn")(h)
+        keep = padding_mask[..., None].astype(x.dtype)
+        return shard_activation((x + h) * keep, "batch", "length", "embed")
+
+
+class LayerPatternEncoder(nn.Module):
+    """``len(layer_types)`` blocks, layer ``i`` mixing by ``layer_types[i]``; the
+    first ``num_dense_layers`` feed forward densely, the rest through experts."""
+
+    layer_types: Sequence[str]
+    num_dense_layers: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float = 10000.0
+    conv_kernel: int = 3
+    dense_dim: int = 256
+    expert_dim: int = 64
+    num_experts: int = 8
+    experts_held: Optional[int] = None  # None: every expert lives here
+    expert_offset: int = 0
+    experts_per_token: int = 2
+    routed_scale: float = 1.0
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, attention_mask, padding_mask):
+        held = self.num_experts if self.experts_held is None else self.experts_held
+        for i, mixer in enumerate(self.layer_types):
+            x = PatternBlock(
+                mixer=mixer, sparse=i >= self.num_dense_layers,
+                num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim, rope_theta=self.rope_theta,
+                conv_kernel=self.conv_kernel, dense_dim=self.dense_dim,
+                expert_dim=self.expert_dim, num_experts=self.num_experts,
+                experts_held=held, expert_offset=self.expert_offset,
+                experts_per_token=self.experts_per_token, routed_scale=self.routed_scale,
+                norm_eps=self.norm_eps, dtype=self.dtype, name=f"layer_{i}",
+            )(x, attention_mask, padding_mask)
+        return x

@@ -1,0 +1,129 @@
+"""HybridRec: a next-item model over a layer-pattern block stack.
+
+The generative-recommender setting: the backbone of a sparse-expert language
+model (gated short convolutions with a full-attention layer every few blocks,
+grouped-query rotary attention, sigmoid-routed SwiGLU experts) over an ITEM
+vocabulary. The item catalog takes the place of the token vocabulary, the tied
+item table scores the next item, and everything around the block stack is
+SasRec's: the same ``schema=`` constructor and embedder, the same tying head,
+``Trainer.fit`` with ``CE`` and the SASRec train transforms.
+
+    x    = table[items] * keep                      no position table, no scaling
+    x    = LayerPatternEncoder(x)                   replay_tpu.nn.blocks
+    out  = rms(x)                                   the family's embedding norm
+    logits = out . table[:num_items]^T
+
+Source of the layer equations and of the default widths' names: the public
+``lfm2_moe`` configuration (https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json).
+``experts_held`` / ``expert_offset`` give this chip's share of each expert
+layer (replay_tpu.nn.moe); the defaults hold every expert.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from replay_tpu.data.nn.schema import TensorMap, TensorSchema
+from replay_tpu.nn.attention import RMSNorm
+from replay_tpu.nn.blocks import LayerPatternEncoder
+from replay_tpu.nn.embedding import SequenceEmbedding
+from replay_tpu.nn.head import EmbeddingTyingHead
+from replay_tpu.nn.mask import causal_attention_mask
+from replay_tpu.parallel.sharding import shard_activation
+
+
+class HybridRec(nn.Module):
+    """The layer-pattern next-item model with an embedding-tying head (see the
+    module docstring). The item feature's ``embedding_dim`` in the schema is the
+    model width. ``experts_held`` / ``expert_offset``: the share of each expert
+    layer that lives on this chip (``None``: every expert); what the expert
+    layers count (``expert_load``, ``dropped_assignments``) rides the trainer's
+    step metrics."""
+
+    logits_via_item_weights = True  # bias-free head: get_logits(h) == h . table^T
+    sows_counters = True  # the expert layers sow into the `counters` collection
+
+    schema: TensorSchema
+    layer_types: Sequence[str] = ("conv", "full_attention")
+    num_dense_layers: int = 1
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: Optional[int] = None  # None: width / num_heads
+    rope_theta: float = 1_000_000.0
+    conv_kernel: int = 3
+    dense_dim: int = 256
+    expert_dim: int = 64
+    num_experts: int = 8
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    experts_per_token: int = 2
+    routed_scale: float = 1.0
+    norm_eps: float = 1e-5
+    excluded_features: tuple = ()
+    dtype: Any = jnp.float32
+    embedding_init: Any = None
+
+    def setup(self) -> None:
+        self.embedder = SequenceEmbedding(
+            schema=self.schema, excluded_features=self.excluded_features,
+            dtype=self.dtype, embedding_init=self.embedding_init, name="embedder",
+        )
+        width = self.schema[self.schema.item_id_feature_name].embedding_dim
+        self.encoder = LayerPatternEncoder(
+            layer_types=tuple(self.layer_types), num_dense_layers=self.num_dense_layers,
+            num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim or width // self.num_heads,
+            rope_theta=self.rope_theta, conv_kernel=self.conv_kernel,
+            dense_dim=self.dense_dim, expert_dim=self.expert_dim,
+            num_experts=self.num_experts, experts_held=self.experts_held,
+            expert_offset=self.expert_offset, experts_per_token=self.experts_per_token,
+            routed_scale=self.routed_scale, norm_eps=self.norm_eps,
+            dtype=self.dtype, name="encoder",
+        )
+        self.final_norm = RMSNorm(self.norm_eps, dtype=self.dtype, name="final_norm")
+        self.head = EmbeddingTyingHead()
+
+    def __call__(self, feature_tensors: TensorMap, padding_mask: jnp.ndarray) -> jnp.ndarray:
+        """Hidden states [B, L, E] (the training forward)."""
+        with jax.named_scope("embed"):
+            embeddings = self.embedder(feature_tensors)
+            x = sum(embeddings[name] for name in sorted(embeddings))
+            x = x * padding_mask[..., None].astype(x.dtype)
+            x = shard_activation(x, "batch", "length", "embed")
+        with jax.named_scope("encoder"):
+            mask = causal_attention_mask(padding_mask, dtype=self.dtype)
+            x = self.encoder(x, mask, padding_mask)
+        with jax.named_scope("final_norm"):
+            return shard_activation(self.final_norm(x), "batch", "length", "embed")
+
+    def get_logits(
+        self, hidden: jnp.ndarray, candidates_to_score: Optional[jnp.ndarray] = None
+    ) -> jnp.ndarray:
+        """Scores against the catalog, or against candidate ids ([K] or [B, ..., K])."""
+        if candidates_to_score is None:
+            return self.head(hidden, self.embedder.get_item_weights())
+        embedded = self.embedder.get_item_weights(candidates_to_score)
+        if candidates_to_score.ndim == 1:
+            return self.head(hidden, embedded)
+        return jnp.einsum("...e,...ke->...k", hidden, embedded)
+
+    def forward_inference(
+        self,
+        feature_tensors: TensorMap,
+        padding_mask: jnp.ndarray,
+        candidates_to_score: Optional[jnp.ndarray] = None,
+    ) -> jnp.ndarray:
+        """Scores of the NEXT item after each sequence: [B, num_items] or [B, K]."""
+        return self.get_logits(self(feature_tensors, padding_mask)[:, -1, :], candidates_to_score)
+
+    def get_query_embeddings(
+        self, feature_tensors: TensorMap, padding_mask: jnp.ndarray
+    ) -> jnp.ndarray:
+        return self(feature_tensors, padding_mask)[:, -1, :]
+
+    def get_item_weights(self) -> jnp.ndarray:
+        return self.embedder.get_item_weights()

@@ -448,6 +448,16 @@ def _local_rows(array: jnp.ndarray) -> np.ndarray:
     return rows
 
 
+def _fold_counters(collection: Any) -> Dict[str, Any]:
+    """A sown ``counters`` collection as ``{name: array}``: the leaves of every
+    module that sowed ``name``, in module order, stacked on a leading axis."""
+    by_name: Dict[str, list] = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(collection)[0]:
+        names = [key.key for key in path if isinstance(key, jax.tree_util.DictKey)]
+        by_name.setdefault(names[-1], []).append(leaf)
+    return {name: jnp.stack(leaves) for name, leaves in by_name.items()}
+
+
 def _globalize_scalars(mesh: Mesh, tree: Any) -> Any:
     """Promote leaves created outside any mesh (e.g. adam's ``count`` scalar
     from ``tx.init``) to replicated arrays ON the mesh — global arrays when
@@ -895,6 +905,11 @@ class Trainer:
                     loss.data_axis = row_axes if len(row_axes) > 1 else row_axes[0]
         label_f, tmask_f, neg_f = self.label_field, self.target_mask_field, self.negative_field
         pad_f = self.padding_mask_field
+        # python-static, like `health`: which collections the forward hands back
+        counts = bool(getattr(model, "sows_counters", False))
+        collected = ["counters"] * counts + ["intermediates"] * bool(
+            health is not None and health.capture_intermediates
+        )
 
         # `health` branches below are python-static (resolved at trace time,
         # like the models' sow guards): health=None lowers to byte-identical
@@ -927,6 +942,7 @@ class Trainer:
                 ]
 
             def loss_fn(params):
+                intermediates, counters = {}, {}
                 kwargs = {
                     name: batch[name] for name in self._forward_params if name in batch
                 }
@@ -935,21 +951,23 @@ class Trainer:
                 # named scopes label the lowered HLO so a jax.profiler device
                 # trace correlates with the host-side Tracer spans by name
                 with jax.named_scope("forward"):
-                    if health is not None and health.capture_intermediates:
-                        # mutable `intermediates`: the bodies' sow sites
-                        # (stage stats, attention entropy) become live
+                    if collected:
+                        # mutable `intermediates`: the bodies' sow sites (stage
+                        # stats, attention entropy) become live; `counters`:
+                        # what a model that declares `sows_counters` counts
+                        # (sparse experts: per-expert load, dropped assignments)
                         hidden, variables = model.apply(
                             {"params": params},
                             rngs={"dropout": dropout_rng},
-                            mutable=["intermediates"],
+                            mutable=collected,
                             **kwargs,
                         )
                         intermediates = variables.get("intermediates", {})
+                        counters = variables.get("counters", {})
                     else:
                         hidden = model.apply(
                             {"params": params}, rngs={"dropout": dropout_rng}, **kwargs
                         )
-                        intermediates = {}
                 logits_extra = {
                     name: batch[name] for name in self._logits_extra_params if name in batch
                 }
@@ -979,14 +997,14 @@ class Trainer:
                         batch[pad_f],
                         target_mask,
                     )
-                if health is None:
+                if not collected and health is None:
                     return loss_value
-                return loss_value, (hidden, intermediates)
+                return loss_value, (hidden, intermediates, counters)
 
-            if health is None:
+            if not collected and health is None:
                 loss_value, grads = jax.value_and_grad(loss_fn)(state.params)
             else:
-                (loss_value, (hidden, intermediates)), grads = jax.value_and_grad(
+                (loss_value, (hidden, intermediates, counters)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True
                 )(state.params)
             # non-finite sentinel: one fused flag decides, in-jit, whether this
@@ -1000,6 +1018,8 @@ class Trainer:
             params = optax.apply_updates(state.params, updates)
 
             metrics = {"loss": loss_value, "good": good, "grad_norm": grad_norm}
+            if counts:
+                metrics["counters"] = _fold_counters(counters)
             if health is not None:
                 logits = None
                 streamed_stats = None
@@ -2280,6 +2300,18 @@ class Trainer:
                     # a health record fetched since the last emission
                     # rides the next step event (cadences may differ)
                     **({"health": pending_health} if pending_health is not None else {}),
+                    # what the model counted in this step (sparse experts:
+                    # `expert_load` per layer and held expert, `dropped_assignments`)
+                    **(
+                        {
+                            "counters": {
+                                name: np.asarray(value).tolist()
+                                for name, value in step_metrics["counters"].items()
+                            }
+                        }
+                        if "counters" in step_metrics
+                        else {}
+                    ),
                 )
                 pending_health = None
             return rolled_back
@@ -2467,6 +2499,12 @@ class Trainer:
                                             losses = np.asarray(chunk_metrics["loss"])
                                             goods = np.asarray(chunk_metrics["good"])
                                             grad_norms = np.asarray(chunk_metrics["grad_norm"])
+                                            counters = {
+                                                name: np.asarray(value)
+                                                for name, value in chunk_metrics.get(
+                                                    "counters", {}
+                                                ).items()
+                                            }
                                     compile_delta = (
                                         self.compile_tracker.total_compile_seconds
                                         - compile_before
@@ -2475,7 +2513,8 @@ class Trainer:
                                     # and `account` opens: everything from here
                                     # to the next pull on the feed
                                     chunk_stages.synced(
-                                        k, dispatch, device_wait, compile_delta > 0, feeder
+                                        k, dispatch, device_wait, compile_delta > 0, feeder,
+                                        {n: v.tolist() for n, v in counters.items()},
                                     )
                                     # the scan has read the chunk's device
                                     # copy: let it go here, inside `account`
@@ -2507,6 +2546,11 @@ class Trainer:
                                                 "loss": losses[i],
                                                 "good": goods[i],
                                                 "grad_norm": grad_norms[i],
+                                                **(
+                                                    {"counters": {n: v[i] for n, v in counters.items()}}
+                                                    if counters
+                                                    else {}
+                                                ),
                                             },
                                             epoch,
                                             step_id=step_base + measured_total + 1,

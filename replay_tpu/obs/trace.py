@@ -608,12 +608,19 @@ def chunk_stage_log() -> List[Dict[str, Any]]:
     ``account`` and of the feeder's ``stack``, ``h2d``, ``feed_full``,
     ``batch_build``, ``transform`` (total) with ``transform_by_name``,
     ``device_leaves`` (leaves of the chunk's batches that arrived as jax
-    Arrays: each is a D2H read inside ``stack``) and ``h2d_bytes``.
+    Arrays: each is a D2H read inside ``stack``), ``h2d_bytes`` and, for a
+    model that counts (``sows_counters``), ``counters``: per name the chunk's
+    ``[steps, ...]`` values as nested lists (``expert_load``:
+    ``[steps, expert layers, held experts]``).
     Interleaved single steps (health cadence, an epoch's short tail) are in
     the next chunk's ``period`` and in none of its stages.
     """
     with _CHUNK_LOG_LOCK:
         return [dict(record) for record in _CHUNK_LOG]
+
+
+def _total(nested: Any) -> Any:
+    return sum(map(_total, nested)) if isinstance(nested, list) else nested
 
 
 class ChunkStages:
@@ -677,8 +684,14 @@ class ChunkStages:
         device_wait: stage,
         compiled: bool,
         feeder: Optional[Mapping[str, Any]] = None,
+        counters: Optional[Mapping[str, Any]] = None,
     ) -> Dict[str, Any]:
-        self._account = self.stage("account")
+        # what the model counted over the chunk (nested lists [K, ...] by name)
+        # rides the `account` span as totals and the chunk's record in full
+        counters = counters or {}
+        self._account = self.stage(
+            "account", **{name: _total(value) for name, value in counters.items()}
+        )
         self._account.__enter__()
         done = device_wait.end
         feeder = feeder or {}
@@ -700,6 +713,8 @@ class ChunkStages:
         record["transform_by_name"] = dict(feeder.get("transform_by_name", ()))
         record["device_leaves"] = int(feeder.get("device_leaves", 0))
         record["h2d_bytes"] = int(feeder.get("h2d_bytes", 0))
+        if counters:
+            record["counters"] = dict(counters)
         with _CHUNK_LOG_LOCK:
             _CHUNK_LOG.append(record)
         self._done = done

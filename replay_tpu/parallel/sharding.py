@@ -24,6 +24,9 @@ kv        per-head key/value width (reserved; fused into ``heads`` today)
 position  rows of a positional table (NEVER sequence-sharded: positional rows
           are indexed by a python slice, not by activation position)
 layers    the stacked-blocks axis of a ``scan_blocks`` encoder
+expert    the experts a sparse layer holds (``replay_tpu.nn.moe``): mapped to no
+          mesh axis by default; expert parallelism maps it once the exchange
+          of tokens between chips exists
 ========  ====================================================================
 
 The default table maps ``batch → "data"``, ``length → "seq"``, ``vocab →
@@ -73,6 +76,7 @@ LOGICAL_AXES = (
     "mlp",
     "position",
     "layers",
+    "expert",
 )
 
 
@@ -114,6 +118,7 @@ class ShardingRules:
                 "mlp": None,
                 "position": None,
                 "layers": None,
+                "expert": None,
             }
         )
 
@@ -221,12 +226,28 @@ _PARAM_RULES: Tuple[Tuple[Tuple[str, ...], str, Tuple[str, ...]], ...] = (
     (("attention",), "lambda_k1", ("heads",)),
     (("attention",), "lambda_q2", ("heads",)),
     (("attention",), "lambda_k2", ("heads",)),
+    # sparse experts (nn.moe): stacked SwiGLU kernels [expert, embed, mlp] /
+    # [expert, mlp, embed]; the router [embed, all experts] and the selection
+    # bias [all experts] span EVERY expert, held here or not: no "expert" name
+    (("moe",), "gate", ("expert", "embed", "mlp")),
+    (("moe",), "value", ("expert", "embed", "mlp")),
+    (("moe",), "out", ("expert", "mlp", "embed")),
+    (("moe", "router"), "kernel", ("embed", None)),
+    (("moe",), "expert_bias", (None,)),
+    # gated short convolution (nn.conv): in_proj [embed, 3·embed] holds three
+    # gates side by side ("mlp": the widened axis), out_proj [embed, embed]
+    (("conv", "in_proj"), "kernel", ("embed", "mlp")),
+    (("conv", "out_proj"), "kernel", ("mlp", "embed")),
+    (("conv",), "kernel", (None, "embed")),
     # FFN: inner/gate/value kernels [embed, mlp], outer/out [mlp, embed]
     (("ffn", "outer"), "kernel", ("mlp", "embed")),
     (("ffn", "outer"), "bias", ("embed",)),
     (("ffn", "out"), "kernel", ("mlp", "embed")),
     (("ffn",), "kernel", ("embed", "mlp")),
     (("ffn",), "bias", ("mlp",)),
+    # per-head norms on q and k (GroupedQueryAttention): one head's width
+    (("q_norm",), "scale", ("kv",)),
+    (("k_norm",), "scale", ("kv",)),
     # norms and generic projections live in the residual stream. A proj
     # kernel's INPUT dim gets no logical name: it is a stacked-feature /
     # tensor_dim axis (NumericalEmbedding, ConcatAggregator) — and naming it

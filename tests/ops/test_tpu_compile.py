@@ -13,6 +13,7 @@ load libtpu; see the on-chip-measurement guide §2): never at import, in a
 """
 
 import os
+from functools import partial
 
 import numpy as np
 import pytest
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from replay_tpu.nn.attention import dot_product_attention
+from replay_tpu.nn.moe import grouped_matmul
 from replay_tpu.ops.flash_attention import flash_attention
 from replay_tpu.ops.flash_tiled import flash_attention_tiled
 from replay_tpu.ops.fused_ce import fused_lse
@@ -99,6 +101,21 @@ def test_flash_tiled_lowers(one_chip, shape, grad):
     qkv = jax.ShapeDtypeStruct(shape, bf16, sharding=one_chip)
     bias = jax.ShapeDtypeStruct((shape[0], shape[2]), f32, sharding=one_chip)
     assert_mosaic(fwd_or_grad(flash_attention_tiled, grad, (0, 1, 2)), qkv, qkv, qkv, bias)
+
+
+# (rows, contraction, columns): the expert layer's two grouped products at the
+# published widths (d 2048, expert width 1536), 8 x 1024 positions x 4 picks of rows
+GROUPED_SHAPES = [(32768, 2048, 1536), (32768, 1536, 2048)]
+
+
+@pytest.mark.parametrize("rows,inner,cols", GROUPED_SHAPES)
+def test_grouped_matmul_lowers_forward_and_backward(one_chip, rows, inner, cols):
+    lhs = jax.ShapeDtypeStruct((rows, inner), bf16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((8, inner, cols), bf16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    # the gradient's program holds the forward kernel and both backward ones
+    compiled = partial(grouped_matmul, interpret=False)  # as on the chip: here the CPU would interpret
+    assert_mosaic(fwd_or_grad(compiled, True, (0, 1)), lhs, rhs, sizes)
 
 
 def test_flash_single_block_lowers_at_its_bound(one_chip):
