@@ -101,8 +101,13 @@ def make_default_bert4rec_transforms(
     item_id = tensor_schema.item_id_feature_name
     train = [
         RenameTransform({f"{item_id}_mask": "padding_mask"}),
+        # what needs no random bits comes first and stays on the host: Compose
+        # compiles the pipeline from the first stochastic transform on as ONE
+        # program, and only the two masks it makes are device arrays
+        CopyTransform({item_id: "positive_labels"}),
+        UnsqueezeTransform("positive_labels", -1),
         TokenMaskTransform(token_name="padding_mask", mask_prob=mask_prob),
-        CopyTransform({item_id: "positive_labels", "padding_mask": "target_padding_mask"}),
+        CopyTransform({"padding_mask": "target_padding_mask"}),
         # target positions = real tokens that were masked out
         EqualityMaskTransform(
             feature_name="token_mask",
@@ -110,7 +115,6 @@ def make_default_bert4rec_transforms(
             equality_value=False,
             op="and",
         ),
-        UnsqueezeTransform("positive_labels", -1),
         UnsqueezeTransform("target_padding_mask", -1),
         GroupTransform({"feature_tensors": list(tensor_schema.names)}),
     ]

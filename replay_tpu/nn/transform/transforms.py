@@ -4,10 +4,29 @@ Capability parity with replay/nn/transform/*.py (~830 LoC): NextToken, negative
 sampling (uniform + multi-class), TokenMask, SequenceRoll, Trim/AdaptiveTrim,
 EqualityMask, Copy, Rename, Select, Unsqueeze, Group, composed per split.
 
-JAX design: every transform is a pure callable ``batch, rng -> batch`` on jnp/numpy
-arrays (no module state); randomness comes from an explicit PRNG key threaded by
-:class:`Compose`. All ops are static-shape except ``AdaptiveTrimTransform``, which is
-host-side only (data-dependent length) and documented as such.
+JAX design: every transform is a pure callable ``batch, rng -> batch`` (no module
+state); randomness comes from an explicit PRNG key threaded by :class:`Compose`.
+The pipeline runs on the feeder thread beside a chip that is busy with the step,
+so the contract is (docs/performance.md "Closing the dispatch gap"):
+
+* **numpy in, numpy out.** A deterministic transform works in the array
+  namespace of the leaf it is given (:func:`_namespace`): a numpy leaf stays
+  numpy and no device is touched; a ``jax.Array`` or a tracer gets ``jax.numpy``.
+  A bare ``jnp`` call on a host leaf is an eager device program whose result
+  ``Trainer._stack_chunk`` has to read back, behind the running scan.
+* **A random draw is ONE compiled program.** A stochastic transform keeps its
+  draw and everything that depends on it in one ``jax.jit`` kernel built once
+  per instance, and :class:`Compose` compiles the run of transforms from the
+  first stochastic one on (its own key splits included) as one program: one
+  dispatch a batch instead of one per ``jnp`` call.
+* **No host read of a device value.** The caller's key lives on the chip, queued
+  behind the scan: reading it (``np.asarray(key)``, ``jax.random.key_data``,
+  ``device_get``) to "draw the mask in numpy" stalls the feeder a whole chunk a
+  batch. The bits are made and used on the device; what does not depend on them
+  never leaves the host.
+
+All ops are static-shape except ``AdaptiveTrimTransform``, which is host-side only
+(data-dependent length) and documented as such.
 """
 
 from __future__ import annotations
@@ -16,11 +35,18 @@ from typing import Dict, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from replay_tpu.obs.trace import stage
 
 DEFAULT_MASK_POSTFIX = "_mask"
 Batch = Dict[str, jnp.ndarray]
+
+
+def _namespace(*leaves):
+    """``numpy`` while every leaf is a host array, ``jax.numpy`` once one is a
+    ``jax.Array`` (a tracer is one): the result lives where its input lives."""
+    return jnp if any(isinstance(leaf, jax.Array) for leaf in leaves) else np
 
 
 class Transform:
@@ -35,29 +61,90 @@ class Transform:
 class Compose(Transform):
     """Apply transforms in order, splitting the rng across the stochastic ones.
 
-    Each transform runs as a ``transform`` stage (``obs.trace.stage``) that
-    carries its class name: in a profiler capture and in the chunk stage log's
-    ``transform_by_name`` the cost of the input pipeline reads per transform."""
+    The deterministic transforms ahead of the first stochastic one run one by
+    one where their leaves live (host leaves: no device program). From the first
+    stochastic transform on, the rest of the pipeline is ONE compiled program
+    ``(rng, leaves) -> new leaves``, its ``jax.random.split`` per stochastic
+    transform inside it, cached by the batch's structure and shapes. A leaf the
+    run hands through untouched comes back as the object that went in (a numpy
+    array stays one), so put what needs no random bits ahead of the first draw.
+
+    Each step runs as a ``transform`` stage (``obs.trace.stage``) that carries
+    the class name (the compiled run: its classes joined by ``+``) and
+    ``device_programs``, the compiled programs ``Compose`` dispatched in it (0
+    on the host, 1 for the run): in a profiler capture and in the chunk stage
+    log's ``transform_by_name`` / ``transform_device_programs`` the cost of the
+    input pipeline reads per transform. A deterministic transform that its
+    caller hands ``jax.Array`` leaves works on them in eager ``jax.numpy``;
+    those programs are not counted here (``device_leaves`` of ``stack`` sees
+    their results)."""
 
     def __init__(self, transforms: Sequence[Transform]) -> None:
         self.transforms = list(transforms)
+        # how many run ahead of the compiled run: up to the first stochastic one
+        self._host = next(
+            (i for i, t in enumerate(self.transforms) if t.needs_rng), len(self.transforms)
+        )
+        self._run_name = "+".join(type(t).__name__ for t in self.transforms[self._host :])
+        self._programs: Dict[tuple, tuple] = {}
 
     @property
     def needs_rng(self) -> bool:  # type: ignore[override]
-        return any(t.needs_rng for t in self.transforms)
+        return self._host < len(self.transforms)
 
     def __call__(self, batch: Batch, rng: Optional[jax.Array] = None) -> Batch:
-        for transform in self.transforms:
-            with stage("transform", transform=type(transform).__name__):
+        for transform in self.transforms[: self._host]:
+            with stage("transform", transform=type(transform).__name__, device_programs=0):
+                batch = transform(batch)
+        if not self.needs_rng:
+            return batch
+        if rng is None:
+            msg = f"{type(self.transforms[self._host]).__name__} needs an rng key"
+            raise ValueError(msg)
+        dispatched = int(not isinstance(rng, jax.core.Tracer))  # inlined under a caller's jit
+        with stage("transform", transform=self._run_name, device_programs=dispatched):
+            return self._compiled_run(batch, rng)
+
+    def _compiled_run(self, batch: Batch, rng: jax.Array) -> Batch:
+        leaves, treedef = jax.tree.flatten(batch)
+        # read from attributes: np.result_type of a jax.Array would read it back
+        key = (
+            treedef,
+            tuple((np.shape(leaf), getattr(leaf, "dtype", type(leaf))) for leaf in leaves),
+        )
+        if key not in self._programs:
+            self._programs[key] = self._compile_run(treedef)
+        program, plan = self._programs[key]
+        made = iter(program(rng, leaves))
+        return jax.tree.unflatten(
+            plan["treedef"],
+            [next(made) if source is None else leaves[source] for source in plan["sources"]],
+        )
+
+    def _compile_run(self, treedef):
+        """The jitted run for one batch structure, and its plan: the output's
+        treedef and, per output leaf, the index of the caller's own leaf that is
+        handed through, or ``None`` for the program's next result (the tracing
+        fills it in: a leaf the transforms did not touch IS the tracer that came
+        in). jit leaves an argument nothing reads on the host."""
+        plan: Dict[str, object] = {}
+
+        def program(rng, leaves):
+            batch = jax.tree.unflatten(treedef, leaves)
+            for transform in self.transforms[self._host :]:
                 if transform.needs_rng:
-                    if rng is None:
-                        msg = f"{type(transform).__name__} needs an rng key"
-                        raise ValueError(msg)
                     rng, sub = jax.random.split(rng)
                     batch = transform(batch, sub)
                 else:
                     batch = transform(batch)
-        return batch
+            out_leaves, plan["treedef"] = jax.tree.flatten(batch)
+            sources = plan["sources"] = [
+                next((i for i, leaf in enumerate(leaves) if leaf is out), None)
+                for out in out_leaves
+            ]
+            return [out for out, source in zip(out_leaves, sources) if source is None]
+
+        return jax.jit(program), plan
 
 
 class NextTokenTransform(Transform):
@@ -112,7 +199,9 @@ class NextTokenTransform(Transform):
         if label_mask_name in batch:
             out[f"{self.out_feature_name}{self.mask_postfix}"] = batch[label_mask_name][:, shift:]
         else:
-            out[f"{self.out_feature_name}{self.mask_postfix}"] = jnp.ones_like(labels, dtype=bool)
+            out[f"{self.out_feature_name}{self.mask_postfix}"] = _namespace(labels).ones_like(
+                labels, dtype=bool
+            )
         return out
 
 
@@ -141,19 +230,19 @@ class UniformNegativeSamplingTransform(Transform):
         self.cardinality = cardinality
         self.num_negative_samples = num_negative_samples
         self.out_feature_name = out_feature_name
+        if sample_distribution is not None:
+            sample_distribution = jnp.asarray(sample_distribution)  # on the device once
         self.sample_distribution = sample_distribution
+        self._kernel = jax.jit(self._draw)
+
+    def _draw(self, rng, distribution):
+        probs = None if distribution is None else distribution / jnp.sum(distribution)
+        return jax.random.choice(
+            rng, self.cardinality, shape=(self.num_negative_samples,), replace=False, p=probs
+        )
 
     def __call__(self, batch: Batch, rng=None) -> Batch:
-        if self.sample_distribution is None:
-            negatives = jax.random.choice(
-                rng, self.cardinality, shape=(self.num_negative_samples,), replace=False
-            )
-        else:
-            probs = self.sample_distribution / jnp.sum(self.sample_distribution)
-            negatives = jax.random.choice(
-                rng, self.cardinality, shape=(self.num_negative_samples,), replace=False, p=probs
-            )
-        return {**batch, self.out_feature_name: negatives}
+        return {**batch, self.out_feature_name: self._kernel(rng, self.sample_distribution)}
 
 
 class MultiClassNegativeSamplingTransform(Transform):
@@ -173,8 +262,6 @@ class MultiClassNegativeSamplingTransform(Transform):
         reference_name: str = "item_id",
         out_feature_name: str = "negative_labels",
     ) -> None:
-        import numpy as np
-
         class_assignment = np.asarray(class_assignment)
         self.class_assignment = jnp.asarray(class_assignment)
         self.num_negative_samples = num_negative_samples
@@ -199,16 +286,24 @@ class MultiClassNegativeSamplingTransform(Transform):
                 table[c, : len(m)] = m
         self._class_items = jnp.asarray(table)  # [num_classes, max_class_size]
         self._class_sizes = jnp.asarray(sizes)  # [num_classes]
+        self._kernel = jax.jit(self._draw)
+
+    def _draw(self, rng, last_items, class_assignment, class_items, class_sizes):
+        # the tables are arguments, not constants of the program: they stay on
+        # the device and a catalog-sized one is not baked into the executable
+        classes = class_assignment[jnp.clip(last_items, 0, class_assignment.shape[0] - 1)]
+        draws = jax.random.randint(
+            rng, (classes.shape[0], self.num_negative_samples), 0, jnp.iinfo(jnp.int32).max
+        )
+        indices = draws % class_sizes[classes][:, None]
+        return jnp.take_along_axis(class_items[classes], indices, axis=1)
 
     def __call__(self, batch: Batch, rng=None) -> Batch:
         reference = batch[self.reference_name]
         last_items = reference[:, -1] if reference.ndim > 1 else reference
-        classes = self.class_assignment[jnp.clip(last_items, 0, self.class_assignment.shape[0] - 1)]
-        draws = jax.random.randint(
-            rng, (classes.shape[0], self.num_negative_samples), 0, jnp.iinfo(jnp.int32).max
+        negatives = self._kernel(
+            rng, last_items, self.class_assignment, self._class_items, self._class_sizes
         )
-        indices = draws % self._class_sizes[classes][:, None]
-        negatives = jnp.take_along_axis(self._class_items[classes], indices, axis=1)
         return {**batch, self.out_feature_name: negatives}
 
 
@@ -307,12 +402,17 @@ class TokenMaskTransform(Transform):
         self.out_feature_name = out_feature_name
         self.mask_prob = mask_prob
         self.mask_postfix = mask_postfix
+        self._kernel = jax.jit(self._keep)
 
     def __call__(self, batch: Batch, rng=None) -> Batch:
         padding = batch[self.token_name]
         if padding.dtype != jnp.bool_:
             msg = "Source tensor for token mask must be boolean (a padding mask)."
             raise ValueError(msg)
+        return {**batch, self.out_feature_name: self._kernel(rng, padding)}
+
+    def _keep(self, rng, padding):
+        """``(rng, padding) -> keep``: the draw, the comparison and both repairs."""
         uniform = jax.random.uniform(rng, padding.shape)
         keep = (uniform * padding) >= self.mask_prob  # padded positions always False
 
@@ -331,7 +431,7 @@ class TokenMaskTransform(Transform):
         keep = keep.at[rows, before_last].set(
             jnp.where(none_kept, True, keep[rows, before_last])
         )
-        return {**batch, self.out_feature_name: keep}
+        return keep
 
 
 class SequenceRollTransform(Transform):
@@ -346,11 +446,17 @@ class SequenceRollTransform(Transform):
         self.padding_value = padding_value
 
     def __call__(self, batch: Batch, rng=None) -> Batch:
-        rolled = jnp.roll(batch[self.feature_name], self.roll, axis=1)
-        if self.roll > 0:
-            rolled = rolled.at[:, : self.roll].set(self.padding_value)
+        value = batch[self.feature_name]
+        xp = _namespace(value)
+        # at most the whole axis: past its length nothing of the sequence is left
+        roll = max(min(self.roll, value.shape[1]), -value.shape[1])
+        vacated = xp.full(
+            (value.shape[0], abs(roll)) + value.shape[2:], self.padding_value, dtype=value.dtype
+        )
+        if roll > 0:
+            rolled = xp.concatenate([vacated, value[:, : value.shape[1] - roll]], axis=1)
         else:
-            rolled = rolled.at[:, self.roll :].set(self.padding_value)
+            rolled = xp.concatenate([value[:, -roll:], vacated], axis=1)
         return {**batch, self.feature_name: rolled}
 
 
@@ -396,11 +502,7 @@ class AdaptiveTrimTransform(Transform):
 class EqualityMaskTransform(Transform):
     """Combine ``mask_name`` with (feature == value) under AND/OR/XOR."""
 
-    _OPS = {
-        "and": jnp.logical_and,
-        "or": jnp.logical_or,
-        "xor": jnp.logical_xor,
-    }
+    _OPS = {"and": "logical_and", "or": "logical_or", "xor": "logical_xor"}
 
     def __init__(self, feature_name: str, mask_name: str, equality_value, op: str = "and") -> None:
         if op not in self._OPS:
@@ -412,9 +514,10 @@ class EqualityMaskTransform(Transform):
         self.op = op
 
     def __call__(self, batch: Batch, rng=None) -> Batch:
+        mask = batch[self.mask_name]
         modification = batch[self.feature_name] == self.equality_value
-        combined = self._OPS[self.op](batch[self.mask_name], modification)
-        return {**batch, self.mask_name: combined}
+        combine = getattr(_namespace(mask, modification), self._OPS[self.op])
+        return {**batch, self.mask_name: combine(mask, modification)}
 
 
 class CopyTransform(Transform):
@@ -453,7 +556,8 @@ class UnsqueezeTransform(Transform):
         self.axis = axis
 
     def __call__(self, batch: Batch, rng=None) -> Batch:
-        return {**batch, self.feature_name: jnp.expand_dims(batch[self.feature_name], self.axis)}
+        value = batch[self.feature_name]
+        return {**batch, self.feature_name: _namespace(value).expand_dims(value, self.axis)}
 
 
 class GroupTransform(Transform):

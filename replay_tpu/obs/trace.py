@@ -513,7 +513,9 @@ class stage:  # noqa: N801 - used as ``with stage(name):``, like Tracer.span
     After exit :attr:`seconds` is the duration and :attr:`end` the
     ``perf_counter`` reading it closed at. A span whose args hold a key of its
     own name (``stage("transform", transform="Mask")``) is also totalled by
-    that value under ``<name>_by_name``.
+    that value under ``<name>_by_name``, and one that counts the compiled
+    programs it dispatched (``device_programs=n``) is summed under
+    ``<name>_device_programs``.
     """
 
     __slots__ = ("name", "args", "seconds", "end", "span", "_tracer", "_annotation", "_start")
@@ -567,6 +569,10 @@ class stage:  # noqa: N801 - used as ``with stage(name):``, like Tracer.span
         if key is not None:
             by_name = totals.setdefault(name + "_by_name", {})
             by_name[key] = by_name.get(key, 0.0) + seconds
+        programs = self.args.get("device_programs")
+        if programs is not None:
+            counted = name + "_device_programs"
+            totals[counted] = totals.get(counted, 0) + programs
 
 
 def claim_chunk(chunk: int) -> Dict[str, Any]:
@@ -607,6 +613,8 @@ def chunk_stage_log() -> List[Dict[str, Any]]:
     of the fit thread's ``data_wait``, ``dispatch``, ``device_wait``,
     ``account`` and of the feeder's ``stack``, ``h2d``, ``feed_full``,
     ``batch_build``, ``transform`` (total) with ``transform_by_name``,
+    ``transform_device_programs`` (compiled programs ``Compose`` dispatched
+    for the chunk's batches: 0 while the pipeline stays on the host),
     ``device_leaves`` (leaves of the chunk's batches that arrived as jax
     Arrays: each is a D2H read inside ``stack``), ``h2d_bytes`` and, for a
     model that counts (``sows_counters``), ``counters``: per name the chunk's
@@ -711,6 +719,7 @@ class ChunkStages:
         for name in _FEEDER_FIELDS:
             record[name] = float(feeder.get(name, 0.0))
         record["transform_by_name"] = dict(feeder.get("transform_by_name", ()))
+        record["transform_device_programs"] = int(feeder.get("transform_device_programs", 0))
         record["device_leaves"] = int(feeder.get("device_leaves", 0))
         record["h2d_bytes"] = int(feeder.get("h2d_bytes", 0))
         if counters:

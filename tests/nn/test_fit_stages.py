@@ -148,12 +148,62 @@ def test_feeder_spans_are_on_another_thread_with_the_same_chunk(traced_fit):
 
 
 def test_stack_counts_the_device_leaves_of_the_default_transforms(traced_fit):
-    # UnsqueezeTransform makes positive_labels and target_padding_mask jax Arrays:
-    # two leaves a batch, each a device-to-host read inside `stack`
-    assert {r["device_leaves"] for r in traced_fit["records"]} == {2 * SCAN_CHUNK}
-    stacks = [e for e in traced_fit["tracer"].to_chrome_trace()["traceEvents"]
-              if e["name"] == "stack"]
-    assert {e["args"]["device_leaves"] for e in stacks} == {2 * SCAN_CHUNK}
+    # the default SASRec transforms keep numpy in numpy: no leaf arrives as a
+    # jax Array, no transform dispatched a device program
+    assert {r["device_leaves"] for r in traced_fit["records"]} == {0}
+    assert {r["transform_device_programs"] for r in traced_fit["records"]} == {0}
+    events = traced_fit["tracer"].to_chrome_trace()["traceEvents"]
+    assert {e["args"]["device_leaves"] for e in events if e["name"] == "stack"} == {0}
+    assert {e["args"]["device_programs"] for e in events if e["name"] == "transform"} == {0}
+    # one jax Array planted per batch is one device-to-host read inside `stack`
+    trainer, stream = traced_fit["trainer"], traced_fit["stream"]
+
+    def planted():
+        for batch in stream():
+            yield {**batch, "padding_mask": jax.numpy.asarray(batch["padding_mask"])}
+
+    trainer.fit(planted, epochs=1, scan_chunk=SCAN_CHUNK, log_every=0)
+    records = last_fit_records()
+    assert len(records) == traced_fit["chunks_an_epoch"]
+    assert {r["device_leaves"] for r in records} == {SCAN_CHUNK}
+
+
+def test_bert4rec_transforms_dispatch_one_program_a_batch(traced_fit):
+    """The MLM pipeline through the same trainer's chunked fit: ``Compose`` runs
+    ONE compiled program a batch (the stochastic run), and only the two masks
+    it makes arrive as jax Arrays."""
+    from replay_tpu.nn.transform.template import make_default_bert4rec_transforms
+
+    trainer, schema = traced_fit["trainer"], traced_fit["trainer"].model.schema
+    compose = Compose(make_default_bert4rec_transforms(schema, mask_prob=0.3)["train"])
+    rng = np.random.default_rng(3)
+    raws = [
+        {"item_id": rng.integers(0, NUM_ITEMS, (BATCH, SEQ_LEN)).astype(np.int32),
+         "item_id_mask": np.ones((BATCH, SEQ_LEN), bool), "valid": np.ones(BATCH, bool)}
+        for _ in range(3 * SCAN_CHUNK)
+    ]
+
+    def stream():
+        key = jax.random.PRNGKey(0)
+        for raw in raws:
+            key, sub = jax.random.split(key)
+            batch = compose(raw, sub)
+            batch.pop("token_mask")  # SASRec does not take it; its stack would count it
+            yield batch
+
+    tracer = Tracer()
+    trainer.fit(stream, epochs=1, scan_chunk=SCAN_CHUNK, tracer=tracer, log_every=0)
+    records = last_fit_records()
+    assert len(records) == 3
+    # an epoch's first batch is pulled by the fit thread before the feeder starts
+    assert [r["transform_device_programs"] for r in records] == [SCAN_CHUNK - 1] + [SCAN_CHUNK] * 2
+    assert {r["device_leaves"] for r in records} == {SCAN_CHUNK}  # target_padding_mask
+    runs = [e for e in tracer.to_chrome_trace()["traceEvents"]
+            if e["name"] == "transform" and e["args"]["device_programs"]]
+    assert len(runs) == len(raws) and {e["args"]["device_programs"] for e in runs} == {1}
+    assert {e["args"]["transform"] for e in runs} == {
+        "TokenMaskTransform+CopyTransform+EqualityMaskTransform+UnsqueezeTransform+GroupTransform"
+    }
 
 
 def test_records_hold_the_input_pipeline_by_transform(traced_fit):
@@ -171,6 +221,7 @@ def test_compose_of_two_transforms_yields_two_transform_names():
     out = compose({"a": np.zeros((2, 3), np.int32)})
     assert out["b"].shape == (2, 3, 1)
     totals = claim_chunk(-2)
+    assert type(out["b"]) is np.ndarray and totals["transform_device_programs"] == 0
     assert set(totals["transform_by_name"]) == {"RenameTransform", "UnsqueezeTransform"}
     assert totals["transform"] == pytest.approx(sum(totals["transform_by_name"].values()))
 
@@ -202,7 +253,7 @@ def test_with_the_feed_off_stack_and_copy_are_the_fit_threads(traced_fit):
     assert [r["chunk"] for r in records] == list(range(traced_fit["chunks_an_epoch"]))
     assert records[0]["fit"] > traced_fit["records"][0]["fit"]
     assert all(r["stack"] > 0 and r["h2d"] > 0 for r in records)
-    assert all(r["device_leaves"] == 2 * SCAN_CHUNK and not r["compiled"] for r in records)
+    assert all(r["device_leaves"] == 0 and not r["compiled"] for r in records)
     threads = {e["tid"] for e in tracer.to_chrome_trace()["traceEvents"]}
     assert threads == {threading.get_ident()}
     fractions = sink.events[-1].payload["goodput"]["fractions"]

@@ -755,8 +755,10 @@ def test_chunk_stages_tile_the_fit_threads_time_and_carry_the_feeders():
             for _ in range(2):
                 with stage("batch_build"):
                     time.sleep(0.002)
-                with stage("transform", transform="A"):
+                with stage("transform", transform="A", device_programs=1):
                     time.sleep(0.001)
+                with stage("transform", transform="B", device_programs=0):
+                    pass
             record = claim_chunk(chunk)
             record["device_leaves"] = 2
             with stage("stack", chunk=chunk):
@@ -787,7 +789,11 @@ def test_chunk_stages_tile_the_fit_threads_time_and_carry_the_feeders():
     for record in records:
         assert record["steps"] == 2 and record["device_leaves"] == 2
         assert record["batch_build"] >= 0.004 and record["stack"] >= 0.001
-        assert record["transform_by_name"] == {"A": pytest.approx(record["transform"])}
+        assert set(record["transform_by_name"]) == {"A", "B"}
+        assert sum(record["transform_by_name"].values()) == pytest.approx(record["transform"])
+        assert record["transform_by_name"]["A"] >= 0.002
+        # two batches a chunk, one program each from A: summed per chunk
+        assert record["transform_device_programs"] == 2
         assert record["device_wait"] >= 0.05 and record["dispatch"] >= 0.002
     for record in (records[1], records[3]):
         assert record["account"] >= 0.005
