@@ -241,7 +241,7 @@ class PackedSequenceBatcher(SequenceBatcher):
         """Epoch-level packing stats: ``padding_fraction`` (fraction of the
         ``[B, L]`` token grid that is padding), ``rows`` (packed rows),
         ``segments_per_row`` and the unpacked baseline's padding fraction for
-        the same entries — the number the bench rows report."""
+        the same entries."""
         order = self._entry_order()
         entries = self._entries[order]
         lengths = np.minimum(entries[:, 2] - entries[:, 1], self.max_sequence_length)

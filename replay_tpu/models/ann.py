@@ -183,7 +183,7 @@ class MIPSIndex:
 
     def table_bytes(self) -> dict:
         """Logical payload bytes of the device catalog (unpadded rows): the
-        honesty number the quant bench rows report next to the f32 baseline.
+        honesty number to report next to the f32 baseline.
         IVF adds the machine-derived breakdown (centroid/cell/codebook/id
         bytes) priced by the same formula as the 100M projection."""
         f32_bytes = int(self.num_items * self.dim * 4)

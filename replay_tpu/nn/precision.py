@@ -170,7 +170,7 @@ class Precision:
         return cast_logits
 
     def describe(self) -> Dict[str, str]:
-        """Flat record for events / bench rows."""
+        """Flat record for events."""
         import jax.numpy as jnp
 
         return {

@@ -569,7 +569,7 @@ class Trainer:
         (save MXU outputs only) / ``"dots_no_batch"`` / a
         ``jax.checkpoint_policies`` callable. The model is cloned with
         ``remat=True`` and the policy plumbed into its ``nn.remat``-wrapped
-        blocks — the HBM-for-FLOPs trade the L=1024 bench rows A/B
+        blocks — the HBM-for-FLOPs trade
         (docs/performance.md "Remat: trading FLOPs for HBM").
     :param precision: mixed-precision rung (``"bf16"`` / ``"f32"`` /
         :class:`~replay_tpu.nn.Precision`): bf16 activations+compute with f32

@@ -53,8 +53,8 @@ evidence of a torn/truncated ring. Reopening an existing ring resumes after
 its highest valid seqno: a respawned process appends to the evidence, it
 never clobbers a dead predecessor's.
 
-Consumed by ``obs.report --postmortem`` (timeline reconstruction),
-``bench_fleet.py`` socket chaos (``flight_records_recovered``) and the
+Consumed by ``obs.report --postmortem`` (timeline reconstruction), the socket
+fleet chaos of ``tests/serve/test_remote.py`` and the
 ``launch_workers(run_dir=...)`` harvest. Beyond-parity — SURVEY.md §5;
 docs/observability.md "The black box and post-mortems".
 """

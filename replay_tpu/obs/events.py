@@ -67,8 +67,8 @@ and the fleet router (``serve/fleet.py``) one level above that::
 
 Every event flattens to one JSON-able dict (``event`` + ``time`` + optional
 ``step``/``epoch`` + the payload), so a run directory's ``events.jsonl`` is a
-self-describing artifact shared by training runs, ``bench.py`` /
-``bench_serve.py`` records and the CPU-mesh dry runs.
+self-describing artifact shared by training runs, serving runs and the
+CPU-mesh dry runs.
 """
 
 from __future__ import annotations

@@ -410,8 +410,7 @@ class MetricsLogger(RunLogger):
     and never on their own thread.
 
     Serve QPS is a sliding-window rate (default 10 s) over the rows each
-    dispatched batch answered — the live analog of ``bench_serve``'s
-    whole-run ``qps``.
+    dispatched batch answered.
     """
 
     def __init__(

@@ -1,9 +1,8 @@
 """Model-FLOPs-utilization: the XLA cost model and the peak-TFLOPs table.
 
 The single home of the peak dense-bf16 throughput table and the cost-model
-FLOPs extraction that ``bench.py`` and ``bench_suite.py`` previously each kept
-privately ("Demystifying BERT" argues MFU belongs in every run record, not in
-one-off bench scripts — PAPERS.md). Import-light on purpose (no jax at import).
+FLOPs extraction ("Demystifying BERT" argues MFU belongs in every run record,
+not in one-off scripts — PAPERS.md). Import-light on purpose (no jax at import).
 """
 
 from __future__ import annotations
@@ -107,22 +106,6 @@ def flops_per_step(jitted_fn: Any, *args, extra_flops: float = 0.0, **kwargs) ->
     if flops <= 0:
         return None
     return flops + float(extra_flops)
-
-
-def fused_ce_flops(rows: int, embed: int, num_items: int) -> float:
-    """Analytic FLOPs of one fused-CE head step (fwd + bwd) for ``rows``
-    hidden vectors against a ``num_items`` catalog.
-
-    The pallas kernels are opaque custom calls to the XLA cost model, so the
-    head's work must be added back via ``extra_flops`` or every fused-variant
-    MFU reads ~0 for exactly the rows where the head dominates: forward
-    ``2·N·E·I`` (the logits sweep), backward ``2 × 2·N·E·I`` (the dh and dW
-    kernels each rematerialize a logits block and do one matmul). The
-    TP-sharded head does the same TOTAL work spread over the mesh — pass the
-    global shapes and divide by nothing; ``mfu()`` already normalizes by
-    ``device_count``.
-    """
-    return 6.0 * float(rows) * float(embed) * float(num_items)
 
 
 def mfu(tflops_per_sec: float, device_kind: str, device_count: int = 1) -> Optional[float]:

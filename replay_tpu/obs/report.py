@@ -2,18 +2,18 @@
 
 ::
 
-    python -m replay_tpu.obs.report <run_dir | events.jsonl | BENCH.json>
+    python -m replay_tpu.obs.report <run_dir | events.jsonl | one-record JSON>
     python -m replay_tpu.obs.report runs/exp2 --compare runs/exp1 --threshold 0.1
 
-Turns the telemetry artifacts every trainer/bench/dry run leaves behind into
+Turns the telemetry artifacts every trainer fit / dry run leaves behind into
 the one-page answer "Demystifying BERT" (PAPERS.md) says a profile must
 become: throughput, MFU, the goodput breakdown (where wall-clock went between
 steps), per-program roofline records (obs.roofline: memory- vs
 compute-bound, predicted ceiling, HBM footprint, collective bytes), retraces,
 bad/recovered steps, the model-health record
 (obs.health: per-group norms/update ratios, activation stats, attention
-entropy, early warnings), and the serving summary (replay_tpu.serve /
-bench_serve.py: QPS, latency percentiles, batch fill, cache hit rate, plus
+entropy, early warnings), and the serving summary (replay_tpu.serve:
+QPS, latency percentiles, batch fill, cache hit rate, plus
 the resilience rates — shed / deadline-miss / error — with degraded-traffic
 counts by ladder rung and breaker state; gated on QPS drops, p99 growth, and
 lower-better ``serve_error_rate`` / ``serve_deadline_miss_rate`` rises —
@@ -26,7 +26,7 @@ per-host step time, the cross-host skew and the straggler index
 (max/median per-host step time). ``on_slo_violation`` events (obs.slo) are
 counted and gated lower-better. ``--compare`` diffs two runs —
 either run may be a run directory, a raw ``events.jsonl``, or a single-record
-bench JSON (one record as a ``bench*.py`` prints it) — and exits
+JSON file — and exits
 non-zero when the candidate regresses beyond ``--threshold`` (relative):
 throughput/MFU drops, new retraces, ``peak_memory_bytes`` growth beyond
 ``--memory-threshold``, ``compile_seconds`` growth beyond
@@ -42,6 +42,13 @@ Import-light by design (stdlib only): the CLI must run in seconds with no
 jax/device involvement, and a malformed artifact must fail loudly (non-zero
 exit) rather than render a partial report — CI uses that as the "our own
 artifacts still parse" check.
+
+The blocks below that render and gate bench rows, precision pairs and the
+serve quant / ann / overload / chaos / swap records read records that no
+program in this repository writes any more (the scripts that wrote them went in
+PR 29); they stay until their tests are folded into the sections that remain
+(ROADMAP D7a). The numbers this repository reports come from
+``benchmark/run.py`` (``PERF.md``, ``PERF_LEDGER.jsonl``), not from this CLI.
 """
 
 from __future__ import annotations
@@ -538,8 +545,8 @@ def summarize_events(
         summary["mfu"] = _finite(fit_end.get("mfu"))
         summary["fit_samples_per_sec"] = None
 
-    # bench_suite.py rows (one bench_row event each): the full measurement
-    # batch — surfaced per row so the catalog-scaling family reads as a table
+    # bench_row events (one each; no program writes them any more, ROADMAP
+    # D7a) — surfaced per row so a catalog-scaling family reads as a table
     summary["bench_rows"] = [
         {
             key: record.get(key)
@@ -656,8 +663,8 @@ def summarize_events(
             summary["processes"] = dict(record["processes"])
 
     # the serving summary (replay_tpu.serve): service-side totals from the
-    # on_serve_end event, load-side qps/latency percentiles from the
-    # bench_serve.py record — either alone still renders a section
+    # on_serve_end event, load-side qps/latency percentiles from a load
+    # generator's record — either alone still renders a section
     serve: Dict[str, Any] = {}
     if serve_ends:
         record = serve_ends[-1]
@@ -779,7 +786,7 @@ def summarize_events(
 
     # the quality plane (obs.quality): the last on_quality_window per role is
     # the run's final windowed telemetry; drift warnings sum their coalesced
-    # counts; the bench drift-phase record (bench_serve.py) carries the
+    # counts; a drift-phase record carries the
     # injected-shift evidence the drift_psi --compare gate is phase-matched on
     quality_windows = [e for e in events if e.get("event") == "on_quality_window"]
     drift_warning_events = [
@@ -849,7 +856,7 @@ def summarize_events(
     summary["quality"] = quality or None
 
     # the fleet summary (serve.fleet): router-level health/failover/hedge
-    # events plus the bench_fleet.py record — per-replica serve totals come
+    # events plus a fleet load record — per-replica serve totals come
     # from the merged per-replica event shards (each replica logs through
     # JsonlLogger(process_index=i), the PR-10 multi-host machinery reused
     # one level up)
@@ -1686,7 +1693,7 @@ def compare_runs(
     gate ``recall_at_candidates`` / ``topk_match_rate`` higher-better with an
     absolute 0.005 floor; serving ``ann`` blocks (the IVF rung) gate
     ``recall_at_100`` / ``topk_agreement`` the same way plus ``ann_qps``
-    higher-better on the relative threshold. Fleet runs (``bench_fleet.py``)
+    higher-better on the relative threshold. Fleet runs
     gate ``fleet_qps``
     higher-better always, and ``fleet_p99_ms`` / ``fleet_reroute_rate``
     lower-better only when the chaos phase matches on both sides (a kill's
@@ -2064,13 +2071,13 @@ def compare_runs(
                 _finite(cand_ann.get("ivf_qps")),
                 _finite(base_ann.get("ivf_qps")),
             )
-    # fleet gates (serve.fleet / bench_fleet.py): aggregate QPS is higher-
+    # fleet gates (serve.fleet): aggregate QPS is higher-
     # better; tail latency and the reroute rate are LOWER-better — but a
     # chaos run's p99 includes the failover gap and its reroutes are the
     # injected kill's whole point, so both gate only when the chaos phase
     # matches on both sides (the PR-9 phase-matching rule). Cache-hit
     # locality is surfaced — its gate is the candidate-alone acceptance
-    # check bench_fleet/CI applies, not a cross-run comparison.
+    # check, not a cross-run comparison.
     cand_fleet, base_fleet = candidate.get("fleet") or {}, baseline.get("fleet") or {}
     if cand_fleet or base_fleet:
         check(

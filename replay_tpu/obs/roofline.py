@@ -16,9 +16,9 @@ bytes), no execution required.
 Import-light like :mod:`.mfu` (jax only inside :func:`analyze_program`):
 drivers consult the peak tables before deciding whether jax may be imported.
 The bandwidth table mirrors :data:`.mfu.PEAK_BF16_TFLOPS`; on hosts without a
-table entry (CPU CI), ``REPLAY_TPU_ROOFLINE_ASSUME_KIND`` (or the existing
-``REPLAY_TPU_BENCH_ASSUME_KIND``) classifies against an assumed chip and the
-record carries ``peak_assumed`` so arithmetic can never read as measurement.
+table entry (CPU CI), ``REPLAY_TPU_ROOFLINE_ASSUME_KIND`` classifies against an
+assumed chip and the record carries ``peak_assumed`` so arithmetic can never
+read as measurement.
 """
 
 from __future__ import annotations
@@ -33,7 +33,6 @@ __all__ = [
     "analyze_costs",
     "analyze_program",
     "assumed_device_kind",
-    "bench_fields",
     "classify",
     "of_ceiling",
     "peak_bandwidth",
@@ -65,11 +64,8 @@ def peak_bandwidth(device_kind: str) -> Optional[float]:
 
 def assumed_device_kind() -> Optional[str]:
     """The chip kind CPU-smoke runs classify against (arithmetic, not
-    measurement): ``REPLAY_TPU_ROOFLINE_ASSUME_KIND``, falling back to the
-    bench suite's existing ``REPLAY_TPU_BENCH_ASSUME_KIND``."""
-    return os.environ.get("REPLAY_TPU_ROOFLINE_ASSUME_KIND") or os.environ.get(
-        "REPLAY_TPU_BENCH_ASSUME_KIND"
-    )
+    measurement): ``REPLAY_TPU_ROOFLINE_ASSUME_KIND``."""
+    return os.environ.get("REPLAY_TPU_ROOFLINE_ASSUME_KIND")
 
 
 def classify(
@@ -126,42 +122,6 @@ def classify(
     return record
 
 
-def bench_fields(
-    static_record: Optional[Mapping[str, Any]],
-    tflops_per_sec: Optional[float] = None,
-    device_count: int = 1,
-) -> Dict[str, Any]:
-    """The flat bench-record fields derived from an :func:`analyze_program`
-    record — ONE shaping of key names/rounding shared by ``bench.py`` and
-    every ``bench_suite.py`` row, so the two harnesses cannot drift:
-    ``hbm_peak_bytes``, ``collective_bytes``, ``roofline_bound``,
-    ``roofline_ceiling_tflops``, ``arithmetic_intensity``,
-    ``roofline_peak_assumed`` and — when the achieved rate is known —
-    ``of_roofline_ceiling`` (per chip, like the ceiling tables)."""
-    fields: Dict[str, Any] = {}
-    if static_record is None:
-        return fields
-    if static_record.get("hbm_peak_bytes") is not None:
-        fields["hbm_peak_bytes"] = static_record["hbm_peak_bytes"]
-    if static_record.get("collective_bytes") is not None:
-        fields["collective_bytes"] = static_record["collective_bytes"]
-    classification = static_record.get("roofline")
-    if classification:
-        fields["roofline_bound"] = classification["bound"]
-        fields["roofline_ceiling_tflops"] = round(classification["ceiling_tflops"], 3)
-        fields["arithmetic_intensity"] = round(classification["arithmetic_intensity"], 2)
-        if classification.get("peak_assumed"):
-            fields["roofline_peak_assumed"] = classification["peak_assumed"]
-        if tflops_per_sec is not None and classification.get("ceiling_tflops"):
-            fields["of_roofline_ceiling"] = round(
-                float(tflops_per_sec)
-                / max(int(device_count), 1)
-                / classification["ceiling_tflops"],
-                4,
-            )
-    return fields
-
-
 def of_ceiling(tflops_per_sec: Optional[float], record: Optional[Mapping[str, Any]]) -> Optional[float]:
     """Achieved ÷ roofline-predicted ceiling — the honest MFU for programs
     whose ceiling is the bandwidth roof, not the MXU peak."""
@@ -186,8 +146,8 @@ def analyze_program(
     collectives — one ``lower().compile()``, no execution.
 
     ``extra_flops`` / ``extra_bytes`` add work opaque to the XLA cost model
-    (pallas custom calls: the CEFused head's analytic FLOPs via
-    :func:`.mfu.fused_ce_flops`, and its ``rows×items`` logits traffic that
+    (pallas custom calls: the CEFused head's analytic FLOPs, fwd 2·N·E·I +
+    bwd 2 × 2·N·E·I, and its ``rows×items`` logits traffic that
     the kernel keeps OUT of HBM — pass the bytes it actually touches, i.e.
     the table + hidden sweeps). Returns None when the backend offers no
     analysis; partial records (memory without a roofline) degrade per-field.

@@ -11,7 +11,7 @@ no device execution, no profiler session:
   / all-to-all, ``-start`` async variants included) with its result shape,
   dtype, byte size and replica groups, plus a best-effort mesh-axis guess.
 * :func:`summarize_collectives` folds an inventory into the
-  ``{count, bytes, by_op}`` record carried by bench rows and dry runs.
+  ``{count, bytes, by_op}`` record carried by roofline records and dry runs.
 * :func:`sharding_report` renders every param leaf's ``PartitionSpec`` and
   flags *accidental full replication* — a table that was supposed to shard
   over the mesh (``expect_sharded``) but lowered replicated, the silent way a
@@ -179,7 +179,7 @@ def collective_bytes(inventory: Sequence[Mapping[str, Any]]) -> int:
 
 
 def summarize_collectives(inventory: Sequence[Mapping[str, Any]]) -> Dict[str, Any]:
-    """Fold an inventory into the record bench rows / dry runs carry:
+    """Fold an inventory into the record roofline records / dry runs carry:
     ``{"count", "bytes", "by_op": {op: {"count", "bytes"}}}``."""
     by_op: Dict[str, Dict[str, int]] = {}
     for entry in inventory:

@@ -32,10 +32,9 @@ ANN-index stack (SURVEY §2.8), built from this repo's own pieces:
   drain-and-swap rollout composing with the promotion path (docs/serving.md
   "The fleet").
 
-``bench_serve.py`` (repo root) drives it with closed/open-loop load — plus
-open-loop OVERLOAD and ``--chaos`` fault-injection modes — and emits the
-QPS/latency/fill/hit-rate/shed-rate record ``obs.report`` renders and gates
-on. See docs/serving.md.
+``tests/serve/`` drives it in process — concurrent clients, an open-loop burst
+above capacity, injected engine faults, hot swaps under load; the benchmark has
+no serving cell yet (``PERF.md`` §7). See docs/serving.md.
 
 Attach an :class:`~replay_tpu.obs.QualityMonitor` via ``ScoringService(
 quality=...)`` to watch the MODEL-quality plane of the same traffic (online

@@ -63,8 +63,8 @@ class QuantizedTable:
 
     @property
     def nbytes(self) -> int:
-        """Total payload bytes (int8 values + f32 scales) — the number the
-        bench rows compare against the f32 table's ``I × E × 4``."""
+        """Total payload bytes (int8 values + f32 scales) — the number to
+        compare against the f32 table's ``I × E × 4``."""
         return int(self.values.nbytes + self.scales.nbytes)
 
     def dequantize(self) -> np.ndarray:

@@ -36,9 +36,8 @@ a thread boundary, not a process one. This module graduates that seam:
   :attr:`address` re-reads the portfile, so a :class:`RemoteReplica` built
   over the process object follows the replica across restarts.
 
-Used by ``tests/serve/test_remote.py`` (socket fleet + SIGKILL chaos) and
-``bench_fleet.py``'s socket-chaos phase (docs/robustness.md "Elastic resume
-and hard-kill chaos").
+Used by ``tests/serve/test_remote.py`` (socket fleet + SIGKILL chaos;
+docs/robustness.md "Elastic resume and hard-kill chaos").
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """Where executables keep JAX's persistent compilation cache.
 
-Called from entry points only (``chip_smoke.py``, the ``bench*.py`` mains,
+Called from entry points only (``chip_smoke.py``, ``benchmark/run.py``,
 ``python -m replay_tpu.serve.remote``), never on ``import replay_tpu``.
 """
 

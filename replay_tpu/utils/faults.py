@@ -142,8 +142,8 @@ class KillAtStep:
     checkpoint + cursor sidecar design actually guarantees. By default the
     injector kills ITS OWN process (a worker wraps its own stream); ``pid``
     retargets it at another process, and :meth:`fire` sends the kill
-    immediately — the fleet chaos path (``bench_fleet.py``) uses it to SIGKILL
-    a replica server process mid-traffic:
+    immediately — the fleet chaos path (``tests/serve/test_remote.py``) uses it
+    to SIGKILL a replica server process mid-traffic:
 
     >>> # training worker: dies fetching global batch 4, no cleanup runs
     >>> # trainer.fit(lambda epoch: KillAtStep(4).wrap(batches(epoch)), ...)

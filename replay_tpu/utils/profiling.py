@@ -1,8 +1,8 @@
 """Profiling hooks (beyond-parity: the reference has none — SURVEY.md §5).
 
 ``trace(dir)`` wraps a region in a jax.profiler trace viewable in TensorBoard /
-xprof; ``StepTimer`` measures steady-state steps/sec + samples/sec the way
-bench.py does (block_until_ready fencing, warmup exclusion).
+xprof; ``StepTimer`` measures steady-state steps/sec + samples/sec with
+block_until_ready fencing and warmup exclusion.
 
 For per-step instantaneous rates, retrace counting and device-memory
 telemetry see :mod:`replay_tpu.obs` (``StepTelemetry`` generalizes this

@@ -215,7 +215,7 @@ def test_compare_against_bench_json(tmp_path):
     candidate = _write_fit_run(str(tmp_path / "cand"), samples_per_sec=700.0)
     bench = tmp_path / "BENCH.json"
     bench.write_text(json.dumps({
-        "metric": "sasrec_train_samples_per_sec_cpu_fallback", "value": 1000.0,
+        "metric": "sasrec_train_samples_per_sec", "value": 1000.0,
         "unit": "samples/sec", "vs_baseline": 0.18, "backend": "cpu",
     }))
     assert main([candidate, "--compare", str(bench)]) != 0
@@ -311,7 +311,7 @@ def test_h2d_overlap_surfaced_from_trace(tmp_path, capsys):
 
 
 # --------------------------------------------------------------------------- #
-# serving summaries (replay_tpu.serve / bench_serve.py)
+# serving summaries (replay_tpu.serve)
 # --------------------------------------------------------------------------- #
 def _write_serve_run(path, qps=250.0, p99_ms=4.5, fill=0.8, hit_rate=0.9,
                      with_bench_record=True):

@@ -60,7 +60,6 @@ def test_classify_memory_vs_compute_bound():
 @pytest.mark.core
 def test_classify_unknown_chip_uses_assumed_kind_and_flags_it(monkeypatch):
     monkeypatch.delenv("REPLAY_TPU_ROOFLINE_ASSUME_KIND", raising=False)
-    monkeypatch.delenv("REPLAY_TPU_BENCH_ASSUME_KIND", raising=False)
     assert classify(1e9, 1e9, "cpu") is None  # no peaks, no assumption -> None
     monkeypatch.setenv("REPLAY_TPU_ROOFLINE_ASSUME_KIND", "v5e")
     record = classify(1e9, 1e9, "cpu")

@@ -1,16 +1,15 @@
-"""Trainer.fit event emission + the bench driver's JSON-line contract.
+"""Trainer.fit event emission.
 
 The smoke test is the acceptance gate for the obs subsystem: two epochs of a
 tiny SASRec through ``fit`` with a ``JsonlLogger`` must produce the full event
-sequence with finite loss/throughput, exactly ONE train-step compile across
-both epochs (the static-shapes invariant, now observable), and ``bench.py``
-must still print its single JSON line with the additive observability fields.
+sequence with finite loss/throughput and exactly ONE train-step compile across
+both epochs (the static-shapes invariant, now observable). The scan-chunked,
+device-fed fit (the path every benchmark cell runs) must end on an
+``on_fit_end`` that carries the same observability fields.
 """
 
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -21,8 +20,6 @@ from replay_tpu.nn import OptimizerFactory, Trainer, make_mesh
 from replay_tpu.nn.loss import CE
 from replay_tpu.nn.sequential.sasrec import SasRec
 from replay_tpu.obs import JsonlLogger
-
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 NUM_ITEMS = 12
 SEQ_LEN = 8
@@ -114,6 +111,17 @@ def test_fit_event_stream_single_compile(tmp_path):
 PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
 
 
+class ListSink:
+    """A sink that conforms to ``RunLogger`` by structure only (no subclass)
+    and keeps the events it is handed."""
+
+    def __init__(self):
+        self.events = []
+
+    def log_event(self, event):
+        self.events.append(event)
+
+
 def _small_trainer(optimizer=None) -> Trainer:
     """The d=8 model of the tests below; the trainers of the default optimizer
     share their two programs, another optimizer keeps its own."""
@@ -158,16 +166,9 @@ def test_fit_accepts_duck_typed_single_sink():
     """RunLogger is a protocol: a structurally-conforming sink that does not
     subclass it must be treated as ONE sink, not iterated as a sequence."""
 
-    class Duck:
-        def __init__(self):
-            self.events = []
-
-        def log_event(self, event):
-            self.events.append(event)
-
     trainer = _small_trainer()
     rng = np.random.default_rng(3)
-    duck = Duck()
+    duck = ListSink()
     trainer.fit(lambda: iter([_make_batch(rng), _make_batch(rng)]), epochs=1, loggers=duck)
     names = [e.event for e in duck.events]
     assert names[0] == "on_fit_start" and names[-1] == "on_fit_end"
@@ -199,39 +200,30 @@ def test_fit_lr_schedule_events_report_applied_rate(tmp_path):
 
 
 @pytest.mark.jax
-@pytest.mark.smoke
-def test_bench_json_line_carries_obs_fields(tmp_path):
-    """bench.py (on the CPU, toy shapes) still prints exactly one JSON line;
-    metric/value/vs_baseline schema unchanged, obs fields additive."""
-    env = {
-        **os.environ,
-        "REPLAY_TPU_BENCH_BATCH": "8",
-        "REPLAY_TPU_BENCH_SEQ_LEN": "8",
-        "REPLAY_TPU_BENCH_NUM_ITEMS": "64",
-        "REPLAY_TPU_BENCH_EMBEDDING_DIM": "8",
-        "REPLAY_TPU_BENCH_NUM_BLOCKS": "1",
-        "REPLAY_TPU_BENCH_SCAN_K": "2",
-        "JAX_PLATFORMS": "cpu",
-    }
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True,
-        text=True,
-        timeout=240,
-        env=env,
-        cwd=REPO,
-        check=False,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    payload = [line for line in proc.stdout.splitlines() if line.strip()]
-    assert len(payload) == 1  # the driver contract: ONE JSON line on stdout
-    record = json.loads(payload[0])
-    assert record["metric"] == "sasrec_train_samples_per_sec_cpu_fallback"
-    assert record["value"] > 0 and record["unit"] == "samples/sec"
-    assert "vs_baseline" in record and "backend" in record
-    # additive observability fields
-    assert record["compile_seconds"] > 0
-    assert "peak_memory_bytes" in record  # null on CPU, bytes on TPU
-    assert record["shape_override"]["B"] == 8
-    # a CPU run names itself: it can never pass for a device number
-    assert record["platform"] == "cpu" and record["device_count"] >= 1
+def test_chunked_device_fed_fit_end_carries_obs_fields():
+    """The benchmark's fit path (``scan_chunk=``, ``device_feed=True``) through
+    the event stream: ONE scan program next to the per-step one, their compile
+    wall time attributed (> 0) in ``on_fit_end``, the device-memory fields
+    present (null on a backend without allocator stats, never absent) and a
+    finite steady-state rate after the warm-up chunk."""
+    trainer = _small_trainer(OptimizerFactory(name="sgd", learning_rate=1e-2))  # own programs
+    rng = np.random.default_rng(5)
+    batches = [_make_batch(rng) for _ in range(7)]  # K=2: three chunks + a per-step tail
+    sink = ListSink()
+    trainer.fit(batches, epochs=1, loggers=sink, scan_chunk=2, device_feed=True)
+
+    steps = [e for e in sink.events if e.event == "on_train_step"]
+    assert [e.step for e in steps] == list(range(1, 8))
+    assert all(np.isfinite(e.payload["loss"]) for e in steps)
+    fit_end = sink.events[-1]
+    assert fit_end.event == "on_fit_end"
+    compile_report = fit_end.payload["compile"]
+    assert compile_report["train_scan"]["traces"] == 1
+    assert compile_report["train_step"]["traces"] == 1
+    assert compile_report["train_scan"]["compile_seconds"] > 0
+    assert trainer.compile_tracker.total_compile_seconds > 0
+    assert "peak_memory_bytes" in fit_end.payload  # null on the CPU, bytes on a TPU
+    peak = fit_end.payload["peak_memory_bytes"]
+    assert peak is None or peak > 0
+    telemetry = fit_end.payload["telemetry"]
+    assert np.isfinite(telemetry["samples_per_sec"]) and telemetry["samples_per_sec"] > 0

@@ -180,7 +180,7 @@ def test_metrics_state_psums_across_devices():
 def test_fused_ce_composes_with_vocab_sharding():
     """CEFused (pallas head, interpret off-TPU) + shard_vocab on a (4, 2) mesh
     == plain CE data-parallel — the exact composition the large-catalog TPU
-    configs run (bench_suite sasrec_100k_fused)."""
+    configs run."""
     from replay_tpu.nn.loss import CEFused
 
     def losses_for(loss, model_parallel, shard_vocab):

@@ -17,7 +17,9 @@ The acceptance contract, machine-checked:
   breach back exactly once, end to end.
 """
 
+import contextlib
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -31,7 +33,6 @@ from replay_tpu.nn.sequential.sasrec import SasRec
 from replay_tpu.nn.vocabulary import resize_item_embeddings
 from replay_tpu.obs.slo import SLORule
 from replay_tpu.serve import FallbackScorer, PromotionController, ScoringService, make_window
-from replay_tpu.serve.errors import ServeError
 from replay_tpu.utils.faults import EngineErrorAt, wrap_method
 
 pytestmark = [pytest.mark.jax, pytest.mark.smoke]
@@ -65,7 +66,7 @@ def make_model(num_items=NUM_ITEMS):
         schema=schema, embedding_dim=DIM, num_blocks=1, max_sequence_length=SEQ_LEN
     )
     ids = np.zeros((2, SEQ_LEN), np.int32)
-    params = model.init(
+    params = jax.jit(model.init)(  # one program, not flax's ~80 eager ones
         jax.random.PRNGKey(0), {"item_id": ids}, np.ones((2, SEQ_LEN), bool)
     )["params"]
     return model, jax.tree.map(np.asarray, params)
@@ -76,24 +77,30 @@ def perturb(params, scale):
     return jax.tree.map(lambda x: (np.asarray(x) * scale).astype(x.dtype), params)
 
 
-def direct_scores(model, params, items, length_bucket, batch_bucket):
+def direct_scores(model, params, items, length_bucket, batch_bucket, programs=None):
     """The generation's own program: AOT forward_inference at the routed
-    (length, batch) bucket — what a response must reproduce bit-for-bit."""
+    (length, batch) bucket — what a response must reproduce bit-for-bit.
+    ``programs`` keeps the compiled program of each bucket for a caller whose
+    generations all have one shape (the parameters are an argument)."""
 
     def fwd(p, ids, mask):
         return model.apply(
             {"params": p}, {"item_id": ids}, mask, method=SasRec.forward_inference
         )
 
-    program = (
-        jax.jit(fwd)
-        .lower(
-            params,
-            jax.ShapeDtypeStruct((batch_bucket, length_bucket), jnp.int32),
-            jax.ShapeDtypeStruct((batch_bucket, length_bucket), jnp.bool_),
+    program = (programs or {}).get((length_bucket, batch_bucket))
+    if program is None:
+        program = (
+            jax.jit(fwd)
+            .lower(
+                params,
+                jax.ShapeDtypeStruct((batch_bucket, length_bucket), jnp.int32),
+                jax.ShapeDtypeStruct((batch_bucket, length_bucket), jnp.bool_),
+            )
+            .compile()
         )
-        .compile()
-    )
+        if programs is not None:
+            programs[(length_bucket, batch_bucket)] = program
     window, mask, _ = make_window(items, length_bucket)
     ids = np.stack([window] * batch_bucket)
     masks = np.stack([mask] * batch_bucket)
@@ -120,6 +127,41 @@ def lane_buckets(response):
     lane = response.lane.split("#", 1)[0]
     assert lane.startswith("encode:L=")
     return int(lane.split("=", 1)[1]), response.batch_bucket
+
+
+@contextlib.contextmanager
+def scoring_clients(service, histories):
+    """Closed-loop load for the block's duration: one thread a user scoring its
+    history back to back. Yields ``(responses, failures, more_traffic)``:
+    ``(user, response)`` pairs, the exceptions a request raised (a client stops
+    at its first), and a wait for ``count`` more answers so that real traffic
+    lands on both sides of whatever the block does next."""
+    responses, failures = [], []
+    stop = threading.Event()
+
+    def client(user):
+        while not stop.is_set():
+            try:
+                response = service.score(user, history=histories[user], timeout=30)
+            except Exception as exc:  # noqa: BLE001 — the tests assert none
+                failures.append(exc)
+                return
+            responses.append((user, response))
+
+    def more_traffic(count=6, timeout_s=10.0):
+        target, deadline = len(responses) + count, time.monotonic() + timeout_s
+        while len(responses) < target and time.monotonic() < deadline:
+            time.sleep(0.005)
+
+    threads = [threading.Thread(target=client, args=(user,)) for user in histories]
+    for thread in threads:
+        thread.start()
+    try:
+        yield responses, failures, more_traffic
+    finally:
+        stop.set()
+        for thread in threads:
+            thread.join()
 
 
 class TestHotSwap:
@@ -191,50 +233,19 @@ class TestHotSwap:
             f"user-{i}": [int(x) for x in np.random.default_rng(i).integers(1, NUM_ITEMS, 4)]
             for i in range(6)
         }
-        responses = []
-        responses_lock = threading.Lock()
-        stop = threading.Event()
-        failures = []
-
-        def client(user):
-            while not stop.is_set():
-                try:
-                    response = service.score(user, history=histories[user], timeout=30)
-                except ServeError as exc:  # pragma: no cover - would fail below
-                    failures.append(exc)
-                    return
-                with responses_lock:
-                    responses.append((user, response))
-
-        def answered_count():
-            with responses_lock:
-                return len(responses)
-
-        threads = [threading.Thread(target=client, args=(u,)) for u in histories]
-        for t in threads:
-            t.start()
-        import time as _time
-
-        for swap in range(1, 5):
-            # let real traffic land BETWEEN swaps so both sides of each swap
-            # are observed under load
-            target = answered_count() + 6
-            deadline = _time.monotonic() + 10.0
-            while answered_count() < target and _time.monotonic() < deadline:
-                _time.sleep(0.005)
-            candidate = perturb(params, 1.0 + 0.01 * swap)
-            generation = service.publish_candidate(candidate)
-            all_params[generation] = candidate
-            service.promote(generation)
-        stop.set()
-        for t in threads:
-            t.join()
+        with scoring_clients(service, histories) as (responses, failures, more_traffic):
+            for swap in range(1, 5):
+                more_traffic()  # both sides of each swap are observed under load
+                candidate = perturb(params, 1.0 + 0.01 * swap)
+                generation = service.publish_candidate(candidate)
+                all_params[generation] = candidate
+                service.promote(generation)
 
         assert not failures  # zero request errors across every swap
         assert len(responses) > 10
         seen_generations = {r.generation for _, r in responses}
         assert len(seen_generations) >= 2  # the swaps were observed mid-load
-        cache = {}
+        cache, programs = {}, {}
         for user, response in responses:
             assert response.generation in all_params
             key = (user, response.generation, lane_buckets(response))
@@ -244,8 +255,77 @@ class TestHotSwap:
                     all_params[response.generation],
                     histories[user],
                     *lane_buckets(response),
+                    programs=programs,
                 )
             np.testing.assert_array_equal(response.scores, cache[key])
+
+    def test_two_retrieval_swaps_under_load_recompile_nothing(self):
+        """Swap under load in RETRIEVAL mode, where every generation ships its
+        own MIPS pipeline (the index embeds that generation's item table): two
+        same-shape swaps while clients score back to back are pointer moves
+        (no publish recompiled), cost zero request errors, end on generation 2,
+        and every answer is ONE generation's encoder AND index — its top-k
+        reproduces that generation's logits."""
+        from replay_tpu.models import MIPSIndex
+        from replay_tpu.serve import CandidatePipeline
+
+        model, params = make_model()
+        top_k = 3
+
+        def pipeline_of(generation_params):
+            item_weights = np.asarray(
+                model.apply({"params": generation_params}, method=SasRec.get_item_weights)
+            )
+            return CandidatePipeline(MIPSIndex(item_weights), num_candidates=10, top_k=top_k)
+
+        logger = RecordingLogger()
+        service = ScoringService(
+            model, params,
+            length_buckets=(SEQ_LEN,),
+            batch_buckets=(1, 4),
+            max_wait_ms=10.0,
+            retrieval=pipeline_of(params),
+            logger=logger,
+        )
+        all_params = {0: params}
+        histories = {
+            f"user-{i}": [int(x) for x in np.random.default_rng(i).integers(1, NUM_ITEMS, 4)]
+            for i in range(4)
+        }
+        with service:
+            with pytest.raises(ValueError, match="CandidatePipeline"):
+                service.publish_candidate(perturb(params, 1.01))  # no index of its own
+            with scoring_clients(service, histories) as (responses, failures, more_traffic):
+                for swap in (1, 2):
+                    more_traffic()
+                    candidate = perturb(params, 1.0 + 0.05 * swap)
+                    generation = service.publish_candidate(
+                        candidate, label=f"swap-{swap}", pipeline=pipeline_of(candidate)
+                    )
+                    assert not service.store.generation(generation).recompiled
+                    all_params[generation] = candidate
+                    service.promote(generation)
+                more_traffic()
+            assert service.store.stable_generation == 2
+            assert service.score("after", history=[1, 2, 3], timeout=30).generation == 2
+
+        assert not failures, failures[:1]
+        assert [e.payload["recompiled"] for e in logger.named("on_publish")] == [False, False]
+        assert len(logger.named("on_swap")) == 2
+        assert len({response.generation for _, response in responses}) >= 2
+        logits, programs = {}, {}
+        for user, response in responses:
+            key = (user, response.generation, lane_buckets(response))
+            if key not in logits:
+                logits[key] = direct_scores(
+                    model, all_params[response.generation], histories[user],
+                    *lane_buckets(response), programs=programs,
+                ).astype(np.float64)
+            want_ids = np.argsort(-logits[key], kind="stable")[:top_k]
+            assert set(response.item_ids) == set(want_ids)
+            np.testing.assert_allclose(
+                np.sort(response.scores), np.sort(logits[key][want_ids]), rtol=1e-5
+            )
 
     def test_grown_catalog_publishes_recompiled_and_serves_new_items(
         self, service_setup
@@ -444,8 +524,8 @@ class TestChaosMidSwap:
             assert len(injector.injected_at) <= 3
             assert "cache_only" in outcomes or "fallback" in outcomes
             # faults cleared: the canary still promotes
-            deadline = __import__("time").monotonic() + 5.0
-            while __import__("time").monotonic() < deadline:
+            deadline = time.monotonic() + 5.0
+            while time.monotonic() < deadline:
                 response = service.score("chaos-user", new_items=[5], timeout=30)
                 if response.served_by == "primary":
                     break

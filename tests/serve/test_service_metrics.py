@@ -113,7 +113,7 @@ def test_shed_totals_reconcile_with_stats(model_and_params):
     finally:
         service.close()
     # close() flushed the throttled on_shed tail, so the registry counter
-    # reproduces the service total exactly — the serve_chaos CI contract
+    # reproduces the service total exactly
     registry = service.metrics_registry
     assert registry.value("replay_serve_shed_total") == stats["shed"]
     assert registry.value("replay_serve_shed_rate") == pytest.approx(

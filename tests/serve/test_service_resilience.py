@@ -242,6 +242,58 @@ class TestAdmissionControl:
         finally:
             service.close()
 
+    def test_open_loop_burst_above_capacity_is_bounded_and_fully_accounted(
+        self, model_and_params
+    ):
+        """Overload end to end, no fallback floor: arrivals at several times
+        what a stalled engine can serve, every request with a deadline. Every
+        submission is accounted for (answered, shed at admission, or dropped
+        expired at batch build), none hangs, and the latency of the ANSWERED
+        ones stays near the deadline: nothing can queue past it."""
+        deadline_ms, stall_s, submitted = 100.0, 0.02, 300
+        service = _service(model_and_params, max_queue_depth=32, max_wait_ms=1.0).start()
+        try:
+            assert service.fallback is None
+            # capacity: 4 rows a dispatch / 20 ms = 200 req/s; unbounded, the last
+            # of 300 arrivals would wait ~1.5 s
+            wrap_method(
+                service.engine, "encode",
+                LatencySpike(at_calls=range(100_000), duration_s=stall_s),
+            )
+            latencies = []  # of the answered; appended from the done-callbacks
+
+            def on_done(submitted_at):
+                def callback(future):
+                    if future.exception() is None:
+                        latencies.append(time.perf_counter() - submitted_at)
+
+                return callback
+
+            futures = []
+            for i in range(submitted):  # ~1 ms apart: ~5x capacity, open loop
+                future = service.submit(f"burst-{i}", history=HISTORY, deadline_ms=deadline_ms)
+                future.add_done_callback(on_done(time.perf_counter()))
+                futures.append(future)
+                time.sleep(0.0005)
+            # a hung future raises TimeoutError here
+            outcomes = [type(future.exception(timeout=30)) for future in futures]
+            answered = outcomes.count(type(None))
+            drain = time.perf_counter() + 5.0  # done-callbacks run after the waiters wake
+            while len(latencies) < answered and time.perf_counter() < drain:
+                time.sleep(0.005)
+            stats = service.stats()
+        finally:
+            service.close()
+        shed, expired = outcomes.count(RequestShed), outcomes.count(DeadlineExceeded)
+        assert answered + shed + expired == submitted, set(outcomes)  # no other error
+        assert answered > 0 and shed + expired > 0, (answered, shed, expired)
+        assert stats["shed"] == shed and stats["deadline_misses"] == expired
+        assert stats["circuit_refusals"] == 0 and stats["breaker"]["state"] == "closed"
+        # deadline + the dispatch in flight + scheduler slack, not the ~1.5 s of
+        # an unbounded queue
+        assert len(latencies) == answered
+        assert np.percentile(latencies, 99) <= deadline_ms / 1000.0 + stall_s + 0.75
+
 
 class TestDegradationLadder:
     def test_cache_only_is_bitwise_identical_to_the_pure_hit_path(
