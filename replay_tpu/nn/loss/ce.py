@@ -14,8 +14,48 @@ import jax.numpy as jnp
 from .base import LossBase, broadcast_negatives, mask_negative_logits, masked_mean
 
 
+@jax.custom_vjp
+def _softmax_nll(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
+    """Per-row ``-log_softmax(logits)[label]`` whose backward keeps no second ``[..., I]`` tensor.
+
+    JAX's rule for ``log_softmax`` keeps a tensor made inside it for the way back
+    (``exp(logits - max)`` as traced, ``logits - max - log_sum`` as the TPU compiler
+    stores it): a second tensor of the logits' shape that is one scalar a row away
+    from them. This saves the logits, the per-row log-sum-exp and the labels instead,
+    and forms ``g * (exp(logits - lse) - onehot(label))`` as one elementwise
+    expression for XLA to fuse into the two products that consume it.
+    """
+    return _softmax_nll_fwd(logits, labels)[0]
+
+
+def _softmax_nll_fwd(logits, labels):
+    # log_softmax + take_along_axis, in their own order of operations
+    row_max = jnp.max(logits, axis=-1, keepdims=True)
+    log_sum = jnp.log(jnp.sum(jnp.exp(logits - row_max), axis=-1, keepdims=True))
+    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)
+    nll = (log_sum - (picked - row_max))[..., 0]
+    return nll, (logits, row_max + log_sum, labels)
+
+
+def _softmax_nll_bwd(residuals, g):
+    logits, lse, labels = residuals
+    onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logits.dtype)
+    dlogits = g[..., None] * (jnp.exp(logits - lse) - onehot)
+    return dlogits, None
+
+
+_softmax_nll.defvjp(_softmax_nll_fwd, _softmax_nll_bwd)
+
+
 class CE(LossBase):
-    """Full-softmax cross-entropy over the whole item catalog."""
+    """Full-softmax cross-entropy over the whole item catalog.
+
+    For the way back the head saves the logits as ``get_logits`` produced them and
+    one log-sum-exp a row (:func:`_softmax_nll`), not the second tensor of the logits'
+    shape that ``log_softmax``'s own rule keeps: at 25,600 positions by 27,278 items
+    that is 2.8 GB of float32 written and held across the step for the sake of one
+    scalar a row.
+    """
 
     def __call__(
         self,
@@ -30,9 +70,8 @@ class CE(LossBase):
             msg = "Multi-positive labels are not supported by the CE loss"
             raise NotImplementedError(msg)
         logits = self.logits_callback(model_embeddings)  # [B, L, I]
-        log_probs = jax.nn.log_softmax(logits, axis=-1)
         labels = jnp.clip(positive_labels[..., 0], 0, logits.shape[-1] - 1)
-        nll = -jnp.take_along_axis(log_probs, labels[..., None], axis=-1)[..., 0]
+        nll = _softmax_nll(logits, labels)
         weights = self._label_weights(labels, nll.dtype)
         mask = target_padding_mask[..., 0].astype(nll.dtype) * weights
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)

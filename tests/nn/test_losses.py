@@ -1,3 +1,5 @@
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -146,3 +148,114 @@ def test_missing_callback_raises():
     loss = CE()
     with pytest.raises(AttributeError):
         _ = loss.logits_callback
+
+
+def plain_ce(hidden, table, labels, target_mask, weight=None):
+    """The head as it was before it saved (logits, lse): ``log_softmax`` + ``take_along_axis``."""
+    logits = jnp.einsum("...e,ie->...i", hidden, table)
+    log_probs = jax.nn.log_softmax(logits, axis=-1)
+    labels = jnp.clip(labels[..., 0], 0, logits.shape[-1] - 1)
+    nll = -jnp.take_along_axis(log_probs, labels[..., None], axis=-1)[..., 0]
+    mask = target_mask[..., 0].astype(nll.dtype)
+    if weight is not None:
+        mask = mask * weight[labels].astype(nll.dtype)
+    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def head_ce(loss):
+    """``loss`` as a function of hidden states and table, through its ``logits_callback``."""
+
+    def apply(hidden, table, labels, target_mask):
+        loss.logits_callback = lambda h: jnp.einsum("...e,ie->...i", h, table)
+        return loss(hidden, {}, labels, None, PAD, target_mask)
+
+    return apply
+
+
+CLASS_WEIGHTS = jnp.linspace(0.25, 3.0, I)
+HEAD_CASES = {
+    # the second row's first two positions are padding: fully masked rows of logits
+    "padded_rows": (POS, TGT),
+    # no valid position at all: the denominator's clamp decides the value
+    "all_padding": (POS, jnp.zeros_like(TGT)),
+    # labels outside the catalog (the padding id, a negative id) are clipped into it
+    "out_of_range": (POS.at[0, 0, 0].set(I + 5).at[1, 3, 0].set(-3), TGT),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HEAD_CASES))
+@pytest.mark.parametrize("hidden_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("weight", [None, CLASS_WEIGHTS], ids=["CE", "CEWeighted"])
+def test_ce_head_matches_log_softmax_form(weight, hidden_dtype, case):
+    """Value and both gradients of the head that saves (logits, lse) against the
+    ``log_softmax`` form, bf16 hidden states against the float32 table included."""
+    labels, target_mask = HEAD_CASES[case]
+    loss = CE() if weight is None else CEWeighted(weight)
+    hidden = EMB.astype(hidden_dtype)
+    got, got_grads = jax.value_and_grad(head_ce(loss), argnums=(0, 1))(hidden, ITEMS, labels, target_mask)
+    want, want_grads = jax.value_and_grad(partial(plain_ce, weight=weight), argnums=(0, 1))(
+        hidden, ITEMS, labels, target_mask
+    )
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6, atol=1e-7)
+    # a bf16 hidden gradient is the float32 one rounded: a last-bit difference before
+    # the cast may fall either side of a bf16 step
+    rtol = 1e-5 if hidden_dtype == jnp.float32 else 2.0**-7
+    for g, w in zip(got_grads, want_grads):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_allclose(
+            np.asarray(g, dtype=np.float32), np.asarray(w, dtype=np.float32), rtol=rtol, atol=1e-6
+        )
+    if case == "all_padding":
+        assert float(got) == 0.0 and not np.any(np.asarray(got_grads[1]))
+
+
+def logits_sized_residuals(fn, *args):
+    """What ``jax.vjp`` of ``fn`` holds for the way back, of the logits' shape."""
+    _, pullback = jax.vjp(fn, *args)
+    return [
+        leaf for leaf in jax.tree_util.tree_leaves(pullback)
+        if getattr(leaf, "shape", None) == (B, L, I) and jnp.issubdtype(leaf.dtype, jnp.floating)
+    ]
+
+
+def test_ce_head_saves_the_logits_and_no_second_tensor():
+    """The structural witness: for the way back the head holds ONE float array of
+    the logits' shape, the logits themselves; the test fails if the second tensor,
+    which ``log_softmax``'s own rule keeps, comes back."""
+    args = (EMB, ITEMS, POS, TGT)
+    logits = np.asarray(full_logits_callback(EMB))
+    for loss in (CE(), CEWeighted(CLASS_WEIGHTS)):
+        saved = logits_sized_residuals(head_ce(loss), *args)
+        assert len(saved) == 1, [leaf.shape for leaf in saved]
+        np.testing.assert_array_equal(np.asarray(saved[0]), logits)
+    # what the plain form holds there is a tensor made inside ``log_softmax`` (as
+    # traced, exp(logits - max)): one scalar a row away from the logits, at their size
+    plain = logits_sized_residuals(plain_ce, *args)
+    assert plain and not any(np.array_equal(np.asarray(leaf), logits) for leaf in plain)
+
+
+@pytest.mark.parametrize("weight", [None, CLASS_WEIGHTS], ids=["CE", "CEWeighted"])
+def test_ce_head_under_data_model_mesh(weight):
+    """The same loss and gradients inside ``jit`` on a data x model mesh with the
+    table's rows over ``model`` (``shard_vocab=True``) as on one device."""
+    from jax.sharding import NamedSharding
+
+    from replay_tpu.nn import make_mesh
+    from replay_tpu.parallel.sharding import ShardingRules
+
+    loss = CE() if weight is None else CEWeighted(weight)
+    step = jax.value_and_grad(head_ce(loss), argnums=(0, 1))
+    want, want_grads = step(EMB, ITEMS, POS, TGT)
+
+    mesh = make_mesh(jax.devices()[:4], model_parallel=2)
+    rules = ShardingRules.default(shard_vocab=True)
+    place = lambda x, *names: jax.device_put(x, NamedSharding(mesh, rules.spec(*names)))  # noqa: E731
+    hidden, table = place(EMB, "batch", "length", "embed"), place(ITEMS, "vocab", "embed")
+    assert "model" in str(table.sharding.spec) and "data" in str(hidden.sharding.spec)
+    got, got_grads = jax.jit(step)(
+        hidden, table, place(POS, "batch", "length", None), place(TGT, "batch", "length", None)
+    )
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-6)
