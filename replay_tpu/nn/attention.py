@@ -13,10 +13,15 @@ mask+softmax chain. Sequence-parallel ring attention reuses these shapes
 
 from __future__ import annotations
 
-from typing import Any
+import math
+from typing import Any, Mapping, Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
+import numpy as np
+
+
+TILED_BLOCK = 512  # query and key/value rows of one tile on the "tiled" route (PERF.md, PR 31)
 
 
 def dot_product_attention(
@@ -28,6 +33,7 @@ def dot_product_attention(
     padding_mask: jnp.ndarray = None,  # [B, L] bool, required for "tiled"/"ring"
     causal: bool = True,
     return_weights: bool = False,  # also return the [B, H, L, L] softmax weights
+    window: Optional[int] = None,  # "tiled" only: query i sees keys i - window < j <= i
 ) -> jnp.ndarray:
     if return_weights and use_flash:
         # the flash kernels never materialize the weights — that is the point
@@ -98,8 +104,12 @@ def dot_product_attention(
             msg = "use_flash='tiled' cannot honor an additive mask; pass mask=None"
             raise ValueError(msg)
         return flash_attention_tiled(
-            q, k, v, padding_mask_bias(padding_mask), causal, interpret=pallas_interpret()
+            q, k, v, padding_mask_bias(padding_mask), causal, TILED_BLOCK, TILED_BLOCK,
+            pallas_interpret(), window,
         ).astype(q.dtype)
+    if window is not None:
+        msg = "only use_flash='tiled' computes a band; the other routes take an additive mask"
+        raise ValueError(msg)
     if use_flash:
         # pallas fused kernel: no [B, H, L, L] HBM materialization
         from replay_tpu.ops.flash_attention import (
@@ -145,21 +155,75 @@ def _grouped_query_attention(q, k, v, mask, scale, return_weights: bool):
     return out
 
 
-def rotary_embedding(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def rotary_embedding(
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    theta: Optional[float] = None,
+    *,
+    inv_freq: Optional[jnp.ndarray] = None,  # [D/2] in place of theta's
+    attention_factor: float = 1.0,
+) -> jnp.ndarray:
     """Rotary positions on ``x`` [..., L, D]: the half-split ("rotate half")
-    form, pair i of (x[i], x[i + D/2]) turned by ``positions * theta**(-2i/D)``.
+    form, pair i of (x[i], x[i + D/2]) turned by ``positions * inv_freq[i]``,
+    ``inv_freq`` given or ``theta**(-2i/D)``; cos and sin are scaled by
+    ``attention_factor`` (YaRN's; on q and k alike, so scores by its square).
     Angles and the rotation are float32; the result takes ``x``'s dtype. Being
     relative, it needs no table and no maximum length."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [L, D/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
     x32 = x.astype(jnp.float32)
     first, second = x32[..., :half], x32[..., half:]
     rotated = jnp.concatenate(
         [first * cos - second * sin, second * cos + first * sin], axis=-1
     )
     return rotated.astype(x.dtype)
+
+
+def yarn_correction_range(head_dim, theta, original_max_position, beta_fast, beta_slow):
+    """(low, high): the pairs between which YaRN blends. ``dim(r)``, the pair
+    that turns ``r`` times over the original context, is ``D ln(L0 / (2 pi r)) /
+    (2 ln theta)``; low = floor(dim(beta_fast)), high = ceil(dim(beta_slow)),
+    clipped to the pairs there are."""
+    turns = lambda r: head_dim * math.log(original_max_position / (2 * math.pi * r)) / (  # noqa: E731
+        2 * math.log(theta)
+    )
+    return max(math.floor(turns(beta_fast)), 0), min(math.ceil(turns(beta_slow)), head_dim - 1)
+
+
+def yarn_inv_freq(head_dim, theta, factor, original_max_position, beta_fast=32.0, beta_slow=1.0):
+    """YaRN's frequencies [D/2] (arXiv 2309.00071, as ``transformers`` computes
+    them): pairs faster than ``low`` keep ``theta**(-2i/D)`` (extrapolation),
+    pairs slower than ``high`` are divided by ``factor`` (interpolation), and
+    those between blend linearly. float64 here, float32 out: a constant."""
+    low, high = yarn_correction_range(head_dim, theta, original_max_position, beta_fast, beta_slow)
+    pairs = np.arange(head_dim // 2, dtype=np.float64)
+    ramp = np.clip((pairs - low) / max(high - low, 1e-3), 0.0, 1.0)
+    plain = theta ** (-2.0 * pairs / head_dim)
+    return jnp.asarray(plain * ((1.0 - ramp) + ramp / factor), jnp.float32)
+
+
+def rotary_arguments(head_dim: int, theta: float, scaling: Optional[Mapping[str, Any]]):
+    """:func:`rotary_embedding`'s keyword arguments for one layer type's
+    ``rope_parameters``: ``None`` or ``rope_type: default`` is the one-theta form."""
+    if not scaling or scaling.get("rope_type", "default") == "default":
+        return {"theta": theta}
+    if scaling["rope_type"] != "yarn":
+        msg = f"unknown rope_type {scaling['rope_type']!r}; known: default, yarn"
+        raise ValueError(msg)
+    factor = scaling["factor"]
+    return {
+        "inv_freq": yarn_inv_freq(
+            head_dim, scaling.get("rope_theta", theta), factor,
+            scaling["original_max_position_embeddings"],
+            scaling.get("beta_fast", 32.0), scaling.get("beta_slow", 1.0),
+        ),
+        "attention_factor": float(scaling.get("attention_factor") or 0.1 * math.log(factor) + 1.0),
+    }
 
 
 class MultiHeadAttention(nn.Module):
@@ -236,9 +300,19 @@ class GroupedQueryAttention(nn.Module):
     projections; the attention layer of the layer-pattern block stack,
     replay_tpu.nn.blocks). Positions are the indices in the window: rotary
     scores depend on their differences only, so left padding shifts nothing.
+    ``rope_scaling``: the layer type's ``rope_parameters`` (``rope_type`` ``yarn``
+    with its factor, original length, betas and attention factor; ``None``: the
+    one-theta form).
 
-    Runs on the standard route of :func:`dot_product_attention` (additive
-    ``mask`` [B, 1, L, L]); the flash kernels take one head count.
+    Two routes. With an additive ``mask`` [B, 1, L, L]: the standard route of
+    :func:`dot_product_attention`. With ``mask=None`` and the [B, L]
+    ``padding_mask``: the fused, length-tiled route (replay_tpu.ops.flash_tiled),
+    causal, key/value heads at their own count, and with ``window`` the band
+    ``0 <= i - j < window`` whose outside blocks are skipped, never masked; the
+    route's block counts are sown into ``counters`` (``attention_blocks_visited``
+    [forward, backward]: kv-block products per row and query head;
+    ``attention_blocks_needed``: the visible pairs over one block's area, what
+    a route with no rounding would compute). Only that route has a band.
     """
 
     num_heads: int
@@ -247,9 +321,13 @@ class GroupedQueryAttention(nn.Module):
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     dtype: Any = jnp.float32
+    window: Optional[int] = None
+    rope_scaling: Optional[Mapping[str, Any]] = None
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    def __call__(
+        self, x: jnp.ndarray, mask: Optional[jnp.ndarray], padding_mask: Optional[jnp.ndarray] = None
+    ) -> jnp.ndarray:
         length = x.shape[-2]
 
         def heads_of(name, count):
@@ -261,11 +339,28 @@ class GroupedQueryAttention(nn.Module):
         v = heads_of("value", self.num_kv_heads)
         positions = jnp.arange(length)
         q, k, v = (t.swapaxes(-3, -2) for t in (q, k, v))  # [B, H, L, D]
-        q = rotary_embedding(q, positions, self.rope_theta)
-        k = rotary_embedding(k, positions, self.rope_theta)
-        out = dot_product_attention(q, k, v, mask)
+        rotary = rotary_arguments(self.head_dim, self.rope_theta, self.rope_scaling)
+        q = rotary_embedding(q, positions, **rotary)
+        k = rotary_embedding(k, positions, **rotary)
+        if mask is None:
+            out = dot_product_attention(
+                q, k, v, None, use_flash="tiled", padding_mask=padding_mask, window=self.window
+            )
+            self._sow_block_counts(length)
+        else:
+            out = dot_product_attention(q, k, v, mask, window=self.window)
         out = out.swapaxes(-3, -2).reshape(*x.shape[:-1], self.num_heads * self.head_dim)
         return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype, name="out")(out)
+
+    def _sow_block_counts(self, length: int) -> None:
+        from replay_tpu.ops.flash_tiled import block_counts
+
+        counted = block_counts(length, TILED_BLOCK, TILED_BLOCK, True, self.window)
+        visited = jnp.array([counted["visited"]] * 2, jnp.int32)  # one schedule, both directions
+        needed = jnp.float32(counted["needed"] / counted["block_area"])
+        latest = {"reduce_fn": lambda _, new: new, "init_fn": lambda: None}  # one value a step
+        self.sow("counters", "attention_blocks_visited", visited, **latest)
+        self.sow("counters", "attention_blocks_needed", needed, **latest)
 
 
 class RMSNorm(nn.Module):

@@ -2,12 +2,17 @@
 
 A block is (mixer kind, feed-forward kind) around a pre-norm residual stream:
 
-    h = x + mixer(rms(x))          mixer: "full_attention" | "conv"
+    h = x + mixer(rms(x))          mixer: "full_attention" | "sliding_attention" | "conv"
     y = h + ffn(rms(h))            ffn:   dense SwiGLU | sparse experts
 
 and a model is a list of mixer kinds (``layer_types``) with the number of
-leading layers whose feed-forward is dense (``num_dense_layers``); every later
-layer routes over sparse experts. No absolute position table (attention carries
+leading layers whose feed-forward is dense (``num_dense_layers``, which may be
+0); every later layer routes over sparse experts. ``sliding_attention`` is
+``full_attention`` over the last ``sliding_window`` keys only, always on the
+fused route (replay_tpu.ops.flash_tiled: blocks outside the band are skipped);
+full layers take that route too when ``fused_attention`` is set, and the
+standard one (an additive [B, 1, L, L] mask) otherwise. Rotary parameters
+arrive by layer type (``rope_scaling``: ``{layer type: rope_parameters}``). No absolute position table (attention carries
 rotary positions, the convolution needs none), no bias, RMSNorm throughout. A
 new mechanism is a new entry in :data:`MIXERS`, not a model file.
 
@@ -16,12 +21,13 @@ again after every block, so a mixer never reads them (``rms(0) = 0``; attention
 masks them as keys besides) and the expert layer leaves them out of its dispatch.
 
 Each layer kind runs under a ``jax.named_scope`` of its own (``attention``,
-``conv``, ``dense_ffn``, ``moe``) so that a device trace splits by kind.
+``window_attention``, ``conv``, ``dense_ffn``, ``moe``) so that a device trace
+splits by kind.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence
+from typing import Any, Mapping, Optional, Sequence
 
 import flax.linen as nn
 import jax
@@ -33,7 +39,13 @@ from replay_tpu.nn.ffn import SwiGLU
 from replay_tpu.nn.moe import SparseExperts
 from replay_tpu.parallel.sharding import shard_activation
 
-MIXERS = ("full_attention", "conv")
+MIXERS = ("full_attention", "sliding_attention", "conv")
+ATTENTION_SCOPES = {"full_attention": "attention", "sliding_attention": "window_attention"}
+
+
+def needs_mask(layer_types: Sequence[str], fused_attention: bool) -> bool:
+    """Whether some layer takes the additive [B, 1, L, L] mask (the standard route)."""
+    return not fused_attention and "full_attention" in layer_types
 
 
 class PatternBlock(nn.Module):
@@ -56,18 +68,28 @@ class PatternBlock(nn.Module):
     routed_scale: float
     norm_eps: float
     dtype: Any = jnp.float32
+    router: str = "sigmoid"
+    sliding_window: Optional[int] = None
+    fused_attention: bool = False
+    rope_scaling: Optional[Mapping[str, Any]] = None  # this layer type's rope_parameters
 
     @nn.compact
     def __call__(self, x, attention_mask, padding_mask):
         norm = lambda name: RMSNorm(self.norm_eps, dtype=self.dtype, name=name)  # noqa: E731
         h = norm("mixer_norm")(x)
-        if self.mixer == "full_attention":
-            with jax.named_scope("attention"):
+        if self.mixer in ATTENTION_SCOPES:
+            sliding = self.mixer == "sliding_attention"
+            if sliding and not self.sliding_window:
+                msg = "a sliding_attention layer needs sliding_window"
+                raise ValueError(msg)
+            with jax.named_scope(ATTENTION_SCOPES[self.mixer]):
                 h = GroupedQueryAttention(
                     num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
                     head_dim=self.head_dim, rope_theta=self.rope_theta,
-                    norm_eps=self.norm_eps, dtype=self.dtype, name="attention",
-                )(h, attention_mask)
+                    norm_eps=self.norm_eps, dtype=self.dtype,
+                    window=self.sliding_window if sliding else None,
+                    rope_scaling=self.rope_scaling, name="attention",
+                )(h, None if sliding or self.fused_attention else attention_mask, padding_mask)
         elif self.mixer == "conv":
             with jax.named_scope("conv"):
                 h = GatedShortConv(self.conv_kernel, dtype=self.dtype, name="conv")(h)
@@ -82,7 +104,7 @@ class PatternBlock(nn.Module):
                     num_experts=self.num_experts, experts_held=self.experts_held,
                     expert_offset=self.expert_offset, top_k=self.experts_per_token,
                     hidden_dim=self.expert_dim, scale=self.routed_scale,
-                    dtype=self.dtype, name="moe",
+                    dtype=self.dtype, router=self.router, name="moe",
                 )(h, token_mask=padding_mask)
         else:
             with jax.named_scope("dense_ffn"):
@@ -111,6 +133,10 @@ class LayerPatternEncoder(nn.Module):
     routed_scale: float = 1.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.float32
+    router: str = "sigmoid"
+    sliding_window: Optional[int] = None
+    fused_attention: bool = False
+    rope_scaling: Optional[Mapping[str, Any]] = None  # {layer type: rope_parameters}
 
     @nn.compact
     def __call__(self, x, attention_mask, padding_mask):
@@ -124,6 +150,8 @@ class LayerPatternEncoder(nn.Module):
                 expert_dim=self.expert_dim, num_experts=self.num_experts,
                 experts_held=held, expert_offset=self.expert_offset,
                 experts_per_token=self.experts_per_token, routed_scale=self.routed_scale,
-                norm_eps=self.norm_eps, dtype=self.dtype, name=f"layer_{i}",
+                norm_eps=self.norm_eps, dtype=self.dtype, router=self.router,
+                sliding_window=self.sliding_window, fused_attention=self.fused_attention,
+                rope_scaling=(self.rope_scaling or {}).get(mixer), name=f"layer_{i}",
             )(x, attention_mask, padding_mask)
         return x

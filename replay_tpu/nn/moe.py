@@ -10,7 +10,12 @@ computes the part of the result that its own experts give:
     w    = s[sel] / (sum s[sel] + 1e-6) * scale   weights from s, not s + b
     out  = sum_{e in sel, e held} w_e * W2_e (silu(W1_e x) * W3_e x)
 
-What the absent experts would add is left out (on one chip the layer runs
+``router="softmax"`` is the other published form (no bias, no scale):
+
+    p    = softmax(W_g x)                         float32, over all num_experts
+    sel  = top_k(p);   w = p[sel] / sum p[sel]
+
+Everything after the selection is one code path. What the absent experts would add is left out (on one chip the layer runs
 without its exchange; summing ``out`` over every share gives the whole layer).
 
 Static shapes, no dropped assignment: the ``T * k`` assignments are sorted by
@@ -37,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 GMM_TILING = (512, 1024, 1024)  # rows, contraction, columns of one kernel tile
+ROUTERS = ("sigmoid", "softmax")
 
 
 def grouped_matmul(
@@ -77,6 +83,13 @@ def route(scores: jnp.ndarray, bias: jnp.ndarray, top_k: int, scale: float = 1.0
     return selected, weights
 
 
+def route_softmax(logits: jnp.ndarray, top_k: int):
+    """(selected experts [T, k], their weights [T, k]) from router logits [T, E]:
+    a softmax over ALL experts, the ``top_k`` largest, renormalised to sum to 1."""
+    weights, selected = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return selected, weights / jnp.sum(weights, axis=-1, keepdims=True)
+
+
 def unserved(slot: jnp.ndarray, held_here: jnp.ndarray, row: jnp.ndarray, group_sizes: jnp.ndarray):
     """How many held assignments are fetched from a buffer row OUTSIDE the rows
     their own expert multiplies: ``slot`` [A] the local expert of each
@@ -92,9 +105,11 @@ def unserved(slot: jnp.ndarray, held_here: jnp.ndarray, row: jnp.ndarray, group_
 
 
 class SparseExperts(nn.Module):
-    """Sigmoid-routed SwiGLU experts, of which ``experts_held`` live here (see
-    the module docstring). ``token_mask`` [...] bool leaves tokens (padding)
-    out of the dispatch: they take no row and count in no expert's load."""
+    """Routed SwiGLU experts, of which ``experts_held`` live here (see the module
+    docstring); ``router``: ``"sigmoid"`` (scores + selection bias) or
+    ``"softmax"`` (top-k of the softmax, renormalised; no bias parameter).
+    ``token_mask`` [...] bool leaves tokens (padding) out of the dispatch: they
+    take no row and count in no expert's load."""
 
     num_experts: int
     experts_held: int
@@ -103,6 +118,7 @@ class SparseExperts(nn.Module):
     hidden_dim: int
     scale: float = 1.0
     dtype: Any = jnp.float32
+    router: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, token_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
@@ -112,6 +128,9 @@ class SparseExperts(nn.Module):
                 f"are not among the layer's {self.num_experts}"
             )
             raise ValueError(msg)
+        if self.router not in ROUTERS:
+            msg = f"unknown router {self.router!r}; known: {ROUTERS}"
+            raise ValueError(msg)
         dim, held, k = x.shape[-1], self.experts_held, self.top_k
         tokens = x.reshape(-1, dim)
         count = tokens.shape[0]
@@ -119,11 +138,12 @@ class SparseExperts(nn.Module):
         gate = self.param("gate", fan_in, (held, dim, self.hidden_dim))
         value = self.param("value", fan_in, (held, dim, self.hidden_dim))
         out_kernel = self.param("out", fan_in, (held, self.hidden_dim, dim))
-        # the bias steers the selection only; it is a buffer kept with the
-        # parameters (checkpoints carry it) and out of the gradient
-        bias = jax.lax.stop_gradient(
-            self.param("expert_bias", nn.initializers.zeros, (self.num_experts,))
-        )
+        if self.router == "sigmoid":
+            # the bias steers the selection only; it is a buffer kept with the
+            # parameters (checkpoints carry it) and out of the gradient
+            bias = jax.lax.stop_gradient(
+                self.param("expert_bias", nn.initializers.zeros, (self.num_experts,))
+            )
 
         with jax.named_scope("router"):
             # float32 for real: at the default precision the TPU would round
@@ -132,7 +152,10 @@ class SparseExperts(nn.Module):
                 self.num_experts, use_bias=False, dtype=jnp.float32,
                 precision=jax.lax.Precision.HIGHEST, name="router",
             )(tokens.astype(jnp.float32))
-            selected, weights = route(jax.nn.sigmoid(logits), bias, k, self.scale)
+            if self.router == "sigmoid":
+                selected, weights = route(jax.nn.sigmoid(logits), bias, k, self.scale)
+            else:
+                selected, weights = route_softmax(logits, k)
 
         with jax.named_scope("dispatch"):
             local = selected - self.expert_offset  # [T, k]

@@ -17,11 +17,20 @@ Source of the layer equations and of the default widths' names: the public
 ``lfm2_moe`` configuration (https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json).
 ``experts_held`` / ``expert_offset`` give this chip's share of each expert
 layer (replay_tpu.nn.moe); the defaults hold every expert.
+
+The same stack carries the window-and-full pattern of the public ``mellum``
+configuration (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/main/config.json):
+``layer_types`` of ``sliding_attention`` and ``full_attention`` with
+``sliding_window``, ``fused_attention`` (the full layers on the fused route
+too), ``rope_scaling`` by layer type (YaRN on the full layers), ``router="softmax"``,
+``num_dense_layers=0`` and ``tie_embeddings=False``: an output table of its
+own, ``logits = rms(x) . output_table^T``, which ``get_item_weights()`` returns,
+so the input table gets no gradient from the head.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence
+from typing import Any, Mapping, Optional, Sequence
 
 import flax.linen as nn
 import jax
@@ -29,7 +38,7 @@ import jax.numpy as jnp
 
 from replay_tpu.data.nn.schema import TensorMap, TensorSchema
 from replay_tpu.nn.attention import RMSNorm
-from replay_tpu.nn.blocks import LayerPatternEncoder
+from replay_tpu.nn.blocks import LayerPatternEncoder, needs_mask
 from replay_tpu.nn.embedding import SequenceEmbedding
 from replay_tpu.nn.head import EmbeddingTyingHead
 from replay_tpu.nn.mask import causal_attention_mask
@@ -37,8 +46,8 @@ from replay_tpu.parallel.sharding import shard_activation
 
 
 class HybridRec(nn.Module):
-    """The layer-pattern next-item model with an embedding-tying head (see the
-    module docstring). The item feature's ``embedding_dim`` in the schema is the
+    """The layer-pattern next-item model with an embedding-tying head, or with
+    an output table of its own (``tie_embeddings=False``; see the module docstring). The item feature's ``embedding_dim`` in the schema is the
     model width. ``experts_held`` / ``expert_offset``: the share of each expert
     layer that lives on this chip (``None``: every expert); what the expert
     layers count (``expert_load``, ``dropped_assignments``) rides the trainer's
@@ -63,6 +72,11 @@ class HybridRec(nn.Module):
     experts_per_token: int = 2
     routed_scale: float = 1.0
     norm_eps: float = 1e-5
+    router: str = "sigmoid"
+    sliding_window: Optional[int] = None
+    fused_attention: bool = False
+    rope_scaling: Optional[Mapping[str, Any]] = None  # {layer type: rope_parameters}
+    tie_embeddings: bool = True
     excluded_features: tuple = ()
     dtype: Any = jnp.float32
     embedding_init: Any = None
@@ -82,10 +96,18 @@ class HybridRec(nn.Module):
             num_experts=self.num_experts, experts_held=self.experts_held,
             expert_offset=self.expert_offset, experts_per_token=self.experts_per_token,
             routed_scale=self.routed_scale, norm_eps=self.norm_eps,
-            dtype=self.dtype, name="encoder",
+            dtype=self.dtype, router=self.router, sliding_window=self.sliding_window,
+            fused_attention=self.fused_attention, rope_scaling=self.rope_scaling,
+            name="encoder",
         )
         self.final_norm = RMSNorm(self.norm_eps, dtype=self.dtype, name="final_norm")
         self.head = EmbeddingTyingHead()
+        if not self.tie_embeddings:
+            items = self.schema[self.schema.item_id_feature_name].cardinality
+            init = self.embedding_init or nn.initializers.variance_scaling(
+                1.0, "fan_in", "normal", out_axis=0
+            )
+            self.output_table = self.param("output_table", init, (items, width))
 
     def __call__(self, feature_tensors: TensorMap, padding_mask: jnp.ndarray) -> jnp.ndarray:
         """Hidden states [B, L, E] (the training forward)."""
@@ -95,7 +117,10 @@ class HybridRec(nn.Module):
             x = x * padding_mask[..., None].astype(x.dtype)
             x = shard_activation(x, "batch", "length", "embed")
         with jax.named_scope("encoder"):
-            mask = causal_attention_mask(padding_mask, dtype=self.dtype)
+            # the fused route builds its mask in-kernel: nothing [L, L] unless a layer needs it
+            mask = None
+            if needs_mask(self.layer_types, self.fused_attention):
+                mask = causal_attention_mask(padding_mask, dtype=self.dtype)
             x = self.encoder(x, mask, padding_mask)
         with jax.named_scope("final_norm"):
             return shard_activation(self.final_norm(x), "batch", "length", "embed")
@@ -105,8 +130,11 @@ class HybridRec(nn.Module):
     ) -> jnp.ndarray:
         """Scores against the catalog, or against candidate ids ([K] or [B, ..., K])."""
         if candidates_to_score is None:
-            return self.head(hidden, self.embedder.get_item_weights())
-        embedded = self.embedder.get_item_weights(candidates_to_score)
+            return self.head(hidden, self.get_item_weights())
+        if self.tie_embeddings:
+            embedded = self.embedder.get_item_weights(candidates_to_score)
+        else:
+            embedded = jnp.take(self.output_table, candidates_to_score, axis=0)
         if candidates_to_score.ndim == 1:
             return self.head(hidden, embedded)
         return jnp.einsum("...e,...ke->...k", hidden, embedded)
@@ -126,4 +154,7 @@ class HybridRec(nn.Module):
         return self(feature_tensors, padding_mask)[:, -1, :]
 
     def get_item_weights(self) -> jnp.ndarray:
-        return self.embedder.get_item_weights()
+        """The table the head scores against: the item table, or the untied one."""
+        if self.tie_embeddings:
+            return self.embedder.get_item_weights()
+        return self.output_table  # float32 like the item table: the product promotes

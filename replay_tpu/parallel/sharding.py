@@ -210,6 +210,8 @@ _PARAM_RULES: Tuple[Tuple[Tuple[str, ...], str, Tuple[str, ...]], ...] = (
     # per-feature vocab tables (SequenceEmbedding's embedding_<feature> scope,
     # CategoricalEmbedding/CategoricalListEmbedding nn.Embed) — the TP tables
     (("embedding_", "table"), "embedding", ("vocab", "embed")),
+    # an output head that is not the item table (HybridRec, tie_embeddings=False)
+    ((), "output_table", ("vocab", "embed")),
     # positional tables: indexed by a python slice over max_sequence_length,
     # so their row axis is 'position', never the sequence-sharded 'length'
     ((), "positional_embedding", ("position", "embed")),

@@ -138,3 +138,73 @@ def test_the_expert_bias_is_a_buffer_the_optimizer_leaves_alone(model):
     after = state.params["encoder"]["layer_1"]["moe"]
     np.testing.assert_array_equal(np.asarray(after["expert_bias"]), np.asarray(bias))
     assert not np.allclose(after["gate"], params["encoder"]["layer_1"]["moe"]["gate"])
+
+
+# -- the window-and-full pattern: sliding + full attention on the fused route,
+#    YaRN by layer type, softmax router, no dense layer, an untied head
+
+WINDOWED = dict(
+    layer_types=("sliding_attention", "sliding_attention", "full_attention"), num_dense_layers=0,
+    num_heads=4, num_kv_heads=2, expert_dim=8, num_experts=8, experts_held=4, expert_offset=2,
+    experts_per_token=2, router="softmax", sliding_window=3, fused_attention=True,
+    tie_embeddings=False, rope_theta=100.0,
+    rope_scaling={"full_attention": {"rope_type": "yarn", "rope_theta": 100, "factor": 4,
+                                     "original_max_position_embeddings": 8, "beta_fast": 0.5,
+                                     "beta_slow": 0.05, "attention_factor": 1.14},
+                  "sliding_attention": {"rope_type": "default", "rope_theta": 100}},
+)
+
+
+def test_the_windowed_pattern_trains_through_fit_and_carries_both_kinds_of_counter(item_only_schema):
+    model = HybridRec(schema=item_only_schema, **WINDOWED)
+    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2), seed=3)
+    batches = train_batches()
+    for b in batches:  # items 12..19 never come in
+        ids = b["feature_tensors"]["item_id"]
+        b["feature_tensors"]["item_id"] = np.where(b["padding_mask"], ids % 12, 20).astype(np.int32)
+    before = len(chunk_stage_log())
+    first = trainer.init_state(batches[0])
+    tables = lambda state: (  # noqa: E731
+        np.asarray(state.params["embedder"]["embedding_item_id"]["table"]["embedding"]),
+        np.asarray(state.params["output_table"]),
+    )
+    table_before, head_before = tables(first)
+    losses = []
+
+    class Sink:
+        def log_event(self, event):
+            if event.event == "on_train_step":
+                losses.append(event.payload["loss"])
+
+    state = trainer.fit(batches, epochs=2, scan_chunk=2, state=first, loggers=Sink(), log_every=0)
+    assert int(state.step) == 8 and int(state.bad_steps) == 0 and losses[-1] < losses[0]
+    counted = chunk_stage_log()[before:][-1]["counters"]
+    assert np.asarray(counted["expert_load"]).shape == (2, 3, 4)  # every layer is sparse
+    assert np.asarray(counted["attention_blocks_visited"]).tolist() == [[[1, 1]] * 3] * 2
+    # one block holds the 8 positions: the band is 3*8 - 3 = 21 of its 64 pairs, the half square 36
+    np.testing.assert_allclose(counted["attention_blocks_needed"], [[21 / 64, 21 / 64, 36 / 64]] * 2)
+    params = state.params
+    assert "expert_bias" not in params["encoder"]["layer_0"]["moe"]
+    assert logical_axes_tree(params)["output_table"] == ("vocab", "embed")
+    # the head is its own table: items no batch brought in keep their input row, and lose their output row
+    table_after, head_after = tables(state)
+    np.testing.assert_array_equal(table_after[12:20], table_before[12:20])
+    assert (np.abs(table_after[:12] - table_before[:12]).sum(axis=1) > 0).all()
+    assert (np.abs(head_after - head_before).sum(axis=1) > 0).all()
+    scores = model.apply({"params": params}, jnp.ones((2, 16)), method=HybridRec.get_logits)
+    picked = model.apply({"params": params}, jnp.ones((2, 16)), jnp.array([1, 5]), method=HybridRec.get_logits)
+    np.testing.assert_allclose(picked, np.asarray(scores)[:, [1, 5]], rtol=2e-5)
+
+
+def test_a_pattern_needs_the_mask_only_for_a_full_layer_on_the_standard_route(item_only_schema):
+    from replay_tpu.nn.blocks import needs_mask
+
+    kinds = WINDOWED["layer_types"]
+    assert not needs_mask(kinds, True) and needs_mask(kinds, False) and not needs_mask(kinds[:2], False)
+    with pytest.raises(ValueError, match="sliding_window"):
+        model = HybridRec(schema=item_only_schema, **{**WINDOWED, "sliding_window": None})
+        ids = np.zeros((1, 8), np.int32)
+        model.init(KEY, {"item_id": ids}, np.ones((1, 8), bool))
+    with pytest.raises(ValueError, match="unknown router"):
+        model = HybridRec(schema=item_only_schema, **{**WINDOWED, "router": "argmax"})
+        model.init(KEY, {"item_id": np.zeros((1, 8), np.int32)}, np.ones((1, 8), bool))

@@ -204,3 +204,143 @@ def test_tiled_misuse_guards():
         dot_product_attention(q, q, q, jnp.zeros((1, 1, 4, 4)), use_flash="tiled",
                               padding_mask=jnp.ones((1, 4), bool))
 
+
+
+# ---------------------------------------------------------------------------
+# grouped-query heads and the band (0 <= i - j < window) on the same route
+
+
+def banded_reference(q, k, v, bias, window):
+    """Plain banded softmax with a materialised mask; key/value head h // group."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    length = q.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1]) + bias[:, None, None, :]
+    distance = np.arange(length)[:, None] - np.arange(length)[None, :]
+    seen = (distance >= 0) & (distance < (length if window is None else window))
+    s = jnp.where(seen[None, None], s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1)
+    probs = jnp.where(jnp.max(s, axis=-1, keepdims=True) <= NEG_INF / 2, 0.0, probs)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def banded_inputs(length, heads=4, kv_heads=2, dim=8, batch=2, padded=True, seed=3):
+    rng = np.random.default_rng(seed)
+    draw = lambda h: jnp.asarray(rng.normal(size=(batch, h, length, dim)).astype(np.float32))  # noqa: E731
+    # left padding, as the batcher makes it: a real query always sees itself
+    starts = rng.integers(1, length // 2, batch) if padded else np.zeros(batch, int)
+    mask = jnp.asarray(np.arange(length)[None, :] >= starts[:, None])
+    return draw(heads), draw(kv_heads), draw(kv_heads), mask
+
+
+# (length, block, window): smaller than / equal to / no multiple of the block,
+# window >= L (the causal route), L no multiple of the block, no window at all
+BANDS = [(32, 8, 3), (32, 8, 8), (32, 8, 13), (32, 8, 32), (32, 8, 100), (29, 8, 11),
+         (29, 8, None), (21, 16, 5)]
+
+
+@pytest.mark.parametrize("length,block,window", BANDS)
+def test_grouped_banded_route_matches_the_plain_banded_softmax(length, block, window):
+    q, k, v, mask = banded_inputs(length)
+    bias = padding_mask_bias(mask)
+
+    def fused(q, k, v, bias):
+        return flash_attention_tiled(q, k, v, bias, True, block, block, True, window)
+
+    keep = np.asarray(mask)[:, None, :, None]  # padded query rows are zeroed by the model
+    got = jax.jit(fused)(q, k, v, bias)
+    want = banded_reference(q, k, v, bias, window)
+    np.testing.assert_allclose(got * keep, want * keep, rtol=2e-5, atol=2e-5)
+
+    weight = jnp.asarray(np.random.default_rng(4).normal(size=q.shape).astype(np.float32)) * keep
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(fused(*a) * weight), argnums=(0, 1, 2, 3)))(q, k, v, bias)
+    wanted = jax.grad(lambda *a: jnp.sum(banded_reference(*a, window) * weight), argnums=(0, 1, 2, 3))(
+        q, k, v, bias
+    )
+    for g, w, name in zip(grads, wanted, "qkvb"):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_a_window_past_the_sequence_is_the_causal_route_bit_for_bit():
+    q, k, v, mask = banded_inputs(32, heads=2, kv_heads=2)
+    bias = padding_mask_bias(mask)
+    causal = flash_attention_tiled(q, k, v, bias, True, 8, 8, True)
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention_tiled(q, k, v, bias, True, 8, 8, True, 32)), np.asarray(causal)
+    )
+    with pytest.raises(ValueError, match="causal band"):
+        flash_attention_tiled(q, k, v, bias, False, 8, 8, True, 4)
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention_tiled(q, k[:, :1].repeat(3, 1), v[:, :1].repeat(3, 1), bias, True, 8, 8, True)
+
+
+@pytest.mark.parametrize("window", [8, 13, None], ids=lambda w: f"window{w}")
+def test_blocks_outside_the_band_are_never_multiplied(window):
+    """The witness a route that MASKS the band instead of skipping it fails: for
+    each query block, every key and value block the schedule leaves out is NaN. A
+    product with such a block poisons the output (0 * NaN) whatever mask follows;
+    the route's outputs and its query gradient stay what they were."""
+    from replay_tpu.ops.flash_tiled import kv_block_range
+
+    length, block = 48, 8
+    q, k, v, mask = banded_inputs(length, padded=False)
+    bias = padding_mask_bias(mask)
+
+    def fused(q, k, v):
+        return flash_attention_tiled(q, k, v, bias, True, block, block, True, window)
+
+    clean = fused(q, k, v)
+    clean_dq = jax.grad(lambda q: jnp.sum(fused(q, k, v) ** 2))(q)
+    first, last = kv_block_range(np.arange(length // block), block, block, length // block, True, window, np)
+    for i in (0, 2, 5):
+        outside = np.ones(length, bool)
+        outside[first[i] * block : (last[i] + 1) * block] = False
+        poison = lambda t: jnp.where(outside[None, None, :, None], jnp.nan, t)  # noqa: E731
+        rows = slice(i * block, (i + 1) * block)
+        out = fused(q, poison(k), poison(v))
+        np.testing.assert_array_equal(np.asarray(out[:, :, rows]), np.asarray(clean[:, :, rows]))
+        only = jnp.zeros_like(q).at[:, :, rows].set(1.0)  # the loss reads this query block alone
+        dq = jax.grad(lambda q: jnp.sum(fused(q, poison(k), poison(v)) ** 2 * only))(q)
+        np.testing.assert_allclose(dq[:, :, rows], clean_dq[:, :, rows], rtol=1e-5, atol=1e-6)
+    if window is not None:
+        assert (last - first + 1).max() <= -(-(window - 1) // block) + 1 < length // block
+
+
+@pytest.mark.parametrize(
+    "length,block,window,visited,needed",
+    [
+        (8192, 256, 1024, 150, 7_864_832),  # 1 + 2 + 3 + 4 + 28 x 5 blocks: 1.25 of the band
+        (8192, 512, 1024, 45, 7_864_832),   # 1 + 2 + 14 x 3: 1.5 of the band
+        (8192, 512, None, 136, 33_558_528),  # the causal half square
+        (8192, 256, 8192, 528, 33_558_528),
+        (50, 256, 7, 1, 7 * 50 - 21),       # one block holds the sequence
+    ],
+)
+def test_block_counts_of_the_schedule(length, block, window, visited, needed):
+    from replay_tpu.ops.flash_tiled import block_counts
+
+    counted = block_counts(length, block, block, True, window)
+    assert counted["visited"] == visited and counted["needed"] == needed
+    assert counted["block_area"] == min(block, length) ** 2
+    # brute force over every pair at a size where that is cheap
+    small = block_counts(61, 8, 8, True, 13)
+    distance = np.arange(61)[:, None] - np.arange(61)[None, :]
+    seen = (distance >= 0) & (distance < 13)
+    assert small["needed"] == seen.sum()
+    touched = {(i // 8, j // 8) for i, j in zip(*np.nonzero(seen))}
+    assert small["visited"] == len(touched)  # every block visited holds a visible pair, and no other
+
+
+@pytest.mark.parametrize("block_q,block_k", [(8, 8), (16, 8), (8, 16)])
+@pytest.mark.parametrize("window", [5, 16, 23, None])
+def test_both_backward_kernels_walk_the_forwards_pairs(block_q, block_k, window):
+    """dq lists the pairs by query block, dk/dv by kv block: the same set."""
+    from replay_tpu.ops.flash_tiled import kv_block_range, q_block_range
+
+    num_q, num_k = -(-61 // block_q), -(-61 // block_k)
+    first, last = kv_block_range(np.arange(num_q), block_q, block_k, num_k, True, window, np)
+    by_query = {(i, j) for i in range(num_q) for j in range(first[i], last[i] + 1)}
+    first, last = q_block_range(np.arange(num_k), block_q, block_k, num_q, True, window, np)
+    by_key = {(i, j) for j in range(num_k) for i in range(first[j], last[j] + 1)}
+    assert by_query == by_key and len(by_query) < num_q * num_k

@@ -103,6 +103,18 @@ def test_flash_tiled_lowers(one_chip, shape, grad):
     assert_mosaic(fwd_or_grad(flash_attention_tiled, grad, (0, 1, 2)), qkv, qkv, qkv, bias)
 
 
+# the fused route at Mellum2's published widths, one row of 8,192 positions: 32
+# query heads over 4 key/value heads of 128, the sliding layers' window and none
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("window", [1024, None], ids=["window1024", "full"])
+def test_grouped_banded_route_lowers_at_the_published_widths(one_chip, window, grad):
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), bf16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), bf16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((1, 8192), f32, sharding=one_chip)
+    route = partial(flash_attention_tiled, causal=True, block_q=512, block_k=512, window=window)
+    assert_mosaic(fwd_or_grad(lambda q, k, v, b: route(q, k, v, b), grad, (0, 1, 2)), q, kv, kv, bias)
+
+
 # (rows, contraction, columns): the expert layer's two grouped products at the
 # published widths (d 2048, expert width 1536), 8 x 1024 positions x 4 picks of rows
 GROUPED_SHAPES = [(32768, 2048, 1536), (32768, 1536, 2048)]
