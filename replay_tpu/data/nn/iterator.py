@@ -39,14 +39,36 @@ DEFAULT_TRAIN_PADDING_VALUE = -1
 Batch = Dict[str, np.ndarray]
 
 
-def _windows(length: int, max_len: int, stride: Optional[int]) -> List[Tuple[int, int]]:
-    """(start, stop) windows covering a sequence; the LAST window always ends at
-    the sequence end (recency matters for next-item training)."""
-    if length <= max_len:
-        return [(0, length)]
-    stride = stride or max_len
-    stops = list(range(max_len, length, stride)) + [length]
-    return [(stop - max_len, stop) for stop in stops]
+def _span_index(lengths: np.ndarray, max_len: int, stride: Optional[int]) -> np.ndarray:
+    """``[entries, 3]`` (row, start, stop) over rows of the given ``lengths``, rows
+    in order. With a ``stride``: the windows of ``max_len`` covering each row, the
+    LAST one always ending at the sequence end (recency matters for next-item
+    training). With none: one entry a row, its last ``max_len`` events."""
+    if stride is None:
+        row, stop = np.arange(len(lengths)), lengths
+    else:
+        counts = 1 + -(-np.maximum(lengths - max_len, 0) // stride)
+        row = np.repeat(np.arange(len(lengths)), counts)
+        ordinal = np.arange(len(row)) - np.repeat(np.cumsum(counts) - counts, counts)
+        stop = np.minimum(max_len + ordinal * stride, lengths[row])
+    start = np.maximum(stop - max_len, 0)
+    return np.stack([row, start, stop], axis=1).astype(np.int64)
+
+
+def _first_values(column: np.ndarray) -> np.ndarray:
+    """The first value of every row of a scalar feature's column, as ONE array:
+    typed where the values are numbers, boxed (``object``) otherwise, for
+    :func:`_take`."""
+    values = [np.asarray(value).reshape(-1)[0] for value in column]
+    typed = np.asarray(values)
+    return typed if typed.dtype.kind in "biuf" else np.asarray(values, dtype=object)
+
+
+def _take(column: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """``column[rows]``; of a boxed column (string ids) the dtype ``np.asarray``
+    infers from THOSE rows' values, which is what a list of them gave."""
+    taken = column[rows]
+    return np.asarray(taken.tolist()) if taken.dtype == object else taken
 
 
 @dataclass
@@ -55,6 +77,19 @@ class SequenceBatcher:
 
     The output feeds the transform pipelines (replay_tpu.nn.transform.template)
     unchanged — masks are emitted per feature under ``<name>_mask``.
+
+    Batch assembly touches no pandas object and runs no Python loop over rows:
+    everything a batch needs of a row is an array made ONCE at construction —
+    per sequence feature the flat values and row offsets
+    (``SequentialDataset.get_all_sequences``: one ``to_numpy()`` a column), the
+    ``(row, start, stop)`` index ``_entries`` of every window (from the item
+    sequences' lengths), the query ids and each scalar feature's first values.
+    A batch is index arithmetic on ``spans = _entries[chunk]`` and one native
+    gather a sequence feature. What falls back: a sequence feature whose dtype
+    the native gather does not take (neither integer nor floating) is
+    assembled row by row through ``SequentialDataset.get_sequence``, and the
+    ``batch_build`` stage reports it as ``python_rows`` (0 otherwise), summed a
+    chunk as ``batch_build_python_rows`` in the chunk stage log.
 
     :param windows: expand sequences longer than ``max_sequence_length`` into
         several windows (training); when False only the LAST ``max_sequence_length``
@@ -107,25 +142,22 @@ class SequenceBatcher:
         self._schema = self.dataset.schema
         self._seq_names = [f.name for f in self._schema.all_features if f.is_seq]
         self._scalar_names = [f.name for f in self._schema.all_features if not f.is_seq]
-        self._index: List[Tuple[int, int, int]] = []  # (row, start, stop)
-        for row in range(len(self.dataset)):
-            length = self.dataset.get_sequence_length(row)
-            spans = (
-                _windows(length, self.max_sequence_length, self.window_stride)
-                if self.windows
-                else [(max(0, length - self.max_sequence_length), length)]
-            )
-            self._index.extend((row, start, stop) for start, stop in spans)
-        self._entries = np.asarray(self._index, dtype=np.int64).reshape(-1, 3)
-        # flat+offsets layout per sequence feature feeds the native gather kernel
+        # everything a batch needs of a row is an array made ONCE, here: batch
+        # assembly touches no pandas object and runs no Python loop over rows.
+        # The flat+offsets layout per sequence feature feeds the native gather.
         self._flat: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
+        self._dtypes: Dict[str, type] = {}  # int32 or float32, by the FIRST row's dtype
         for name in self._seq_names:
             sequences = [
-                np.asarray(self.dataset.get_sequence(row, name)).reshape(-1)
-                for row in range(len(self.dataset))
+                np.asarray(sequence).reshape(-1)
+                for sequence in self.dataset.get_all_sequences(name)
             ]
-            lengths = np.array([len(s) for s in sequences], np.int64)
-            offsets = np.concatenate([[0], np.cumsum(lengths)])
+            first = sequences[0] if sequences else np.zeros(0)
+            self._dtypes[name] = (
+                np.int32 if np.issubdtype(first.dtype, np.integer) else np.float32
+            )
+            row_lengths = np.fromiter(map(len, sequences), np.int64, count=len(sequences))
+            offsets = np.concatenate([[0], np.cumsum(row_lengths)])
             flat = (
                 np.concatenate(sequences) if sequences else np.zeros(0, np.int64)
             )
@@ -136,6 +168,18 @@ class SequenceBatcher:
             else:
                 continue  # exotic dtype: the per-row python path handles it
             self._flat[name] = (flat, offsets)
+        # rows a batch assembles in the per-row python loop (the stage's counter)
+        self._python_rows = self.batch_size if len(self._flat) < len(self._seq_names) else 0
+        self._scalars = {
+            name: _first_values(self.dataset.get_all_sequences(name))
+            for name in self._scalar_names
+        }
+        self._query_ids = self.dataset.query_ids
+        # the item sequences define the windows, in the schema or not
+        item_sequences = self.dataset.get_all_sequences(self.dataset.item_id_column)
+        lengths = np.fromiter(map(len, item_sequences), np.int64, count=len(item_sequences))
+        stride = (self.window_stride or self.max_sequence_length) if self.windows else None
+        self._entries = _span_index(lengths, self.max_sequence_length, stride)
 
     def _buckets(self) -> List[int]:
         # boundaries above max_sequence_length would out-grow positional tables
@@ -155,7 +199,7 @@ class SequenceBatcher:
         from replay_tpu.data.batching import uniform_batch_count
 
         part = self.partitioning or Partitioning()
-        order = part.generate(len(self._index), self.epoch)
+        order = part.generate(len(self._entries), self.epoch)
         if not self.bucket_boundaries:
             return uniform_batch_count(len(order), self.batch_size)
         bucket_ids = self._bucket_ids(self._entries[order], self._buckets())
@@ -180,17 +224,13 @@ class SequenceBatcher:
         if self.shuffle and not part.shuffle:
             # honor shuffle=True even when an (unshuffled) partitioning was injected
             part = Partitioning(part.replicas, shuffle=True, seed=self.seed)
-        return part.generate(len(self._index), self.epoch)
+        return part.generate(len(self._entries), self.epoch)
 
     def _padding_value(self, name: str):
         return self._schema[name].padding_value
 
-    def _dtype(self, name: str):
-        sample = self.dataset.get_sequence(0, name) if len(self.dataset) else np.zeros(0)
-        return np.int32 if np.issubdtype(np.asarray(sample).dtype, np.integer) else np.float32
-
     def _make_batch(self, chunk: np.ndarray, L: int, dtypes: Dict) -> Batch:
-        with stage("batch_build", tracer=self.tracer):
+        with stage("batch_build", tracer=self.tracer, python_rows=self._python_rows):
             return self._assemble_batch(chunk, L, dtypes)
 
     def _assemble_batch(self, chunk: np.ndarray, L: int, dtypes: Dict) -> Batch:
@@ -220,34 +260,23 @@ class SequenceBatcher:
             else:
                 arr = np.full((self.batch_size, L), pad, dtype=dtypes[name])
                 mask = np.zeros((self.batch_size, L), dtype=bool)
-                for b, entry in enumerate(chunk):
-                    row, start, stop = self._index[entry]
+                for b, (row, start, stop) in enumerate(spans.tolist()):
                     seq = self.dataset.get_sequence(row, name)[start:stop]
                     seq = seq[-L:]
                     arr[b, L - len(seq) :] = seq
                     mask[b, L - len(seq) :] = True
                 batch[name] = arr
             batch[f"{name}_mask"] = np.asarray(mask, bool)
-        for name in self._scalar_names:
-            batch[name] = np.asarray(
-                [
-                    np.asarray(
-                        self.dataset.get_sequence(self._index[entry][0], name)
-                    ).reshape(-1)[0]
-                    for entry in chunk
-                ]
-            )
-        batch["query_id"] = np.asarray(
-            [self.dataset.get_query_id(self._index[entry][0]) for entry in chunk]
-        )
+        for name, column in self._scalars.items():
+            batch[name] = _take(column, spans[:, 0])
+        batch["query_id"] = _take(self._query_ids, spans[:, 0])
         valid = np.zeros(self.batch_size, dtype=bool)
         valid[:n_real] = True
         batch["valid"] = valid
         return batch
 
     def __iter__(self) -> Iterator[Batch]:
-        order = self._entry_order()
-        dtypes = {name: self._dtype(name) for name in self._seq_names}
+        order, dtypes = self._entry_order(), self._dtypes
         if not self.bucket_boundaries:
             L = self.max_sequence_length
             for chunk_start in range(0, len(order), self.batch_size):

@@ -177,7 +177,7 @@ class PackedSequenceBatcher(SequenceBatcher):
             offset = 0
             row_slots = []
             for seg, entry in enumerate(members, start=1):
-                row, start, stop = self._index[entry]
+                row, start, stop = self._entries[entry].tolist()
                 raw_len = stop - start
                 take = min(raw_len, L)
                 # recency truncation like the unpacked batcher: keep the LAST
@@ -227,12 +227,12 @@ class PackedSequenceBatcher(SequenceBatcher):
         return batch
 
     def __iter__(self) -> Iterator[Batch]:  # type: ignore[override]
-        order = self._entry_order()
-        dtypes = {name: self._dtype(name) for name in self._seq_names}
+        order, dtypes = self._entry_order(), self._dtypes
         rows = self._packed_rows(order)
         for start in range(0, len(rows), self.batch_size):
             chunk = rows[start : start + self.batch_size]
-            with stage("batch_build", tracer=self.tracer):
+            # every packed row is assembled in python loops, and says so
+            with stage("batch_build", tracer=self.tracer, python_rows=len(chunk)):
                 batch = self._assemble_packed(chunk, dtypes)
             yield batch
 

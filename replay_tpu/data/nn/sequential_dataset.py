@@ -59,6 +59,13 @@ class SequentialDataset:
         (ref data/nn/sequential_dataset.py)."""
         return self.query_ids
 
+    def get_all_sequences(self, feature_name: str) -> np.ndarray:
+        """One feature's column as ONE array, a query's value per entry (an
+        object array where the values are sequences): for a consumer that walks
+        every row (the batcher's flat layout), where :meth:`get_sequence` is a
+        pandas lookup a row."""
+        return self._sequences[feature_name].to_numpy()
+
     def get_query_id(self, index: int):
         return self._sequences[self._query_id_column].iloc[index]
 

@@ -453,6 +453,8 @@ CHUNK_STAGES = {
     "feeder": ("batch_build", "transform", "stack", "h2d", "feed_full"),
 }
 _FEEDER_FIELDS = CHUNK_STAGES["feeder"]
+# span arguments that count: a stage's totals sum each under ``<stage>_<argument>``
+_COUNTED_ARGS = ("device_programs", "python_rows")
 
 # one record per scan chunk, appended by the fit thread when the chunk's
 # metrics are on the host: 4096 chunks is a quarter of an hour of SASRec at
@@ -513,9 +515,10 @@ class stage:  # noqa: N801 - used as ``with stage(name):``, like Tracer.span
     After exit :attr:`seconds` is the duration and :attr:`end` the
     ``perf_counter`` reading it closed at. A span whose args hold a key of its
     own name (``stage("transform", transform="Mask")``) is also totalled by
-    that value under ``<name>_by_name``, and one that counts the compiled
-    programs it dispatched (``device_programs=n``) is summed under
-    ``<name>_device_programs``.
+    that value under ``<name>_by_name``, and one that counts (the compiled
+    programs it dispatched, ``device_programs=n``; the rows a batch assembled in
+    the per-row python loop, ``python_rows=n``) is summed under
+    ``<name>_device_programs`` / ``<name>_python_rows``.
     """
 
     __slots__ = ("name", "args", "seconds", "end", "span", "_tracer", "_annotation", "_start")
@@ -569,10 +572,11 @@ class stage:  # noqa: N801 - used as ``with stage(name):``, like Tracer.span
         if key is not None:
             by_name = totals.setdefault(name + "_by_name", {})
             by_name[key] = by_name.get(key, 0.0) + seconds
-        programs = self.args.get("device_programs")
-        if programs is not None:
-            counted = name + "_device_programs"
-            totals[counted] = totals.get(counted, 0) + programs
+        for counter in _COUNTED_ARGS:
+            count = self.args.get(counter)
+            if count is not None:
+                counted = f"{name}_{counter}"
+                totals[counted] = totals.get(counted, 0) + count
 
 
 def claim_chunk(chunk: int) -> Dict[str, Any]:
@@ -615,6 +619,9 @@ def chunk_stage_log() -> List[Dict[str, Any]]:
     ``batch_build``, ``transform`` (total) with ``transform_by_name``,
     ``transform_device_programs`` (compiled programs ``Compose`` dispatched
     for the chunk's batches: 0 while the pipeline stays on the host),
+    ``batch_build_python_rows`` (rows of the chunk's batches that the batcher
+    assembled in its per-row python loop: 0 while the native gather takes
+    every sequence feature),
     ``device_leaves`` (leaves of the chunk's batches that arrived as jax
     Arrays: each is a D2H read inside ``stack``), ``h2d_bytes`` and, for a
     model that counts (``sows_counters``), ``counters``: per name the chunk's
@@ -720,6 +727,7 @@ class ChunkStages:
             record[name] = float(feeder.get(name, 0.0))
         record["transform_by_name"] = dict(feeder.get("transform_by_name", ()))
         record["transform_device_programs"] = int(feeder.get("transform_device_programs", 0))
+        record["batch_build_python_rows"] = int(feeder.get("batch_build_python_rows", 0))
         record["device_leaves"] = int(feeder.get("device_leaves", 0))
         record["h2d_bytes"] = int(feeder.get("h2d_bytes", 0))
         if counters:

@@ -753,7 +753,7 @@ def test_chunk_stages_tile_the_fit_threads_time_and_carry_the_feeders():
     def feeder():
         for chunk in range(4):
             for _ in range(2):
-                with stage("batch_build"):
+                with stage("batch_build", python_rows=3):
                     time.sleep(0.002)
                 with stage("transform", transform="A", device_programs=1):
                     time.sleep(0.001)
@@ -794,6 +794,8 @@ def test_chunk_stages_tile_the_fit_threads_time_and_carry_the_feeders():
         assert record["transform_by_name"]["A"] >= 0.002
         # two batches a chunk, one program each from A: summed per chunk
         assert record["transform_device_programs"] == 2
+        # and three rows a batch from the batcher's python loop
+        assert record["batch_build_python_rows"] == 6
         assert record["device_wait"] >= 0.05 and record["dispatch"] >= 0.002
     for record in (records[1], records[3]):
         assert record["account"] >= 0.005
