@@ -312,7 +312,8 @@ class GroupedQueryAttention(nn.Module):
     route's block counts are sown into ``counters`` (``attention_blocks_visited``
     [forward, backward]: kv-block products per row and query head;
     ``attention_blocks_needed``: the visible pairs over one block's area, what
-    a route with no rounding would compute). Only that route has a band.
+    a route with no rounding would compute: :func:`sow_block_counts`). Only that
+    route has a band.
     """
 
     num_heads: int
@@ -346,21 +347,85 @@ class GroupedQueryAttention(nn.Module):
             out = dot_product_attention(
                 q, k, v, None, use_flash="tiled", padding_mask=padding_mask, window=self.window
             )
-            self._sow_block_counts(length)
+            sow_block_counts(self, length, self.window)
         else:
             out = dot_product_attention(q, k, v, mask, window=self.window)
         out = out.swapaxes(-3, -2).reshape(*x.shape[:-1], self.num_heads * self.head_dim)
         return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype, name="out")(out)
 
-    def _sow_block_counts(self, length: int) -> None:
-        from replay_tpu.ops.flash_tiled import block_counts
 
-        counted = block_counts(length, TILED_BLOCK, TILED_BLOCK, True, self.window)
-        visited = jnp.array([counted["visited"]] * 2, jnp.int32)  # one schedule, both directions
-        needed = jnp.float32(counted["needed"] / counted["block_area"])
-        latest = {"reduce_fn": lambda _, new: new, "init_fn": lambda: None}  # one value a step
-        self.sow("counters", "attention_blocks_visited", visited, **latest)
-        self.sow("counters", "attention_blocks_needed", needed, **latest)
+def sow_block_counts(module: nn.Module, length: int, window: Optional[int] = None) -> None:
+    """The fused route's schedule at this length into ``module``'s ``counters``
+    (what :class:`GroupedQueryAttention`'s docstring names)."""
+    from replay_tpu.ops.flash_tiled import block_counts
+
+    counted = block_counts(length, TILED_BLOCK, TILED_BLOCK, True, window)
+    visited = jnp.array([counted["visited"]] * 2, jnp.int32)  # one schedule, both directions
+    needed = jnp.float32(counted["needed"] / counted["block_area"])
+    latest = {"reduce_fn": lambda _, new: new, "init_fn": lambda: None}  # one value a step
+    module.sow("counters", "attention_blocks_visited", visited, **latest)
+    module.sow("counters", "attention_blocks_needed", needed, **latest)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA) with a decoupled rotary key, training
+    side: keys and values are made from ONE ``latent_dim``-wide vector a
+    position, and position enters through a ``rope_head_dim``-wide part of q and
+    k that ALL heads share on the key side (source of the equations: the public
+    ``deepseek_v3`` configuration of
+    https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/config.json,
+    ``q_lora_rank`` null). Per position, H heads, every projection bias-free:
+
+        q       = W_q x               -> [H, nope + rope], split q_nope | q_rope
+        a       = W_kva x             -> [latent + rope]
+        c       = rms(a[:latent])     a learned scale;  k_rope = a[latent:], ONE head
+        c W_kvb                       -> [H, nope + value], split k_nope | v
+        q_rope, k_rope <- rotary (half-split pairing; no other dim turns)
+        k       = [k_nope | k_rope for every head]
+        o       = softmax_causal(q k^T / sqrt(nope + rope)) v      -> [H, value]
+        y       = W_o o
+
+    No per-head norm on q or k. Always on the fused, length-tiled route
+    (replay_tpu.ops.flash_tiled: float32 softmax statistics, causal, padding by
+    ``padding_mask``), whose kernels carry the value width (``value_head_dim``)
+    apart from the query/key width (``nope_head_dim + rope_head_dim``); the
+    rotary key is broadcast over the heads before the call (PERF.md, PR 33). The
+    route's block counts are sown as :class:`GroupedQueryAttention` sows them.
+    The latent per-user cache and the absorbed decode path are not here.
+    """
+
+    num_heads: int
+    latent_dim: int
+    nope_head_dim: int
+    rope_head_dim: int
+    value_head_dim: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, padding_mask: jnp.ndarray) -> jnp.ndarray:
+        length, heads = x.shape[-2], self.num_heads
+        nope, rope, value = self.nope_head_dim, self.rope_head_dim, self.value_head_dim
+        dense = lambda width, name: nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)  # noqa: E731
+
+        q = dense(heads * (nope + rope), "query")(x).reshape(*x.shape[:-1], heads, nope + rope)
+        down = dense(self.latent_dim + rope, "kv_down")(x)
+        latent = RMSNorm(self.norm_eps, dtype=self.dtype, name="kv_norm")(down[..., : self.latent_dim])
+        up = dense(heads * (nope + value), "kv_up")(latent).reshape(*x.shape[:-1], heads, nope + value)
+        q, up = q.swapaxes(-3, -2), up.swapaxes(-3, -2)  # [B, H, L, .]
+        positions = jnp.arange(length)
+        q_rope = rotary_embedding(q[..., nope:], positions, self.rope_theta)
+        k_rope = rotary_embedding(down[..., None, :, self.latent_dim :], positions, self.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k_rope = jnp.broadcast_to(k_rope, (*k_rope.shape[:-3], heads, length, rope))
+        k = jnp.concatenate([up[..., :nope], k_rope], axis=-1)
+        out = dot_product_attention(
+            q, k, up[..., nope:], None, use_flash="tiled", padding_mask=padding_mask
+        )
+        sow_block_counts(self, length)
+        out = out.swapaxes(-3, -2).reshape(*x.shape[:-1], heads * value)
+        return dense(x.shape[-1], "out")(out)
 
 
 class RMSNorm(nn.Module):

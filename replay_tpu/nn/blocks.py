@@ -3,7 +3,8 @@
 A block is (mixer kind, feed-forward kind) around a pre-norm residual stream:
 
     h = x + mixer(rms(x))          mixer: "full_attention" | "sliding_attention" | "conv"
-    y = h + ffn(rms(h))            ffn:   dense SwiGLU | sparse experts
+                                          | "latent_attention"
+    y = h + ffn(rms(h))            ffn:   dense SwiGLU | sparse experts [+ shared expert]
 
 and a model is a list of mixer kinds (``layer_types``) with the number of
 leading layers whose feed-forward is dense (``num_dense_layers``, which may be
@@ -16,13 +17,24 @@ arrive by layer type (``rope_scaling``: ``{layer type: rope_parameters}``). No a
 rotary positions, the convolution needs none), no bias, RMSNorm throughout. A
 new mechanism is a new entry in :data:`MIXERS`, not a model file.
 
+``latent_attention`` (replay_tpu.nn.attention.LatentAttention; the third public
+configuration, ``deepseek_v3`` as
+https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/config.json
+publishes it): keys and values from one ``kv_latent_dim``-wide vector a
+position, a ``rope_head_dim``-wide rotary part beside the ``head_dim``-wide
+rest of q and k, values ``value_head_dim`` wide; always on the fused route.
+``shared_expert_dim`` > 0 adds to every sparse layer a dense SwiGLU of that
+width that every token passes (``routed_share(h) + SwiGLU(h)``; computed whole
+on every chip that holds a share of the routed experts), and the layer counts
+the positions it multiplied (``shared_expert_tokens`` in ``counters``).
+
 Padding: the stream is zero at padding positions on entry and is zeroed there
 again after every block, so a mixer never reads them (``rms(0) = 0``; attention
 masks them as keys besides) and the expert layer leaves them out of its dispatch.
 
 Each layer kind runs under a ``jax.named_scope`` of its own (``attention``,
-``window_attention``, ``conv``, ``dense_ffn``, ``moe``) so that a device trace
-splits by kind.
+``window_attention``, ``latent_attention``, ``conv``, ``dense_ffn``, ``moe`` and,
+its sibling, ``shared_expert``) so that a device trace splits by kind.
 """
 
 from __future__ import annotations
@@ -33,13 +45,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from replay_tpu.nn.attention import GroupedQueryAttention, RMSNorm
+from replay_tpu.nn.attention import GroupedQueryAttention, LatentAttention, RMSNorm
 from replay_tpu.nn.conv import GatedShortConv
 from replay_tpu.nn.ffn import SwiGLU
 from replay_tpu.nn.moe import SparseExperts
 from replay_tpu.parallel.sharding import shard_activation
 
-MIXERS = ("full_attention", "sliding_attention", "conv")
+MIXERS = ("full_attention", "sliding_attention", "conv", "latent_attention")
 ATTENTION_SCOPES = {"full_attention": "attention", "sliding_attention": "window_attention"}
 
 
@@ -72,6 +84,10 @@ class PatternBlock(nn.Module):
     sliding_window: Optional[int] = None
     fused_attention: bool = False
     rope_scaling: Optional[Mapping[str, Any]] = None  # this layer type's rope_parameters
+    kv_latent_dim: Optional[int] = None  # latent_attention: the key/value latent's width
+    rope_head_dim: Optional[int] = None  # latent_attention: the rotary part of q and k
+    value_head_dim: Optional[int] = None  # latent_attention: a value head (None: head_dim)
+    shared_expert_dim: int = 0  # 0: a sparse layer is its routed experts alone
 
     @nn.compact
     def __call__(self, x, attention_mask, padding_mask):
@@ -90,6 +106,18 @@ class PatternBlock(nn.Module):
                     window=self.sliding_window if sliding else None,
                     rope_scaling=self.rope_scaling, name="attention",
                 )(h, None if sliding or self.fused_attention else attention_mask, padding_mask)
+        elif self.mixer == "latent_attention":
+            if not self.kv_latent_dim or not self.rope_head_dim:
+                msg = "a latent_attention layer needs kv_latent_dim and rope_head_dim"
+                raise ValueError(msg)
+            with jax.named_scope("latent_attention"):
+                h = LatentAttention(
+                    num_heads=self.num_heads, latent_dim=self.kv_latent_dim,
+                    nope_head_dim=self.head_dim, rope_head_dim=self.rope_head_dim,
+                    value_head_dim=self.value_head_dim or self.head_dim,
+                    rope_theta=self.rope_theta, norm_eps=self.norm_eps, dtype=self.dtype,
+                    name="attention",
+                )(h, padding_mask)
         elif self.mixer == "conv":
             with jax.named_scope("conv"):
                 h = GatedShortConv(self.conv_kernel, dtype=self.dtype, name="conv")(h)
@@ -100,12 +128,22 @@ class PatternBlock(nn.Module):
         h = norm("ffn_norm")(x)
         if self.sparse:
             with jax.named_scope("moe"):
-                h = SparseExperts(
+                routed = SparseExperts(
                     num_experts=self.num_experts, experts_held=self.experts_held,
                     expert_offset=self.expert_offset, top_k=self.experts_per_token,
                     hidden_dim=self.expert_dim, scale=self.routed_scale,
                     dtype=self.dtype, router=self.router, name="moe",
                 )(h, token_mask=padding_mask)
+            if self.shared_expert_dim:
+                with jax.named_scope("shared_expert"):  # a sibling of `moe`, not inside it
+                    routed = routed + SwiGLU(
+                        self.shared_expert_dim, x.shape[-1], dtype=self.dtype, name="shared_expert"
+                    )(h)
+                self.sow(
+                    "counters", "shared_expert_tokens", jnp.sum(padding_mask, dtype=jnp.int32),
+                    reduce_fn=lambda _, new: new, init_fn=lambda: None,  # one value a step
+                )
+            h = routed
         else:
             with jax.named_scope("dense_ffn"):
                 h = SwiGLU(self.dense_dim, x.shape[-1], dtype=self.dtype, name="dense_ffn")(h)
@@ -137,6 +175,10 @@ class LayerPatternEncoder(nn.Module):
     sliding_window: Optional[int] = None
     fused_attention: bool = False
     rope_scaling: Optional[Mapping[str, Any]] = None  # {layer type: rope_parameters}
+    kv_latent_dim: Optional[int] = None
+    rope_head_dim: Optional[int] = None
+    value_head_dim: Optional[int] = None
+    shared_expert_dim: int = 0
 
     @nn.compact
     def __call__(self, x, attention_mask, padding_mask):
@@ -152,6 +194,9 @@ class LayerPatternEncoder(nn.Module):
                 experts_per_token=self.experts_per_token, routed_scale=self.routed_scale,
                 norm_eps=self.norm_eps, dtype=self.dtype, router=self.router,
                 sliding_window=self.sliding_window, fused_attention=self.fused_attention,
-                rope_scaling=(self.rope_scaling or {}).get(mixer), name=f"layer_{i}",
+                rope_scaling=(self.rope_scaling or {}).get(mixer),
+                kv_latent_dim=self.kv_latent_dim, rope_head_dim=self.rope_head_dim,
+                value_head_dim=self.value_head_dim, shared_expert_dim=self.shared_expert_dim,
+                name=f"layer_{i}",
             )(x, attention_mask, padding_mask)
         return x

@@ -26,6 +26,14 @@ too), ``rope_scaling`` by layer type (YaRN on the full layers), ``router="softma
 ``num_dense_layers=0`` and ``tie_embeddings=False``: an output table of its
 own, ``logits = rms(x) . output_table^T``, which ``get_item_weights()`` returns,
 so the input table gets no gradient from the head.
+
+And the latent-attention pattern of the public ``deepseek_v3`` configuration
+that Moonlight publishes (https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/config.json):
+``layer_types`` of ``latent_attention`` with ``kv_latent_dim`` (``kv_lora_rank``),
+``head_dim`` (``qk_nope_head_dim``), ``rope_head_dim`` (``qk_rope_head_dim``) and
+``value_head_dim`` (``v_head_dim``), one leading dense layer, the sigmoid router
+with ``routed_scale``, and ``shared_expert_dim``: a dense SwiGLU beside the
+routed share of every sparse layer (replay_tpu.nn.blocks).
 """
 
 from __future__ import annotations
@@ -76,6 +84,10 @@ class HybridRec(nn.Module):
     sliding_window: Optional[int] = None
     fused_attention: bool = False
     rope_scaling: Optional[Mapping[str, Any]] = None  # {layer type: rope_parameters}
+    kv_latent_dim: Optional[int] = None  # latent_attention layers: see replay_tpu.nn.blocks
+    rope_head_dim: Optional[int] = None
+    value_head_dim: Optional[int] = None
+    shared_expert_dim: int = 0  # 0: no shared expert beside the routed ones
     tie_embeddings: bool = True
     excluded_features: tuple = ()
     dtype: Any = jnp.float32
@@ -98,6 +110,8 @@ class HybridRec(nn.Module):
             routed_scale=self.routed_scale, norm_eps=self.norm_eps,
             dtype=self.dtype, router=self.router, sliding_window=self.sliding_window,
             fused_attention=self.fused_attention, rope_scaling=self.rope_scaling,
+            kv_latent_dim=self.kv_latent_dim, rope_head_dim=self.rope_head_dim,
+            value_head_dim=self.value_head_dim, shared_expert_dim=self.shared_expert_dim,
             name="encoder",
         )
         self.final_norm = RMSNorm(self.norm_eps, dtype=self.dtype, name="final_norm")

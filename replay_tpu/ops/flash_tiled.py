@@ -28,6 +28,11 @@ the query blocks that see it (:func:`q_block_range`). Never O(L²), never a pair
 outside the band. :func:`block_counts` gives what both directions visit, for the
 counters of the layer that calls the route.
 
+Value width apart from key width. Scores contract over ``q.shape[-1]`` (which is
+``k``'s too, and sets the softmax scale); ``v``, the output and its cotangent
+are ``v.shape[-1]`` wide. Latent attention has keys of 192 (128 + a 64-wide
+rotary part) over values of 128; nothing is padded to the wider of the two.
+
 Beyond-parity: the reference has no custom kernels; its torch path
 materializes [B, H, L, L] (SURVEY.md §2.3). The mesh-sharded regime is ring
 attention (replay_tpu/parallel/ring.py); this kernel is the within-chip story.
@@ -176,9 +181,12 @@ def _pad_to(x, axis, multiple, value=0.0):
 
 def _forward(q, k, v, kv_bias, causal, block_q, block_k, interpret, window=None):
     batch, heads, length, dim = q.shape
-    kv_heads = k.shape[1]
+    kv_heads, value_dim = k.shape[1], v.shape[-1]
     if heads % kv_heads:
         msg = f"{heads} query heads do not divide over {kv_heads} key/value heads"
+        raise ValueError(msg)
+    if k.shape[-1] != dim:
+        msg = f"queries are {dim} wide and keys {k.shape[-1]}: scores contract over one width"
         raise ValueError(msg)
     if window is not None and not causal:
         msg = "a window is a causal band (0 <= i - j < window); causal=False has none"
@@ -199,8 +207,9 @@ def _forward(q, k, v, kv_bias, causal, block_q, block_k, interpret, window=None)
     grid = (batch, heads, num_q, steps)
     qspec = pl.BlockSpec((1, 1, block_q, dim), lambda b, h, i, s: (b, h, i, 0))
     kspec = pl.BlockSpec((1, 1, block_k, dim), lambda b, h, i, s: (b, h // group, kv_block(i, s), 0))
+    vspec = pl.BlockSpec((1, 1, block_k, value_dim), lambda b, h, i, s: (b, h // group, kv_block(i, s), 0))
     bspec = pl.BlockSpec((1, 1, block_k), lambda b, h, i, s: (b, 0, kv_block(i, s)))
-    out_spec = pl.BlockSpec((1, 1, block_q, dim), lambda b, h, i, s: (b, h, i, 0))
+    out_spec = pl.BlockSpec((1, 1, block_q, value_dim), lambda b, h, i, s: (b, h, i, 0))
     lse_spec = pl.BlockSpec((1, 1, block_q, 128), lambda b, h, i, s: (b, h, i, 0))
 
     from jax.experimental.pallas import tpu as pltpu
@@ -208,16 +217,16 @@ def _forward(q, k, v, kv_bias, causal, block_q, block_k, interpret, window=None)
     scratch = [
         pltpu.VMEM((block_q, 128), jnp.float32),  # running max
         pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
-        pltpu.VMEM((block_q, dim), jnp.float32),  # output accumulator
+        pltpu.VMEM((block_q, value_dim), jnp.float32),  # output accumulator
     ]
     out, lse = pl.pallas_call(
         partial(_kernel, block_q=block_q, block_k=block_k, num_k=num_k, steps=steps,
                 causal=causal, window=window),
         grid=grid,
-        in_specs=[qspec, kspec, kspec, bspec],
+        in_specs=[qspec, kspec, vspec, bspec],
         out_specs=[out_spec, lse_spec],
         out_shape=[
-            jax.ShapeDtypeStruct(qp.shape, q.dtype),
+            jax.ShapeDtypeStruct(qp.shape[:-1] + (value_dim,), q.dtype),
             jax.ShapeDtypeStruct((batch, heads, lq, 128), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -230,7 +239,7 @@ def _forward(q, k, v, kv_bias, causal, block_q, block_k, interpret, window=None)
 def flash_attention_tiled(
     q: jnp.ndarray,  # [B, H, L, D]
     k: jnp.ndarray,  # [B, Hkv, L, D], H a multiple of Hkv
-    v: jnp.ndarray,
+    v: jnp.ndarray,  # [B, Hkv, L, Dv]: the output is [B, H, L, Dv]
     kv_bias: jnp.ndarray,  # [B, L] additive per-key bias (0 valid / -1e30 pad)
     causal: bool = True,
     block_q: int = 256,
@@ -346,7 +355,7 @@ def _bwd(causal, block_q, block_k, interpret, window, residuals, g):
 
     q, k, v, kv_bias, out, lse = residuals
     batch, heads, length, dim = q.shape
-    kv_heads = k.shape[1]
+    kv_heads, value_dim = k.shape[1], v.shape[-1]
     group = heads // kv_heads
     block_q, block_k, num_q, num_k = _blocks(length, block_q, block_k)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, H, L]
@@ -359,16 +368,19 @@ def _bwd(causal, block_q, block_k, interpret, window, residuals, g):
 
     # -- dq: grid and walk of the forward
     steps, kv_block = _kv_walk(num_q, block_q, block_k, num_k, causal, window)
-    q_rows = pl.BlockSpec((1, 1, block_q, dim), lambda b, h, i, s: (b, h, i, 0))
-    q_column = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, s: (b, h, i, 0))
-    kv_rows = pl.BlockSpec((1, 1, block_k, dim), lambda b, h, i, s: (b, h // group, kv_block(i, s), 0))
+    rows_of = lambda block, width, index: pl.BlockSpec((1, 1, block, width), index)  # noqa: E731
+    q_index = lambda b, h, i, s: (b, h, i, 0)  # noqa: E731
+    kv_index = lambda b, h, i, s: (b, h // group, kv_block(i, s), 0)  # noqa: E731
+    q_rows, g_rows = rows_of(block_q, dim, q_index), rows_of(block_q, value_dim, q_index)
+    q_column = pl.BlockSpec((1, 1, block_q, 1), q_index)
+    k_rows, v_rows = rows_of(block_k, dim, kv_index), rows_of(block_k, value_dim, kv_index)
     dq = pl.pallas_call(
         partial(_dq_kernel, block_q=block_q, block_k=block_k, num_k=num_k, steps=steps,
                 causal=causal, window=window),
         grid=(batch, heads, num_q, steps),
-        in_specs=[q_rows, kv_rows, kv_rows,
+        in_specs=[q_rows, k_rows, v_rows,
                   pl.BlockSpec((1, 1, block_k), lambda b, h, i, s: (b, 0, kv_block(i, s))),
-                  q_rows, q_column, q_column],
+                  g_rows, q_column, q_column],
         out_specs=q_rows,
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
@@ -384,21 +396,24 @@ def _bwd(causal, block_q, block_k, interpret, window, residuals, g):
         return jnp.minimum(first + t % steps, last)
 
     head = lambda h, t: h * group + t // steps  # noqa: E731
-    q_rows = pl.BlockSpec((1, 1, block_q, dim), lambda b, h, j, t: (b, head(h, t), q_block(j, t), 0))
+    q_index = lambda b, h, j, t: (b, head(h, t), q_block(j, t), 0)  # noqa: E731
+    kv_index = lambda b, h, j, t: (b, h, j, 0)  # noqa: E731
+    q_rows, g_rows = rows_of(block_q, dim, q_index), rows_of(block_q, value_dim, q_index)
     q_row = pl.BlockSpec((1, 1, 1, block_q), lambda b, h, j, t: (b, head(h, t), 0, q_block(j, t)))
-    kv_rows = pl.BlockSpec((1, 1, block_k, dim), lambda b, h, j, t: (b, h, j, 0))
+    k_rows, v_rows = rows_of(block_k, dim, kv_index), rows_of(block_k, value_dim, kv_index)
     dk, dv, dbias = pl.pallas_call(
         partial(_dkv_kernel, block_q=block_q, block_k=block_k, num_q=num_q, steps=steps,
                 group=group, causal=causal, window=window),
         grid=(batch, kv_heads, num_k, group * steps),
-        in_specs=[q_rows, kv_rows, kv_rows,
+        in_specs=[q_rows, k_rows, v_rows,
                   pl.BlockSpec((1, block_k, 1), lambda b, h, j, t: (b, j, 0)),
-                  q_rows, q_row, q_row],
-        out_specs=[kv_rows, kv_rows,
-                   pl.BlockSpec((1, 1, block_k, 128), lambda b, h, j, t: (b, h, j, 0))],
+                  g_rows, q_row, q_row],
+        out_specs=[k_rows, v_rows,
+                   pl.BlockSpec((1, 1, block_k, 128), kv_index)],
         out_shape=[jax.ShapeDtypeStruct(kp.shape, k.dtype), jax.ShapeDtypeStruct(vp.shape, v.dtype),
                    jax.ShapeDtypeStruct((batch, kv_heads, lk, 128), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_k, dim), jnp.float32), pltpu.VMEM((block_k, dim), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_k, dim), jnp.float32),
+                        pltpu.VMEM((block_k, value_dim), jnp.float32),
                         pltpu.VMEM((block_k, 128), jnp.float32)],
         interpret=interpret,
     )(qp, kp, vp, bias_p[:, :, None], gp, lse_p[:, :, None, :], delta_p[:, :, None, :])

@@ -17,6 +17,7 @@ from replay_tpu.obs.trace import Tracer, chunk_stage_log
 from replay_tpu.parallel.sharding import LOGICAL_AXES, ShardingRules, logical_axes_tree
 
 KEY = jax.random.PRNGKey(0)
+PROGRAMS = None  # this module's SharedPrograms, set by tests/conftest.py
 KWARGS = dict(layer_types=("conv", "full_attention", "conv"), num_dense_layers=1, num_heads=4,
               num_kv_heads=2, dense_dim=24, expert_dim=8, num_experts=8, experts_held=4,
               expert_offset=2, experts_per_token=2)
@@ -96,7 +97,9 @@ def test_fit_trains_and_carries_the_expert_counters(model, scan_chunk):
             if event.event == "on_train_step":
                 events.append(event)
 
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2), seed=3)
+    trainer = PROGRAMS.adopt(
+        Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2), seed=3)
+    )
     tracer = Tracer()
     batches = train_batches()
     before = len(chunk_stage_log())
@@ -125,7 +128,9 @@ def test_fit_trains_and_carries_the_expert_counters(model, scan_chunk):
 
 
 def test_the_expert_bias_is_a_buffer_the_optimizer_leaves_alone(model):
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2), seed=3)
+    trainer = PROGRAMS.adopt(
+        Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2), seed=3)
+    )
     batches = train_batches()
     state = trainer.init_state(batches[0])
     bias = jax.tree.map(
@@ -157,7 +162,9 @@ WINDOWED = dict(
 
 def test_the_windowed_pattern_trains_through_fit_and_carries_both_kinds_of_counter(item_only_schema):
     model = HybridRec(schema=item_only_schema, **WINDOWED)
-    trainer = Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2), seed=3)
+    trainer = PROGRAMS.share_init(
+        Trainer(model=model, loss=CE(), optimizer=OptimizerFactory(learning_rate=1e-2), seed=3)
+    )
     batches = train_batches()
     for b in batches:  # items 12..19 never come in
         ids = b["feature_tensors"]["item_id"]

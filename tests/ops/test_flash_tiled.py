@@ -1,6 +1,8 @@
 """Tiled flash attention: parity with full attention at every shape class the
 single-block kernel cannot reach (interpret mode — no TPU needed)."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -249,12 +251,12 @@ def test_grouped_banded_route_matches_the_plain_banded_softmax(length, block, wi
 
     keep = np.asarray(mask)[:, None, :, None]  # padded query rows are zeroed by the model
     got = jax.jit(fused)(q, k, v, bias)
-    want = banded_reference(q, k, v, bias, window)
+    want = jax.jit(partial(banded_reference, window=window))(q, k, v, bias)
     np.testing.assert_allclose(got * keep, want * keep, rtol=2e-5, atol=2e-5)
 
     weight = jnp.asarray(np.random.default_rng(4).normal(size=q.shape).astype(np.float32)) * keep
     grads = jax.jit(jax.grad(lambda *a: jnp.sum(fused(*a) * weight), argnums=(0, 1, 2, 3)))(q, k, v, bias)
-    wanted = jax.grad(lambda *a: jnp.sum(banded_reference(*a, window) * weight), argnums=(0, 1, 2, 3))(
+    wanted = jax.jit(jax.grad(lambda *a: jnp.sum(banded_reference(*a, window) * weight), argnums=(0, 1, 2, 3)))(
         q, k, v, bias
     )
     for g, w, name in zip(grads, wanted, "qkvb"):
@@ -344,3 +346,66 @@ def test_both_backward_kernels_walk_the_forwards_pairs(block_q, block_k, window)
     first, last = q_block_range(np.arange(num_k), block_q, block_k, num_q, True, window, np)
     by_key = {(i, j) for j in range(num_k) for i in range(first[j], last[j] + 1)}
     assert by_query == by_key and len(by_query) < num_q * num_k
+
+
+# ---------------------------------------------------------------------------
+# value (and output, and cotangent) width apart from the query/key width: latent
+# attention scores over 192 = 128 + a 64-wide rotary part and mixes values of 128
+
+
+def narrow_value_inputs(length, dim, value_dim, heads, kv_heads, seed=5):
+    q, k, _, mask = banded_inputs(length, heads=heads, kv_heads=kv_heads, dim=dim, seed=seed)
+    v = banded_inputs(length, heads=heads, kv_heads=kv_heads, dim=value_dim, seed=seed + 1)[2]
+    return q, k, v, mask
+
+
+# (length, block, window, key width, value width, heads, key/value heads)
+WIDTHS = [(32, 8, None, 12, 8, 4, 4), (29, 8, 13, 12, 8, 4, 2), (21, 16, 5, 8, 16, 2, 1)]
+
+
+@pytest.mark.parametrize("length,block,window,dim,value_dim,heads,kv_heads", WIDTHS)
+def test_value_width_apart_from_key_width_matches_the_dense_formula(
+    length, block, window, dim, value_dim, heads, kv_heads
+):
+    q, k, v, mask = narrow_value_inputs(length, dim, value_dim, heads, kv_heads)
+    bias = padding_mask_bias(mask)
+
+    def fused(q, k, v, bias):
+        return flash_attention_tiled(q, k, v, bias, True, block, block, True, window)
+
+    keep = np.asarray(mask)[:, None, :, None]
+    got = jax.jit(fused)(q, k, v, bias)
+    assert got.shape == (*q.shape[:-1], value_dim)
+    want = jax.jit(partial(banded_reference, window=window))(q, k, v, bias)
+    np.testing.assert_allclose(got * keep, want * keep, rtol=2e-5, atol=2e-5)
+
+    weight = jnp.asarray(np.random.default_rng(4).normal(size=got.shape).astype(np.float32)) * keep
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(fused(*a) * weight), argnums=(0, 1, 2, 3)))(q, k, v, bias)
+    wanted = jax.jit(jax.grad(lambda *a: jnp.sum(banded_reference(*a, window) * weight), argnums=(0, 1, 2, 3)))(
+        q, k, v, bias
+    )
+    for g, w, name in zip(grads, wanted, "qkvb"):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("window", [None, 13], ids=lambda w: f"window{w}")
+def test_narrow_values_equal_the_equal_width_route_on_zero_padded_values(window):
+    """The equal-width call is the route as it was (one width for every block
+    spec); what the narrow call adds must give, bit for bit, what that route
+    gives for values padded with zero columns up to the key width."""
+    q, k, v, mask = narrow_value_inputs(32, 12, 8, 4, 2)
+    bias = padding_mask_bias(mask)
+    padded = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, 4)))
+    route = lambda values: flash_attention_tiled(q, k, values, bias, True, 8, 8, True, window)  # noqa: E731
+    np.testing.assert_array_equal(np.asarray(route(v)), np.asarray(route(padded))[..., :8])
+    weight = jnp.asarray(np.random.default_rng(6).normal(size=(*q.shape[:-1], 8)).astype(np.float32))
+    narrow = jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention_tiled(q, k, v, bias, True, 8, 8, True, window) * weight), argnums=(0, 1, 2))(q, k, v)
+    wide = jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention_tiled(q, k, v, bias, True, 8, 8, True, window)[..., :8] * weight), argnums=(0, 1, 2))(
+        q, k, padded)
+    for g, w in zip(narrow, (wide[0], wide[1], wide[2][..., :8])):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+    with pytest.raises(ValueError, match="contract over one width"):
+        flash_attention_tiled(q, k[..., :8], v, bias, True, 8, 8, True, window)

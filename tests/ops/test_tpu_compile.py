@@ -115,6 +115,19 @@ def test_grouped_banded_route_lowers_at_the_published_widths(one_chip, window, g
     assert_mosaic(fwd_or_grad(lambda q, k, v, b: route(q, k, v, b), grad, (0, 1, 2)), q, kv, kv, bias)
 
 
+# the fused route at Moonlight's published latent-attention widths, one row of 4,096
+# positions (the cell's) and of 8,192 (the published context): 16 heads, scores over
+# 192 (128 + the 64-wide rotary part), values and output 128 wide
+@pytest.mark.parametrize("length,grad", [(4096, False), (4096, True), (8192, True)],
+                         ids=["L4096-fwd", "L4096-grad", "L8192-grad"])
+def test_latent_route_lowers_at_the_published_192_128_widths(one_chip, length, grad):
+    qk = jax.ShapeDtypeStruct((1, 16, length, 192), bf16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 16, length, 128), bf16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((1, length), f32, sharding=one_chip)
+    route = partial(flash_attention_tiled, causal=True, block_q=512, block_k=512)
+    assert_mosaic(fwd_or_grad(lambda q, k, v, b: route(q, k, v, b), grad, (0, 1, 2)), qk, qk, v, bias)
+
+
 # (rows, contraction, columns): the expert layer's two grouped products at the
 # published widths (d 2048, expert width 1536), 8 x 1024 positions x 4 picks of rows
 GROUPED_SHAPES = [(32768, 2048, 1536), (32768, 1536, 2048)]
