@@ -6,7 +6,8 @@ CESampledWeighted).
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,15 +48,149 @@ def _softmax_nll_bwd(residuals, g):
 _softmax_nll.defvjp(_softmax_nll_fwd, _softmax_nll_bwd)
 
 
-class CE(LossBase):
-    """Full-softmax cross-entropy over the whole item catalog.
+# The routes of the full-softmax head. PLAIN writes the ``[B, L, I]`` logits
+# (``get_logits`` + :func:`_softmax_nll`); FUSED keeps them in VMEM
+# (``ops.fused_ce.fused_lse``: three Pallas kernels, the product formed again on the
+# way back); SHARDED is FUSED under the trainer's mesh, each device's rows (and
+# catalog shard) inside one ``shard_map`` (``parallel.sharded_ce.sharded_fused_lse``).
+PLAIN, FUSED, SHARDED = "plain", "fused", "fused_sharded"
 
-    For the way back the head saves the logits as ``get_logits`` produced them and
-    one log-sum-exp a row (:func:`_softmax_nll`), not the second tensor of the logits'
-    shape that ``log_softmax``'s own rule keeps: at 25,600 positions by 27,278 items
-    that is 2.8 GB of float32 written and held across the step for the sake of one
-    scalar a row.
+# The widest embedding at which :class:`CE` takes the fused route. Both routes cost
+# ~ positions x items, so the width decides: the plain route's four passes over the
+# float32 logits do not grow with it, the fused route's five float32 products do (a
+# contraction of up to 128 is one pass of the MXU, up to 256 two, 300 three).
+# TPU v5e, 25,600 positions x 27,278 items, SASRec's train_scan at that width, device
+# ms a step under the ``loss`` scope and its transpose (my chip runs, PR 34; PERF.md,
+# Findings PR 34; d 64 is the benchmark cell itself, d 128's fused reading the head alone):
+#
+#     width    64      128     192     256     300
+#     plain    16.46   16.44   16.39   15.73   15.77
+#     fused     6.54    6.58   12.13   12.84   17.90
+#
+# At 256 the fused route is still 18% under (2.9 ms of a 26.5 ms step, and 2.8 GB of
+# HBM it never holds); at 300 it loses by 13%. No width between was read, so the
+# limit stays at the last one measured to win.
+FUSED_MAX_WIDTH = 256
+
+
+def _backend() -> str:
+    return jax.default_backend()
+
+
+def dtypes_sanctioned(hidden_dtype, table_dtype) -> bool:
+    """Matching dtypes, or the flax compute-dtype split where one side is float32
+    (the master table, or f32 hidden states) and the other a narrower float: the
+    kernels accumulate in float32, exactly what ``get_logits``'s einsum promotion
+    does (``Trainer(precision="bf16")``, docs/performance.md "The precision
+    ladder"). An integer / quantized table or two different narrow floats are not."""
+    h_dt, t_dt = jnp.dtype(hidden_dtype), jnp.dtype(table_dtype)
+    floats = jnp.issubdtype(h_dt, jnp.floating) and jnp.issubdtype(t_dt, jnp.floating)
+    return h_dt == t_dt or (floats and jnp.dtype(jnp.float32) in (h_dt, t_dt))
+
+
+def full_softmax_route(
+    *,
+    backend: str,
+    tying_head: bool,
+    positives: int,
+    rows: int,
+    width: int,
+    hidden_dtype=None,
+    table_dtype=None,
+    mesh_shape: Optional[Mapping[str, int]] = None,
+    row_axes: Tuple[str, ...] = (),
+    vocab_axis: Optional[str] = None,
+) -> str:
+    """The route :class:`CE` takes, from what it can observe when the step is traced.
+
+    FUSED (SHARDED under a mesh of several devices) only when ALL hold: the backend
+    is ``"tpu"`` (elsewhere the Pallas interpreter would stand in for the kernels);
+    the model has a bias-free tying head over ``get_item_weights`` (``tying_head``:
+    the trainer bound the table), so ``hidden . table^T`` IS ``get_logits``; one
+    positive label; dtypes the kernels accumulate faithfully
+    (:func:`dtypes_sanctioned`); the width is at most :data:`FUSED_MAX_WIDTH`; and,
+    under a mesh, the mesh has the axes the trainer's rule table names and the
+    flattened ``rows`` divide over the row axes (a Pallas call needs its
+    ``shard_map``). Anything else: PLAIN.
     """
+    if backend != "tpu" or not tying_head or positives != 1:
+        return PLAIN
+    if width > FUSED_MAX_WIDTH or not dtypes_sanctioned(hidden_dtype, table_dtype):
+        return PLAIN
+    mesh_shape = dict(mesh_shape or {})
+    if math.prod(mesh_shape.values()) <= 1:
+        return FUSED
+    if vocab_axis not in mesh_shape or any(axis not in mesh_shape for axis in row_axes):
+        return PLAIN
+    if rows % math.prod(mesh_shape[axis] for axis in row_axes):
+        return PLAIN
+    return SHARDED
+
+
+class CE(LossBase):
+    """Full-softmax cross-entropy over the whole item catalog; the head CHOOSES its route.
+
+    :func:`full_softmax_route` decides at trace time, from the backend, the model's
+    head, the shapes and dtypes and the trainer's mesh (no option sets it):
+
+    - PLAIN: ``get_logits`` writes the ``[B, L, I]`` logits; for the way back the head
+      saves them and one log-sum-exp a row (:func:`_softmax_nll`), not the second
+      tensor of the logits' shape that ``log_softmax``'s own rule keeps. Four
+      HBM-bound passes over the logits a step (2.8 GB of float32 at 25,600 positions
+      by 27,278 items), whatever the width.
+    - FUSED / SHARDED: the log-sum-exp comes from ``ops.fused_ce.fused_lse`` (under a
+      mesh inside ``parallel.sharded_ce.sharded_fused_lse``) and the label's logit
+      from one gathered row: the logits never reach HBM. Taken on a TPU for a model
+      with a bias-free tying head (``logits_via_item_weights``; the trainer then binds
+      ``item_embeddings_callback``, the mesh and its axes) up to
+      :data:`FUSED_MAX_WIDTH`, where the kernels' float32 products cost less than
+      the plain route's passes.
+
+    :attr:`route` is the route of the last trace; :class:`CEFused` and
+    :class:`CEFusedTP` are this head with the route forced.
+    """
+
+    route = PLAIN
+
+    def __init__(self) -> None:
+        super().__init__()
+        # bound by the trainer for a model that declares the tying head
+        self.item_embeddings_callback = None
+        self.mesh = None
+        self.axis_name = "model"
+        self.data_axis = "data"  # one mesh axis, a tuple of them, or None (rows replicated)
+        # the kernels' parameters: CEFused's arguments; None = from the shapes
+        self.tile: Optional[int] = None
+        self.item_tile: Optional[int] = None
+        self.interpret: Optional[bool] = None
+
+    @property
+    def avoid_full_logits(self) -> bool:
+        """Whether the traced step never held ``[B, L, I]`` logits: health's
+        logits statistics then stream over catalog chunks (``obs.health``)."""
+        return self.route != PLAIN
+
+    def _row_axes(self) -> Tuple[str, ...]:
+        if self.data_axis is None:
+            return ()
+        return self.data_axis if isinstance(self.data_axis, tuple) else (self.data_axis,)
+
+    def _choose_route(self, hidden: jnp.ndarray, positives: int) -> str:
+        tying_head = self.item_embeddings_callback is not None
+        # the table's shape and dtype without an op in the traced step
+        table = jax.eval_shape(self.item_embeddings_callback) if tying_head else None
+        return full_softmax_route(
+            backend=_backend(),
+            tying_head=tying_head,
+            positives=positives,
+            rows=math.prod(hidden.shape[:-1]),
+            width=hidden.shape[-1],
+            hidden_dtype=hidden.dtype,
+            table_dtype=table.dtype if tying_head else None,
+            mesh_shape=None if self.mesh is None else self.mesh.shape,
+            row_axes=self._row_axes(),
+            vocab_axis=self.axis_name,
+        )
 
     def __call__(
         self,
@@ -69,9 +204,13 @@ class CE(LossBase):
         if positive_labels.shape[-1] != 1:
             msg = "Multi-positive labels are not supported by the CE loss"
             raise NotImplementedError(msg)
-        logits = self.logits_callback(model_embeddings)  # [B, L, I]
-        labels = jnp.clip(positive_labels[..., 0], 0, logits.shape[-1] - 1)
-        nll = _softmax_nll(logits, labels)
+        self.route = self._choose_route(model_embeddings, positive_labels.shape[-1])
+        if self.route == PLAIN:
+            logits = self.logits_callback(model_embeddings)  # [B, L, I]
+            labels = jnp.clip(positive_labels[..., 0], 0, logits.shape[-1] - 1)
+            nll = _softmax_nll(logits, labels)
+        else:
+            labels, nll = self._fused_nll(model_embeddings, positive_labels)
         weights = self._label_weights(labels, nll.dtype)
         mask = target_padding_mask[..., 0].astype(nll.dtype) * weights
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
@@ -79,41 +218,7 @@ class CE(LossBase):
     def _label_weights(self, labels, dtype):
         return jnp.ones_like(labels, dtype=dtype)
 
-
-class CEFused(CE):
-    """CE with the pallas fused-logsumexp head (TPU).
-
-    Bitwise-equivalent math to :class:`CE` up to f32-vs-bf16 softmax precision
-    (the fused path accumulates in f32 inside VMEM), but the ``[B, L, I]``
-    logits tensor never reaches HBM — the dominant train-step traffic at
-    full-catalog scales. Compiled on the ``"tpu"`` backend; on ``"cpu"`` the
-    Pallas interpreter stands in (logged once at WARNING); any other backend
-    raises unless ``interpret=`` is given (``ops.flash_attention.pallas_interpret``).
-
-    Contract: the loss reconstructs logits as ``hidden · get_item_weights()ᵀ``,
-    so it matches :class:`CE` only for models whose ``get_logits`` is a
-    BIAS-FREE tying head over that same table (SasRec/TiSasRec/Bert4Rec). Such
-    models declare ``logits_via_item_weights = True``; the trainer refuses to
-    bind CEFused to a model without that declaration (a model adding an item
-    bias or scale would otherwise silently train with a different loss).
-    """
-
-    needs_item_embeddings = True
-    requires_tying_head = True
-    # the full [B, L, I] logits never exist on this path: health's logits-stats
-    # collector must stream its last-position stats over catalog chunks (or
-    # flag itself skipped) instead of calling get_logits (obs.health)
-    avoid_full_logits = True
-
-    def __init__(
-        self, tile: int = 256, item_tile: Optional[int] = None, interpret: bool = None
-    ) -> None:
-        super().__init__()
-        self.tile = tile
-        self.item_tile = item_tile
-        self.interpret = interpret
-        self.item_embeddings_callback = None
-
+    # -- the fused route ----------------------------------------------------- #
     def _item_table(self) -> jnp.ndarray:
         if self.item_embeddings_callback is None:
             msg = (
@@ -129,27 +234,14 @@ class CEFused(CE):
         return self.item_embeddings_callback()
 
     def _check_dtypes(self, hidden: jnp.ndarray, table: jnp.ndarray) -> None:
-        """Reject dtype mismatches the kernel would silently paper over.
-
-        Sanctioned: identical dtypes, and the flax compute-dtype split where
-        one side is the float32 PARAM table (or f32 hidden) and the other a
-        narrower float — the kernel accumulates in f32, exactly what
-        ``get_logits``'s einsum promotion does. This is the precision
-        ladder's bf16 rung (``Trainer(precision="bf16")``: bf16 hidden
-        states against the f32 master table, docs/performance.md "The
-        precision ladder"). Anything else (an integer / quantized table, two
-        different narrow floats) is a bug at the call site, named here
-        instead of surfacing as a wrong-loss training run.
-        """
-        h_dt, t_dt = jnp.dtype(hidden.dtype), jnp.dtype(table.dtype)
-        floats = jnp.issubdtype(h_dt, jnp.floating) and jnp.issubdtype(t_dt, jnp.floating)
-        sanctioned = h_dt == t_dt or (
-            floats and jnp.dtype(jnp.float32) in (h_dt, t_dt)
-        )
-        if not sanctioned:
+        """Reject dtype mismatches the kernel would silently paper over
+        (:func:`dtypes_sanctioned`): a bug at the call site, named here instead
+        of surfacing as a wrong-loss training run."""
+        if not dtypes_sanctioned(hidden.dtype, table.dtype):
             msg = (
-                f"{type(self).__name__}: hidden states are {h_dt} but the item "
-                f"table is {t_dt}. Only matching dtypes, or the sanctioned "
+                f"{type(self).__name__}: hidden states are {jnp.dtype(hidden.dtype)} "
+                f"but the item table is {jnp.dtype(table.dtype)}. Only matching "
+                "dtypes, or the sanctioned "
                 "mixed-precision split — narrow-float compute (e.g. bfloat16 "
                 "hidden states, the Trainer(precision='bf16') rung) against "
                 "the float32 master/param table, accumulated in f32 inside "
@@ -165,23 +257,36 @@ class CEFused(CE):
         return pallas_interpret() if self.interpret is None else self.interpret
 
     def _lse(self, hidden2d: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
-        """``[N]`` catalog logsumexp — the seam :class:`CEFusedTP` overrides."""
-        from replay_tpu.ops.fused_ce import fused_lse
+        """``[N]`` catalog logsumexp by :attr:`route`."""
+        from replay_tpu.ops.fused_ce import fused_lse, row_tile
 
-        return fused_lse(hidden2d, table, self.tile, self.item_tile, self._resolve_interpret())
+        tile = row_tile(hidden2d.shape[0], self.tile)
+        if self.route != SHARDED:
+            return fused_lse(hidden2d, table, tile, self.item_tile, self._resolve_interpret())
+        from replay_tpu.parallel.sharded_ce import sharded_fused_lse
 
-    def __call__(
-        self,
-        model_embeddings,
-        feature_tensors,
-        positive_labels,
-        negative_labels,
-        padding_mask,
-        target_padding_mask,
-    ) -> jnp.ndarray:
-        if positive_labels.shape[-1] != 1:
-            msg = "Multi-positive labels are not supported by the CE loss"
-            raise NotImplementedError(msg)
+        if self.mesh is None:
+            msg = (
+                f"{type(self).__name__} needs the device mesh to shard the rows "
+                "and the catalog over: "
+                "train through replay_tpu.nn.Trainer (which binds loss.mesh) "
+                "or assign loss.mesh before the first call."
+            )
+            raise AttributeError(msg)
+        return sharded_fused_lse(
+            hidden2d,
+            table,
+            self.mesh,
+            axis_name=self.axis_name,
+            data_axis=self.data_axis,
+            tile=tile,
+            item_tile=self.item_tile,
+            interpret=self._resolve_interpret(),
+        )
+
+    def _fused_nll(self, model_embeddings, positive_labels):
+        """``(labels, nll)`` with the log-sum-exp from the kernels and the label's
+        logit from its one table row."""
         table = self._item_table()  # [I, E]
         self._check_dtypes(model_embeddings, table)
         num_items = table.shape[0]
@@ -192,14 +297,49 @@ class CEFused(CE):
             model_embeddings.astype(jnp.float32) * table[labels].astype(jnp.float32),
             axis=-1,
         )
-        nll = lse - label_logit
-        weights = self._label_weights(labels, nll.dtype)
-        mask = target_padding_mask[..., 0].astype(nll.dtype) * weights
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        return labels, lse - label_logit
+
+
+class CEFused(CE):
+    """:class:`CE` with the FUSED route forced (the Pallas log-sum-exp head, one device).
+
+    Bitwise-equivalent math to the plain route up to f32-vs-bf16 softmax precision
+    (the fused path accumulates in f32 inside VMEM), but the ``[B, L, I]``
+    logits tensor never reaches HBM — the dominant train-step traffic at
+    full-catalog scales. Forcing is for a catalog whose logits do not fit, or a
+    width past :data:`FUSED_MAX_WIDTH` where memory matters more than time;
+    otherwise :class:`CE` takes this route where it is the faster one. Compiled on
+    the ``"tpu"`` backend; on ``"cpu"`` the Pallas interpreter stands in (logged
+    once at WARNING); any other backend raises unless ``interpret=`` is given
+    (``ops.flash_attention.pallas_interpret``).
+
+    Contract: the loss reconstructs logits as ``hidden · get_item_weights()ᵀ``,
+    so it matches the plain route only for models whose ``get_logits`` is a
+    BIAS-FREE tying head over that same table (SasRec/TiSasRec/Bert4Rec). Such
+    models declare ``logits_via_item_weights = True``; the trainer refuses to
+    bind CEFused to a model without that declaration (a model adding an item
+    bias or scale would otherwise silently train with a different loss).
+    """
+
+    needs_item_embeddings = True
+    requires_tying_head = True
+    route = FUSED
+
+    def __init__(
+        self, tile: int = 256, item_tile: Optional[int] = None, interpret: bool = None
+    ) -> None:
+        super().__init__()
+        self.tile = tile
+        self.item_tile = item_tile
+        self.interpret = interpret
+
+    def _choose_route(self, hidden: jnp.ndarray, positives: int) -> str:
+        return type(self).route  # forced: CEFusedTP's too
 
 
 class CEFusedTP(CEFused):
-    """:class:`CEFused` with the item table sharded over the mesh's TP axis.
+    """:class:`CE` with the SHARDED route forced: the fused head under a mesh, the
+    item table sharded over the mesh's TP axis.
 
     The catalog lives ``[I/n_tp, E]`` per device (the layout
     ``Trainer(shard_vocab=True)`` already places the embedding params in);
@@ -216,6 +356,7 @@ class CEFusedTP(CEFused):
     """
 
     needs_mesh = True
+    route = SHARDED
 
     def __init__(
         self,
@@ -228,28 +369,6 @@ class CEFusedTP(CEFused):
         super().__init__(tile, item_tile, interpret)
         self.axis_name = axis_name
         self.data_axis = data_axis
-        self.mesh = None
-
-    def _lse(self, hidden2d: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
-        from replay_tpu.parallel.sharded_ce import sharded_fused_lse
-
-        if self.mesh is None:
-            msg = (
-                "CEFusedTP needs the device mesh to shard the catalog over: "
-                "train through replay_tpu.nn.Trainer (which binds loss.mesh) "
-                "or assign loss.mesh before the first call."
-            )
-            raise AttributeError(msg)
-        return sharded_fused_lse(
-            hidden2d,
-            table,
-            self.mesh,
-            axis_name=self.axis_name,
-            data_axis=self.data_axis,
-            tile=self.tile,
-            item_tile=self.item_tile,
-            interpret=self._resolve_interpret(),
-        )
 
 
 class CEWeighted(CE):

@@ -48,6 +48,7 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from replay_tpu.metrics.builder import MetricsBuilder
+from replay_tpu.nn.loss.ce import PLAIN
 from replay_tpu.obs import (
     CompileTracker,
     ConsoleLogger,
@@ -883,8 +884,20 @@ class Trainer:
                 "such declaration."
             )
             raise ValueError(msg)
-        if getattr(loss, "needs_mesh", False):
-            # vocab-sharded losses (CEFusedTP) shard_map over the trainer mesh
+        # a loss that CHOOSES its route (CE: nn.loss.ce.full_softmax_route) is bound
+        # the table wherever the model declares the bias-free tying head, and
+        # refuses nothing: without the declaration it keeps the plain route
+        tying_head = getattr(model, "logits_via_item_weights", False) and hasattr(
+            type(model), "get_item_weights"
+        )
+        binds_table = getattr(loss, "needs_item_embeddings", False) or (
+            tying_head and hasattr(loss, "item_embeddings_callback")
+        )
+        if not binds_table and hasattr(loss, "item_embeddings_callback"):
+            loss.item_embeddings_callback = None  # no table of another trainer's model
+        if getattr(loss, "needs_mesh", False) or (binds_table and hasattr(loss, "mesh")):
+            # losses that run the fused head under the mesh (CE where it chooses
+            # to, CEFusedTP) shard_map over the trainer mesh
             # with their axes taken from the ONE rule table: the catalog over
             # the "vocab" rule, the flattened [B·L, E] rows over the batch
             # (× length, under SP) axes — the loss carries no layout of its own
@@ -981,8 +994,9 @@ class Trainer:
                     # promote through the f32 item table)
                     logits_callback = precision.wrap_logits_callback(logits_callback)
                 loss.logits_callback = logits_callback
-                if getattr(loss, "needs_item_embeddings", False):
-                    # SCE-style losses mine hard negatives from the raw item table
+                if binds_table:
+                    # SCE-style losses mine hard negatives from the raw item table;
+                    # the fused CE head forms its logits from it
                     loss.item_embeddings_callback = partial(
                         model.apply, {"params": params}, method=type(model).get_item_weights
                     )
@@ -1028,16 +1042,15 @@ class Trainer:
                     # inference path serves) — cheap next to the loss's scoring
                     last_hidden = hidden[:, -1, :] if hidden.ndim == 3 else hidden
                     if getattr(loss, "avoid_full_logits", False):
-                        # memory-wall losses (CEFused/CEFusedTP/SCE/GBCE) never
+                        # memory-wall losses (CE on its fused route, read after
+                        # the loss was traced above; CEFused/CEFusedTP/SCE/GBCE) never
                         # materialize [B, I] logits — neither may health. For
                         # bias-free tying heads the same stats stream over
                         # catalog chunks (obs.health.streamed_logits_stats);
                         # anything else is flagged skipped IN the record (a
                         # numeric sentinel: every sink stays scalar-typed) —
                         # never silently absent.
-                        if getattr(model, "logits_via_item_weights", False) and hasattr(
-                            type(model), "get_item_weights"
-                        ):
+                        if tying_head:
                             from replay_tpu.obs.health import streamed_logits_stats
 
                             table = model.apply(
@@ -2515,6 +2528,12 @@ class Trainer:
                                     chunk_stages.synced(
                                         k, dispatch, device_wait, compile_delta > 0, feeder,
                                         {n: v.tolist() for n, v in counters.items()},
+                                        # the route the loss head took when this
+                                        # trainer's programs were traced (one set
+                                        # of shapes: the step's and the scan's agree)
+                                        ce_fused_steps=(
+                                            k if getattr(self.loss, "route", PLAIN) != PLAIN else 0
+                                        ),
                                     )
                                     # the scan has read the chunk's device
                                     # copy: let it go here, inside `account`

@@ -622,6 +622,8 @@ def chunk_stage_log() -> List[Dict[str, Any]]:
     ``batch_build_python_rows`` (rows of the chunk's batches that the batcher
     assembled in its per-row python loop: 0 while the native gather takes
     every sequence feature),
+    ``ce_fused_steps`` (steps of the chunk whose program ran the fused loss head,
+    ``nn.loss.ce.full_softmax_route``: ``steps`` or 0),
     ``device_leaves`` (leaves of the chunk's batches that arrived as jax
     Arrays: each is a D2H read inside ``stack``), ``h2d_bytes`` and, for a
     model that counts (``sows_counters``), ``counters``: per name the chunk's
@@ -700,6 +702,7 @@ class ChunkStages:
         compiled: bool,
         feeder: Optional[Mapping[str, Any]] = None,
         counters: Optional[Mapping[str, Any]] = None,
+        ce_fused_steps: int = 0,
     ) -> Dict[str, Any]:
         # what the model counted over the chunk (nested lists [K, ...] by name)
         # rides the `account` span as totals and the chunk's record in full
@@ -728,6 +731,7 @@ class ChunkStages:
         record["transform_by_name"] = dict(feeder.get("transform_by_name", ()))
         record["transform_device_programs"] = int(feeder.get("transform_device_programs", 0))
         record["batch_build_python_rows"] = int(feeder.get("batch_build_python_rows", 0))
+        record["ce_fused_steps"] = int(ce_fused_steps)
         record["device_leaves"] = int(feeder.get("device_leaves", 0))
         record["h2d_bytes"] = int(feeder.get("h2d_bytes", 0))
         if counters:

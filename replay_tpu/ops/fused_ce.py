@@ -55,6 +55,7 @@ import numpy as np
 logger = logging.getLogger("replay_tpu")
 
 _LANE = 128  # TPU lane width: catalog axis is padded to a multiple of this
+_DEFAULT_ROWS = 512  # rows per program where the caller names none (row_tile)
 _DEFAULT_ITEM_TILE = 4096  # catalog tiles: [row_tile, item_tile] logits blocks
 # finite catalog-padding mask: exp(_MASK - lse) == 0.0 exactly for any
 # realistic lse (f32 exp underflows below ~-104), so real rows are
@@ -112,6 +113,15 @@ def _resolve_item_tile(num_items: int, item_tile, tile: int, embed: int) -> int:
                 shrunk,
             )
     return shrunk
+
+
+def row_tile(rows: int, tile: Optional[int]) -> int:
+    """Rows a program for a caller that names none: 512 (no more than the rows there
+    are), which read 2.5-5% under 256 at every width tried (TPU v5e, 25,600 x 27,278,
+    the head alone, forward + backward, my chip run, PR 34: d 64 6.63 against 6.98
+    ms, d 128 6.58 against 6.75, d 192 11.93 against 12.49; 128 rows read 7.76, 7.49,
+    13.98). The catalog's tile is :func:`_resolve_item_tile`'s."""
+    return min(_DEFAULT_ROWS, _pad_to(max(rows, 1), 8)) if tile is None else tile
 
 
 def _masked_logits(num_valid_ref, h_ref, w_ref, item_tile: int):

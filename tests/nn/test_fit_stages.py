@@ -152,6 +152,8 @@ def test_stack_counts_the_device_leaves_of_the_default_transforms(traced_fit):
     # jax Array, no transform dispatched a device program
     assert {r["device_leaves"] for r in traced_fit["records"]} == {0}
     assert {r["transform_device_programs"] for r in traced_fit["records"]} == {0}
+    # and on the CPU the CE head writes its logits: no step ran the fused head
+    assert {r["ce_fused_steps"] for r in traced_fit["records"]} == {0}
     events = traced_fit["tracer"].to_chrome_trace()["traceEvents"]
     assert {e["args"]["device_leaves"] for e in events if e["name"] == "stack"} == {0}
     assert {e["args"]["device_programs"] for e in events if e["name"] == "transform"} == {0}

@@ -775,7 +775,8 @@ def test_chunk_stages_tile_the_fit_threads_time_and_carry_the_feeders():
             time.sleep(0.002)
         with stages.stage("device_wait") as device_wait:
             time.sleep(0.05)
-        stages.synced(2, dispatch, device_wait, compiled=stages.chunk == 0, feeder=record)
+        stages.synced(2, dispatch, device_wait, compiled=stages.chunk == 0, feeder=record,
+                      ce_fused_steps=2 * (stages.chunk % 2))
         time.sleep(0.005)  # bookkeeping: `account` is open
         if stages.chunk == 2:
             stages.new_epoch()
@@ -785,6 +786,7 @@ def test_chunk_stages_tile_the_fit_threads_time_and_carry_the_feeders():
     records = [r for r in chunk_stage_log() if r["fit"] == stages.fit]
     assert [r["chunk"] for r in records] == [0, 1, 2, 3]
     assert [r["compiled"] for r in records] == [True, False, False, False]
+    assert [r["ce_fused_steps"] for r in records] == [0, 2, 0, 2]  # as the fit thread said
     assert ["period" in r for r in records] == [False, True, False, True]
     for record in records:
         assert record["steps"] == 2 and record["device_leaves"] == 2

@@ -79,6 +79,14 @@ def test_non_divisible_catalog_padding_masked():
     assert_parity(make_mesh(4, 2), h, w, g)
 
 
+def test_data_parallel_mesh_with_the_table_replicated():
+    """``data`` = 4, ``model`` = 1 (the four-chip benchmark cell; the route ``CE``
+    takes under such a mesh): each device runs the kernels on its own rows over
+    the whole catalog, and ``shard_map``'s transpose sums ``dW`` over ``data``."""
+    h, w, g = make_data(32, 37, 16, seed=5)
+    assert_parity(make_mesh(4, 1), h, w, g)
+
+
 def test_multi_tile_shard():
     """Each 300-row shard sweeps several 128-column catalog tiles: the online
     max/sum inside a shard composes with the cross-shard combine."""

@@ -161,13 +161,23 @@ def test_single_block_route_refuses_long_sequences():
         )
 
 
-def test_sharded_fused_lse_grad_lowers_on_2x2(topo):
-    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+# (mesh axes, their sizes, rows, table's spec): DP2 x TP2 with the catalog sharded,
+# and the four-chip benchmark cell's data=4 mesh (512 x 50 rows a chip, the table
+# replicated): the route CE takes under a mesh (nn.loss.ce.full_softmax_route)
+SHARDED_CE_MESHES = [
+    (("data", "model"), (2, 2), 25600, P("model", None)),
+    (("data", "model", "seq"), (4, 1, 1), 102400, P(None, None)),
+]
+
+
+@pytest.mark.parametrize("axes,sizes,rows,table_spec", SHARDED_CE_MESHES, ids=["dp2tp2", "dp4"])
+def test_sharded_fused_lse_grad_lowers_on_2x2(topo, axes, sizes, rows, table_spec):
+    mesh = Mesh(np.array(topo.devices).reshape(sizes), axes)
     hidden = jax.ShapeDtypeStruct(
-        (25600, 64), bf16, sharding=NamedSharding(mesh, P("data", None))
+        (rows, 64), bf16, sharding=NamedSharding(mesh, P("data", None))
     )
     table = jax.ShapeDtypeStruct(
-        (27278, 64), f32, sharding=NamedSharding(mesh, P("model", None))
+        (27278, 64), f32, sharding=NamedSharding(mesh, table_spec)
     )
     assert_mosaic(
         fwd_or_grad(lambda h, w: sharded_fused_lse(h, w, mesh), True, (0, 1)), hidden, table
