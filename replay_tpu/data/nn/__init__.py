@@ -1,3 +1,7 @@
+import time as _time
+
+_IMPORT_STARTED = _time.perf_counter()  # handed to the start-up log on the last line
+
 from .schema_builder import TensorSchemaBuilder
 from .utils import ensure_pandas, groupby_sequences
 from .iterator import (
@@ -52,3 +56,9 @@ __all__ = [
     "validation_batches",
     "write_sequence_parquet",
 ]
+
+# the seconds this package's own imports took, as `pkg_import` in the start-up
+# log (obs.trace.startup_log)
+from replay_tpu.obs.trace import package_imported as _package_imported
+
+_package_imported(__name__, _IMPORT_STARTED)

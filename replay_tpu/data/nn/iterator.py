@@ -139,6 +139,10 @@ class SequenceBatcher:
                 "Use fixed-shape batches for multi-host training."
             )
             raise ValueError(msg)
+        with stage("batcher_init", tracer=self.tracer):
+            self._index_rows()
+
+    def _index_rows(self) -> None:
         self._schema = self.dataset.schema
         self._seq_names = [f.name for f in self._schema.all_features if f.is_seq]
         self._scalar_names = [f.name for f in self._schema.all_features if not f.is_seq]

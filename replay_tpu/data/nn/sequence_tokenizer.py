@@ -34,6 +34,7 @@ from replay_tpu.data.dataset_label_encoder import DatasetLabelEncoder
 from replay_tpu.data.nn.schema import TensorFeatureInfo, TensorSchema
 from replay_tpu.data.nn.sequential_dataset import SequentialDataset
 from replay_tpu.data.schema import FeatureSource
+from replay_tpu.obs.trace import stage
 from replay_tpu.preprocessing.label_encoder import HandleUnknownStrategies
 
 
@@ -196,7 +197,8 @@ class SequenceTokenizer:
     def fit_transform(
         self, dataset: Dataset, tensor_features_to_keep: Optional[Sequence[str]] = None
     ) -> SequentialDataset:
-        return self.fit(dataset).transform(dataset, tensor_features_to_keep)
+        with stage("tokenize"):  # obs.trace.startup_log: the encoders and the group-by
+            return self.fit(dataset).transform(dataset, tensor_features_to_keep)
 
     # -- persistence --------------------------------------------------------- #
     def save(self, path: str) -> None:

@@ -1,3 +1,7 @@
+import time as _time
+
+_IMPORT_STARTED = _time.perf_counter()  # handed to the start-up log on the last line
+
 from . import loss
 from .agg import ConcatAggregator, PositionAwareAggregator, SumAggregator
 from .attention import MultiHeadAttention, MultiHeadDifferentialAttention, RMSNorm
@@ -89,3 +93,9 @@ __all__ = [
     "make_mesh",
     "padding_mask_from_ids",
 ]
+
+# the seconds this package's own imports took, as `pkg_import` in the start-up
+# log (obs.trace.startup_log)
+from replay_tpu.obs.trace import package_imported as _package_imported
+
+_package_imported(__name__, _IMPORT_STARTED)

@@ -743,25 +743,28 @@ class Trainer:
         path (replay_tpu.nn.vocab): the reference rebuilds its optimizer the
         same way after ``set_item_embeddings_*``.
         """
-        state_rng = jax.random.split(jax.random.PRNGKey(self.seed))[1]
-        if params is None:
-            params = self._init_params(example_batch)
         from replay_tpu.parallel.sharding import params_shardings
 
-        shardings = params_shardings(self.mesh, params, self.sharding_rules)
-        params = _place_tree(jax.tree.map(np.asarray, params), shardings)
-        # every leaf is placed on the mesh, scalars included: a leaf created
-        # outside it carries no mesh in its type, the step's outputs do, and the
-        # second dispatch of each program would retrace and compile again
-        opt_state = _globalize_scalars(self.mesh, self._tx.init(params))
-        replicated = NamedSharding(self.mesh, P())
-        step, rng, bad_steps = (
-            jax.make_array_from_process_local_data(replicated, np.asarray(v))
-            for v in (jnp.zeros((), jnp.int32), state_rng, jnp.zeros((), jnp.int32))
-        )
-        return TrainState(
-            step=step, params=params, opt_state=opt_state, rng=rng, bad_steps=bad_steps
-        )
+        # one stage: the start-up log (obs.trace.startup_log) says what of a
+        # set-up was the state's init, and what jax built for it
+        with stage("init_state"):
+            state_rng = jax.random.split(jax.random.PRNGKey(self.seed))[1]
+            if params is None:
+                params = self._init_params(example_batch)
+            shardings = params_shardings(self.mesh, params, self.sharding_rules)
+            params = _place_tree(jax.tree.map(np.asarray, params), shardings)
+            # every leaf is placed on the mesh, scalars included: a leaf created
+            # outside it carries no mesh in its type, the step's outputs do, and the
+            # second dispatch of each program would retrace and compile again
+            opt_state = _globalize_scalars(self.mesh, self._tx.init(params))
+            replicated = NamedSharding(self.mesh, P())
+            step, rng, bad_steps = (
+                jax.make_array_from_process_local_data(replicated, np.asarray(v))
+                for v in (jnp.zeros((), jnp.int32), state_rng, jnp.zeros((), jnp.int32))
+            )
+            return TrainState(
+                step=step, params=params, opt_state=opt_state, rng=rng, bad_steps=bad_steps
+            )
 
     def _init_params(self, example_batch: Batch) -> Any:
         """A fresh flax init of the model's parameters from :attr:`seed` (pure:
@@ -2539,7 +2542,13 @@ class Trainer:
                                     # copy: let it go here, inside `account`
                                     placed = fed = None
                                     if tracing and compile_delta > 0:
-                                        trace.carve(dispatch.span, "compile", compile_delta)
+                                        # the seconds jax said it spent tracing,
+                                        # lowering and compiling (or fetching)
+                                        # inside the dispatch (obs.trace's
+                                        # listeners), not the whole call
+                                        trace.carve(
+                                            dispatch.span, "compile", dispatch.compile_seconds
+                                        )
                                     self.last_step_metrics = chunk_metrics
                                     # chunk-boundary HBM sample: the scan path
                                     # otherwise only snapshots memory per

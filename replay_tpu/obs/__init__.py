@@ -80,6 +80,7 @@ from .trace import (
     lifecycle_span,
     merge_traces,
     stage,
+    startup_log,
     tail_attribution,
     traced_iterator,
 )
@@ -138,6 +139,7 @@ __all__ = [
     "read_flight",
     "scrape_snapshot",
     "stage",
+    "startup_log",
     "tail_attribution",
     "traced_iterator",
 ]

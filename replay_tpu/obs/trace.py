@@ -24,15 +24,26 @@ Design:
   span on the device ops' clock, plane ``/host:CPU``), always adds its seconds
   to the **chunk stage log** (:func:`chunk_stage_log`: a fixed-size ring of one
   record per scan chunk), and records into a :class:`Tracer` when one is
-  attached. :class:`ChunkStages` is the fit thread's side of that log.
+  attached. :class:`ChunkStages` is the fit thread's side of that log. What a
+  thread's stages total outside any chunk (set-up: ``pkg_import``, ``split``,
+  ``tokenize``, ``batcher_init``, ``init_state``) is the
+  **start-up log** (:func:`startup_log`): one record per ``fit`` call.
+* Once jax is imported the module listens to ``jax.monitoring``, once a process:
+  every program that jax traces, lowers and compiles (or fetches from the
+  persistent cache), on any thread, adds to the stage totals of the thread it
+  happened on: ``compile_programs``, ``compile_trace_s``, ``compile_lower_s``,
+  ``compile_backend_s``, ``compile_cache_load_s``, ``compile_cache_hits``,
+  ``compile_cache_misses``. They ride the chunk's record or the start-up record
+  like a stage's seconds.
 * :meth:`Tracer.summary` aggregates per-name **inclusive** and **exclusive**
   (self) time; :func:`goodput_breakdown` turns an exclusive-time snapshot
   diff into the epoch/fit goodput record carried by ``on_epoch_end`` /
   ``on_fit_end`` events.
 
 The module is import-light on purpose (no jax, no numpy): the report CLI and
-the core-tier tests run it host-only. The annotation class is taken from
-``sys.modules["jax"]`` only once something else has imported jax.
+the core-tier tests run it host-only. The annotation class and the listeners'
+registry are taken from ``sys.modules["jax"]`` only once something else has
+imported jax.
 """
 
 from __future__ import annotations
@@ -60,6 +71,7 @@ from typing import (
 
 __all__ = [
     "CHUNK_STAGES",
+    "COMPILE_COUNTERS",
     "ChunkStages",
     "GOODPUT_SPANS",
     "REQUEST_HOP_SPANS",
@@ -74,7 +86,9 @@ __all__ = [
     "goodput_breakdown",
     "lifecycle_span",
     "merge_traces",
+    "package_imported",
     "stage",
+    "startup_log",
     "tail_attribution",
     "traced_iterator",
 ]
@@ -456,11 +470,40 @@ _FEEDER_FIELDS = CHUNK_STAGES["feeder"]
 # span arguments that count: a stage's totals sum each under ``<stage>_<argument>``
 _COUNTED_ARGS = ("device_programs", "python_rows")
 
+# what jax reports of every program it builds (jax/_src/dispatch.py, pjit.py,
+# interpreters/pxla.py and compiler.py of jax 0.9), by the field it adds to. A
+# phase arrives as a scalar when it starts and as a duration when it ends, on
+# the thread that does the work. ``backend_compile_duration`` wraps
+# ``compiler.compile_or_get_cached``, the look into the persistent cache
+# included: one event per executable, compiled or fetched, and the cache's
+# load time (``cache_retrieval_time_sec``, fired on a hit) lies inside it.
+# ``cache_hits`` fires on every executable fetched; ``cache_misses`` only when a
+# compiled one is WRITTEN (never with no cache directory, nor under jax's
+# default least compile time and entry size): what XLA compiled is
+# ``compile_programs`` less ``compile_cache_hits``, whatever the cache's settings.
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile_trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile_lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_backend_s",
+}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile_cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile_cache_misses",
+}
+COMPILE_COUNTERS = (
+    "compile_programs",
+    *_COMPILE_PHASES.values(),
+    "compile_cache_load_s",
+    *_CACHE_EVENTS.values(),
+)
+
 # one record per scan chunk, appended by the fit thread when the chunk's
 # metrics are on the host: 4096 chunks is a quarter of an hour of SASRec at
 # the ML-20M catalog. The lock is taken once per chunk (and by readers), never
-# per step.
+# per step. The start-up log beside it gets one record per ``fit`` call.
 _CHUNK_LOG: Deque[Dict[str, Any]] = collections.deque(maxlen=4096)
+_STARTUP_LOG: Deque[Dict[str, Any]] = collections.deque(maxlen=4096)
 _CHUNK_LOG_LOCK = threading.Lock()
 _FIT_ORDINALS = itertools.count(1)
 
@@ -469,17 +512,23 @@ _FIT_ORDINALS = itertools.count(1)
 # in trace.json without a constructor argument)
 _ATTACHED: Optional["Tracer"] = None
 _ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+_LISTENING = False  # the jax.monitoring listeners below are registered
+# (perf_counter at its top, own seconds) of every package that timed its import
+_PACKAGE_IMPORTS: List[Tuple[float, float]] = []
 
 
 class _ThreadStages(threading.local):
     """Seconds of the stages that ran on this thread: ``loose`` holds those of
     spans that named no chunk since the last :func:`claim_chunk` (the input
     pipeline does not know which chunk it fills), ``record`` is the chunk this
-    thread last claimed."""
+    thread last claimed. ``open`` is the innermost stage the thread is inside,
+    ``phases`` the seconds nested in each compile phase jax has begun on it."""
 
     def __init__(self) -> None:
         self.loose: Dict[str, Any] = {}
         self.record: Optional[Dict[str, Any]] = None
+        self.open: Optional["stage"] = None
+        self.phases: List[float] = []
 
 
 _THREAD = _ThreadStages()
@@ -497,6 +546,111 @@ def attached_tracer() -> Optional["Tracer"]:
     return _ATTACHED
 
 
+def _totals_for(args: Mapping[str, Any]) -> Dict[str, Any]:
+    """Where a stage with these arguments adds on this thread: the record of
+    the chunk the thread claimed if ``args`` name that chunk, else ``loose``."""
+    local = _THREAD
+    record = local.record
+    return record if record is not None and record["chunk"] == args.get("chunk") else local.loose
+
+
+def _add_stage(name: str, seconds: float, args: Mapping[str, Any]) -> None:
+    totals = _totals_for(args)
+    totals[name] = totals.get(name, 0.0) + seconds
+    key = args.get(name)
+    if key is not None:
+        by_name = totals.setdefault(name + "_by_name", {})
+        by_name[key] = by_name.get(key, 0.0) + seconds
+    for counter in _COUNTED_ARGS:
+        count = args.get(counter)
+        if count is not None:
+            counted = f"{name}_{counter}"
+            totals[counted] = totals.get(counted, 0) + count
+
+
+def _count(name: str, amount: float) -> Dict[str, Any]:
+    """A compile event adds where the innermost open stage of its thread will
+    (a ``transform`` on the feeder: the chunk being filled; a ``dispatch``: the
+    chunk being run), and outside any stage where one without a chunk would."""
+    inside = _THREAD.open
+    totals = _totals_for(inside.args if inside is not None else {})
+    totals[name] = totals.get(name, 0) + amount
+    return totals
+
+
+def _on_phase_start(event: str, value: float, **_: Any) -> None:
+    if event in _COMPILE_PHASES:
+        _THREAD.phases.append(0.0)
+
+
+def _on_phase_seconds(event: str, seconds: float, **_: Any) -> None:
+    name = _COMPILE_PHASES.get(event)
+    if name is None:
+        if event == _CACHE_LOAD_EVENT:
+            _count("compile_cache_load_s", seconds)
+        return
+    # a phase inside another (a jitted function traced while its caller is, an
+    # eager op compiled during a trace) takes its seconds out of the outer
+    # one's: trace + lower + backend is time of this thread, counted once
+    local = _THREAD
+    nested = local.phases.pop() if local.phases else 0.0
+    if local.phases:
+        local.phases[-1] += seconds
+    own = max(seconds - nested, 0.0)
+    totals = _count(name, own)
+    if name == "compile_backend_s":
+        totals["compile_programs"] = totals.get("compile_programs", 0) + 1
+    if local.open is not None:
+        local.open.compile_seconds += own
+
+
+def _on_cache_event(event: str, **_: Any) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        _count(name, 1)
+
+
+def _listen(monitoring: Any) -> None:
+    """Register the three listeners with ``jax.monitoring``, once a process:
+    module functions that write to the calling thread's totals and hold nothing."""
+    global _LISTENING
+    with _CHUNK_LOG_LOCK:
+        if _LISTENING:
+            return
+        _LISTENING = True
+    monitoring.register_scalar_listener(_on_phase_start)
+    monitoring.register_event_duration_secs_listener(_on_phase_seconds)
+    monitoring.register_event_listener(_on_cache_event)
+
+
+def _find_jax() -> Any:
+    """``jax.profiler.TraceAnnotation`` once something has imported jax (and
+    from then on the listeners are registered), else ``None``."""
+    global _ANNOTATION
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    monitoring = getattr(jax, "monitoring", None)
+    if profiler is None or monitoring is None:
+        return None
+    _listen(monitoring)
+    _ANNOTATION = profiler.TraceAnnotation
+    return _ANNOTATION
+
+
+def package_imported(package: str, started: float) -> None:
+    """The last line of a package's ``__init__`` hands its import to the
+    calling thread's stage totals as ``pkg_import`` (``pkg_import_by_name``
+    splits it by ``package``): the seconds since ``started``, the
+    ``perf_counter`` reading of the ``__init__``'s first line, less those of
+    the packages that timed themselves while it ran."""
+    own = time.perf_counter() - started
+    own -= sum(seconds for began, seconds in _PACKAGE_IMPORTS if began >= started)
+    _PACKAGE_IMPORTS.append((started, own))
+    _add_stage("pkg_import", own, {"pkg_import": package})
+    if _ANNOTATION is None:
+        _find_jax()  # what set-up builds before its first stage is counted too
+
+
 class stage:  # noqa: N801 - used as ``with stage(name):``, like Tracer.span
     """``with stage(name, **args):`` one boundary of the fit path, three things.
 
@@ -507,33 +661,39 @@ class stage:  # noqa: N801 - used as ``with stage(name):``, like Tracer.span
     argument cannot be called ``name``). Skipped until jax has been imported.
     (b) always: its seconds are added to the calling thread's stage totals,
     which :func:`claim_chunk` and :class:`ChunkStages` fold into the chunk
-    stage log (:func:`chunk_stage_log`).
+    stage log (:func:`chunk_stage_log`) and, outside a chunk, into the
+    start-up log (:func:`startup_log`).
     (c) with an enabled :class:`Tracer` (``tracer=``, else the attached one):
     the span is recorded there as ``tracer.span(name, **args)`` would; the live
     span is :attr:`span` (for :meth:`Tracer.carve`).
 
     After exit :attr:`seconds` is the duration and :attr:`end` the
-    ``perf_counter`` reading it closed at. A span whose args hold a key of its
-    own name (``stage("transform", transform="Mask")``) is also totalled by
+    ``perf_counter`` reading it closed at; :attr:`compile_seconds` is what jax
+    spent tracing, lowering and compiling (or fetching) programs on this thread
+    while the stage was the innermost open one. A span whose args hold a key of
+    its own name (``stage("transform", transform="Mask")``) is also totalled by
     that value under ``<name>_by_name``, and one that counts (the compiled
     programs it dispatched, ``device_programs=n``; the rows a batch assembled in
     the per-row python loop, ``python_rows=n``) is summed under
     ``<name>_device_programs`` / ``<name>_python_rows``.
     """
 
-    __slots__ = ("name", "args", "seconds", "end", "span", "_tracer", "_annotation", "_start")
+    __slots__ = (
+        "name", "args", "seconds", "end", "span", "compile_seconds",
+        "_tracer", "_annotation", "_start", "_outer",
+    )
 
     def __init__(self, name: str, tracer: Optional["Tracer"] = None, **args: Any) -> None:
         self.name = name
         self.args = args
         self.seconds = 0.0
         self.end = 0.0
+        self.compile_seconds = 0.0
         self.span: Optional[_Span] = None
         self._tracer = tracer
         self._annotation = None
 
     def __enter__(self) -> "stage":
-        global _ANNOTATION
         # the clock is the outermost: what the span itself costs (and a wait
         # for the GIL that its own calls into the profiler let happen) is the
         # stage's, so consecutive stages of a thread tile its time
@@ -542,41 +702,23 @@ class stage:  # noqa: N801 - used as ``with stage(name):``, like Tracer.span
         if tracer is not None and tracer.enabled:
             self.span = tracer.span(self.name, **self.args)
             self.span.__enter__()
-        annotation = _ANNOTATION
-        if annotation is None:
-            profiler = getattr(sys.modules.get("jax"), "profiler", None)
-            if profiler is not None:
-                annotation = _ANNOTATION = profiler.TraceAnnotation
+        annotation = _ANNOTATION or _find_jax()
         if annotation is not None:
             self._annotation = annotation(self.name, **self.args)
             self._annotation.__enter__()
+        local = _THREAD
+        self._outer, local.open = local.open, self
         return self
 
     def __exit__(self, *exc_info) -> None:
+        _THREAD.open = self._outer
         if self._annotation is not None:
             self._annotation.__exit__(*exc_info)
         if self.span is not None:
             self.span.__exit__(*exc_info)
         self.end = time.perf_counter()
-        seconds = self.seconds = self.end - self._start
-        local = _THREAD
-        record = local.record
-        totals = (
-            record
-            if record is not None and record["chunk"] == self.args.get("chunk")
-            else local.loose
-        )
-        name = self.name
-        totals[name] = totals.get(name, 0.0) + seconds
-        key = self.args.get(name)
-        if key is not None:
-            by_name = totals.setdefault(name + "_by_name", {})
-            by_name[key] = by_name.get(key, 0.0) + seconds
-        for counter in _COUNTED_ARGS:
-            count = self.args.get(counter)
-            if count is not None:
-                counted = f"{name}_{counter}"
-                totals[counted] = totals.get(counted, 0) + count
+        self.seconds = self.end - self._start
+        _add_stage(self.name, self.seconds, self.args)
 
 
 def claim_chunk(chunk: int) -> Dict[str, Any]:
@@ -629,15 +771,64 @@ def chunk_stage_log() -> List[Dict[str, Any]]:
     model that counts (``sows_counters``), ``counters``: per name the chunk's
     ``[steps, ...]`` values as nested lists (``expert_load``:
     ``[steps, expert layers, held experts]``).
+    And what jax built during the chunk, on the fit thread and on the feeder
+    (:data:`COMPILE_COUNTERS`, from ``jax.monitoring``): ``compile_programs``
+    (executables compiled or fetched from the persistent cache),
+    ``compile_trace_s``, ``compile_lower_s`` and ``compile_backend_s`` (seconds
+    tracing to a jaxpr, lowering to MLIR, and in the backend: XLA's compile or
+    the cache's load; each second counted once, so the three add up to time of
+    their thread), ``compile_cache_load_s`` (the part of ``compile_backend_s``
+    that loaded from the cache), ``compile_cache_hits`` and
+    ``compile_cache_misses`` (executables fetched; compiled and written to the
+    cache: jax writes none without a cache directory or under its least compile
+    time and entry size, so the executables XLA compiled are
+    ``compile_programs`` less ``compile_cache_hits``, and a chunk or a start was
+    warm where the two are equal). A feeder's program is in the record of the
+    chunk it was filling. ``compiled`` says that one of the trainer's own
+    programs was traced in the ``dispatch``; the counters say how much of that
+    ``dispatch`` was which phase.
     Interleaved single steps (health cadence, an epoch's short tail) are in
-    the next chunk's ``period`` and in none of its stages.
+    the next chunk's ``period`` and in none of its stages; what they built is in
+    the next chunk's counters.
     """
     with _CHUNK_LOG_LOCK:
         return [dict(record) for record in _CHUNK_LOG]
 
 
+def startup_log() -> List[Dict[str, Any]]:
+    """The start-up log, oldest record first (copies: read-only).
+
+    One record per ``fit`` call of this process (the newest 4096), written when
+    the call begins and closed when its first chunk loop does (a per-step fit
+    has none: what it does is in the next record): the seconds of every stage
+    that ran on the fit's thread OUTSIDE any chunk since the previous ``fit``'s
+    last chunk, by name (set-up: ``pkg_import`` with ``pkg_import_by_name``,
+    ``split``, ``tokenize``, ``batcher_init``, ``init_state``; also the previous
+    ``fit``'s tail, its last ``account`` and any per-step ``h2d``, and the first
+    batch the call pulled itself), what jax built there
+    (:data:`COMPILE_COUNTERS`, as in :func:`chunk_stage_log`; a field is absent
+    where nothing added to it) and ``fit``, the call's ordinal.
+    The last record is the calling thread's so far, with ``fit`` ``None``:
+    what ran after the last ``fit``. A process's whole set-up is the sum over
+    these records plus the chunks whose ``compiled`` is true."""
+    with _CHUNK_LOG_LOCK:
+        log = [_fold({}, record) for record in _STARTUP_LOG]
+    log.append(_fold({"fit": None}, _THREAD.loose))
+    return log
+
+
 def _total(nested: Any) -> Any:
     return sum(map(_total, nested)) if isinstance(nested, list) else nested
+
+
+def _fold(into: Dict[str, Any], totals: Mapping[str, Any]) -> Dict[str, Any]:
+    """Add a thread's stage totals to ``into`` (the ``_by_name`` groups too)."""
+    for name, value in totals.items():
+        if isinstance(value, dict):
+            _fold(into.setdefault(name, {}), value)
+        elif value is not None:
+            into[name] = into.get(name, 0) + value
+    return into
 
 
 class ChunkStages:
@@ -659,9 +850,14 @@ class ChunkStages:
         self._account: Optional[stage] = None
         self._account_seconds = 0.0
         self._wait_seconds = 0.0
-        # what this thread's stages left over from earlier work is no chunk's
-        _THREAD.loose = {}
-        _THREAD.record = None
+        # what this thread's stages left over from earlier work is no chunk's:
+        # it is this fit's start-up record
+        local = _THREAD
+        started, local.loose, local.record = local.loose, {}, None
+        started["fit"] = self.fit
+        with _CHUNK_LOG_LOCK:
+            _STARTUP_LOG.append(started)
+        self._started: Optional[Dict[str, Any]] = started
 
     def stage(self, name: str, **args: Any) -> stage:
         return stage(name, tracer=self.tracer, chunk=self.chunk, **args)
@@ -678,6 +874,14 @@ class ChunkStages:
         self.close()
         self._done = None
         self._account_seconds = self._wait_seconds = 0.0
+        if self._started is not None:
+            # the fit's first chunk loop begins: what the call itself did until
+            # here (its state's init, a restore) is start-up as well
+            local = _THREAD
+            begun, local.loose = local.loose, {}
+            with _CHUNK_LOG_LOCK:
+                _fold(self._started, begun)
+            self._started = None
 
     def feed(self, source: Iterable[Any]) -> Iterator[Any]:
         iterator = iter(source)
@@ -734,6 +938,14 @@ class ChunkStages:
         record["ce_fused_steps"] = int(ce_fused_steps)
         record["device_leaves"] = int(feeder.get("device_leaves", 0))
         record["h2d_bytes"] = int(feeder.get("h2d_bytes", 0))
+        # what jax built for the chunk: on the thread that filled it, and on
+        # this one since the previous sync (with the feed off `feeder` is this
+        # thread's own claimed record, and `loose` what fell between two
+        # stages). The rest of `loose` is the chunk's stages again: dropped
+        local = _THREAD
+        loose, local.loose = local.loose, {}
+        for name in COMPILE_COUNTERS:
+            record[name] = feeder.get(name, 0) + loose.get(name, 0)
         if counters:
             record["counters"] = dict(counters)
         with _CHUNK_LOG_LOCK:

@@ -20,6 +20,8 @@ from typing import Optional
 import numpy as np
 import pandas as pd
 
+from replay_tpu.obs.trace import stage
+
 SplitterReturnType = tuple[pd.DataFrame, pd.DataFrame]
 
 
@@ -60,12 +62,14 @@ class Splitter(ABC):
     # -- public API -------------------------------------------------------
     def split(self, interactions: pd.DataFrame) -> SplitterReturnType:
         """Split interactions into (train, test)."""
-        test_mask = np.asarray(self._test_mask(interactions), dtype=bool)
-        if self.session_id_column is not None:
-            test_mask = self._recover_sessions(interactions, test_mask)
-        train = interactions[~test_mask]
-        test = interactions[test_mask]
-        return self._drop_cold(train, test)
+        # a pass over the whole log: a `split` stage (obs.trace.startup_log)
+        with stage("split"):
+            test_mask = np.asarray(self._test_mask(interactions), dtype=bool)
+            if self.session_id_column is not None:
+                test_mask = self._recover_sessions(interactions, test_mask)
+            train = interactions[~test_mask]
+            test = interactions[test_mask]
+            return self._drop_cold(train, test)
 
     @abstractmethod
     def _test_mask(self, interactions: pd.DataFrame) -> np.ndarray:

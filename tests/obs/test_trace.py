@@ -499,8 +499,13 @@ def test_traced_chunked_fit_goodput_sums_and_h2d_overlaps(tmp_path):
     chunked train_step spans carry their per-step attribution (steps=K)."""
     from replay_tpu.obs import JsonlLogger
 
+    from replay_tpu.obs.trace import COMPILE_COUNTERS, chunk_stage_log, stage, startup_log
+
     trainer, make_batch = _tiny_trainer(own_programs=True)  # its compile counts are asserted
     batches = [make_batch(i) for i in range(7)]  # two K=3 chunks + one tail step
+    with stage("split"):  # set-up: outside any chunk, so the fit's start-up record's
+        time.sleep(0.002)
+    fits_before = len(startup_log())
 
     run_dir = _run_dir(tmp_path, "chunked_smoke")
     # mode="w": REPLAY_TPU_RUN_DIR is a fixed path in CI — re-runs must not append
@@ -545,6 +550,35 @@ def test_traced_chunked_fit_goodput_sums_and_h2d_overlaps(tmp_path):
     # one compiled scan + one compiled per-step program (the tail)
     assert trainer.compile_tracker.traces["train_scan"] == 1
     assert trainer.compile_tracker.traces["train_step"] == 1
+
+    # the start-up log: one record for this fit, holding the stage that ran
+    # before it and the state's init (inside the fit, before its first chunk)
+    started = startup_log()[fits_before - 1]
+    assert startup_log()[-1]["fit"] is None and len(startup_log()) == fits_before + 1
+    assert started["split"] >= 0.002
+    records = [r for r in chunk_stage_log() if r["fit"] == started["fit"]]
+    assert [r["chunk"] for r in records] == [0, 1, 2, 3]
+    assert all("split" not in r and "init_state" not in r for r in records)
+    # what jax said it built: the scan in the first chunk's dispatch, split
+    # into its phases; the per-step program of the first epoch's tail in the
+    # chunk after it; nothing in the steady ones
+    first = records[0]
+    assert first["compiled"] and first["compile_programs"] >= 1
+    assert first["compile_trace_s"] > 0 and first["compile_lower_s"] > 0
+    assert first["compile_backend_s"] > 0
+    phases = sum(first[k] for k in ("compile_trace_s", "compile_lower_s", "compile_backend_s"))
+    assert phases <= first["dispatch"]
+    assert first["compile_cache_hits"] + first["compile_cache_misses"] >= 1
+    assert records[2]["compile_programs"] >= 1 and not records[2]["compiled"]
+    for steady in (records[1], records[3]):
+        # (a call whose arguments are committed anew looks its jaxpr up again:
+        # jax reports that as a trace of some tens of microseconds)
+        assert steady["compile_trace_s"] < 0.01
+        assert [steady[k] for k in COMPILE_COUNTERS if k != "compile_trace_s"] == [0] * 6
+    # the carved `compile` span is those phases, not the whole dispatch
+    dispatches = sorted(by_name["dispatch"], key=lambda e: e["ts"])
+    assert by_name["compile"][0]["dur"] <= dispatches[0]["dur"]
+    assert by_name["compile"][0]["dur"] == pytest.approx(1e6 * phases, rel=0.01)
 
 
 # --------------------------------------------------------------------------- #
@@ -806,3 +840,151 @@ def test_chunk_stages_tile_the_fit_threads_time_and_carry_the_feeders():
     # the feeder waited on the full queue while the fit thread ran chunk 1
     assert records[2]["feed_full"] >= 0.01
     assert ChunkStages().fit == stages.fit + 1  # the next fit call's ordinal
+
+
+TRACE, LOWER, BACKEND = (
+    f"/jax/core/compile/{phase}_duration"
+    for phase in ("jaxpr_trace", "jaxpr_to_mlir_module", "backend_compile")
+)
+CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+CACHE_HIT, CACHE_MISS = "/jax/compilation_cache/cache_hits", "/jax/compilation_cache/cache_misses"
+
+
+def test_compile_events_land_in_the_record_of_the_chunk_they_happened_in():
+    """``jax.monitoring`` events fired by hand: on a thread that claimed a chunk
+    they are in that chunk's record, on the fit thread in the record ``synced``
+    writes, and one chunk's record holds what BOTH threads built."""
+    from jax import monitoring
+
+    from replay_tpu.obs.trace import COMPILE_COUNTERS, ChunkStages, chunk_stage_log, claim_chunk, stage
+
+    fed = {}
+
+    def feeder():
+        with stage("transform", transform="Mask"):  # names no chunk: the one being filled
+            monitoring.record_event_duration_secs(TRACE, 0.25)
+        fed[0] = claim_chunk(0)
+        with stage("h2d", chunk=0):
+            monitoring.record_event_duration_secs(BACKEND, 0.5)
+            monitoring.record_event_duration_secs(CACHE_LOAD, 0.125)
+            monitoring.record_event(CACHE_HIT)
+        with stage("batch_build"):  # the next chunk's batches: not chunk 0's
+            monitoring.record_event_duration_secs(BACKEND, 4.0)
+        fed[1] = claim_chunk(1)
+
+    thread = threading.Thread(target=feeder)
+    thread.start()
+    thread.join()
+    assert fed[0]["compile_trace_s"] == 0.25 and fed[0]["compile_backend_s"] == 0.5
+    assert fed[0]["compile_cache_load_s"] == 0.125
+    assert (fed[0]["compile_programs"], fed[0]["compile_cache_hits"]) == (1, 1)
+    assert (fed[1]["compile_programs"], fed[1]["compile_backend_s"]) == (1, 4.0)
+
+    stages = ChunkStages()
+    monitoring.record_event_duration_secs(LOWER, 0.0625)  # between two stages: the next sync's
+    with stages.stage("dispatch") as dispatch:
+        monitoring.record_event_duration_secs(TRACE, 1.0)
+        monitoring.record_event_duration_secs(LOWER, 2.0)
+        monitoring.record_event_duration_secs(BACKEND, 3.0)
+        monitoring.record_event(CACHE_MISS)
+        monitoring.record_event("/jax/compilation_cache/compile_requests_use_cache")  # not counted
+        monitoring.record_event_duration_secs("/jax/compilation_cache/compile_time_saved_sec", 9.0)
+    with stages.stage("device_wait") as device_wait:
+        pass
+    assert dispatch.compile_seconds == 6.0 and device_wait.compile_seconds == 0.0
+    record = stages.synced(2, dispatch, device_wait, compiled=True, feeder=fed[0])
+    assert [record[k] for k in COMPILE_COUNTERS] == [2, 1.25, 2.0625, 3.5, 0.125, 1, 1]
+    # the fit thread's totals were taken: the next chunk starts from nought
+    with stages.stage("dispatch") as dispatch:
+        pass
+    with stages.stage("device_wait") as device_wait:
+        pass
+    steady = stages.synced(2, dispatch, device_wait, compiled=False)
+    stages.close()
+    assert [steady[k] for k in COMPILE_COUNTERS] == [0] * 7
+    assert chunk_stage_log()[-2:] == [record, steady]
+
+
+def test_compile_phases_count_each_second_once():
+    """jax opens a phase with a scalar and closes it with a duration: one inside
+    another (a jitted function traced inside a trace, an eager op compiled
+    during it) takes its seconds out of the outer one's."""
+    from jax import monitoring
+
+    from replay_tpu.obs.trace import claim_chunk, stage
+
+    record = claim_chunk(-1)
+    with stage("dispatch", chunk=-1) as dispatch:
+        monitoring.record_scalar(TRACE, 100.0)
+        monitoring.record_scalar(TRACE, 100.1)
+        monitoring.record_event_duration_secs(TRACE, 0.25)  # the inner jit's trace
+        monitoring.record_scalar(BACKEND, 100.5)
+        monitoring.record_event_duration_secs(BACKEND, 0.5)  # an eager op's program
+        monitoring.record_event_duration_secs(TRACE, 2.0)
+    assert (record["compile_trace_s"], record["compile_backend_s"]) == (1.5, 0.5)
+    assert record["compile_programs"] == 1 and dispatch.compile_seconds == 2.0
+
+
+def test_the_listeners_are_registered_once_a_process():
+    import jax
+    from jax._src import monitoring as registry
+
+    from replay_tpu.obs import trace
+
+    with trace.stage("dispatch"):  # a stage that finds jax imported registers them
+        pass
+    trace._listen(jax.monitoring)
+    trace._listen(jax.monitoring)
+    assert registry._event_listeners.count(trace._on_cache_event) == 1
+    assert registry._event_duration_secs_listeners.count(trace._on_phase_seconds) == 1
+    assert registry._scalar_listeners.count(trace._on_phase_start) == 1
+    trace.claim_chunk(-1)
+    jax.monitoring.record_event(CACHE_MISS)
+    assert trace.claim_chunk(-2)["compile_cache_misses"] == 1
+
+
+def test_what_runs_outside_a_chunk_is_the_next_fits_start_up_record(monkeypatch):
+    """Stages and compile events outside any chunk go to the record of the next
+    ``fit`` call, a chunk's stages do not; a reader also gets what ran since."""
+    from jax import monitoring
+
+    from replay_tpu.obs import trace
+    from replay_tpu.obs.trace import ChunkStages, chunk_stage_log, stage, startup_log
+
+    ChunkStages()  # what this thread did before is an earlier fit's
+    monkeypatch.setattr(trace, "_PACKAGE_IMPORTS", [])
+    now = time.perf_counter()
+    trace.package_imported("inner.package", now - 1.0)  # imported while the outer was
+    trace.package_imported("outer.package", now - 3.0)
+    with stage("init_state"):
+        with stage("inner"):  # a stage inside a stage: both by name
+            monitoring.record_event_duration_secs(BACKEND, 0.5)
+        monitoring.record_event(CACHE_MISS)
+    assert startup_log()[-1]["init_state"] > 0 and startup_log()[-1]["fit"] is None
+
+    stages = ChunkStages()
+    for _ in range(2):
+        with stages.stage("dispatch") as dispatch:
+            pass
+        with stages.stage("device_wait") as device_wait:
+            pass
+        stages.synced(1, dispatch, device_wait, compiled=False)
+    stages.close()
+    with stage("tokenize"):
+        pass
+
+    *_, started, since = startup_log()
+    assert started["fit"] == stages.fit and since["fit"] is None
+    assert started["pkg_import"] == pytest.approx(3.0, abs=0.01)
+    assert started["pkg_import_by_name"]["inner.package"] == pytest.approx(1.0, abs=0.01)
+    assert started["pkg_import_by_name"]["outer.package"] == pytest.approx(2.0, abs=0.01)
+    assert started["init_state"] >= started["inner"] > 0
+    assert (started["compile_programs"], started["compile_backend_s"]) == (1, 0.5)
+    assert started["compile_cache_misses"] == 1
+    assert not {"dispatch", "device_wait", "tokenize"} & set(started)
+    # after the fit: its last `account`, and the stage that followed
+    assert "tokenize" in since and "init_state" not in since and "dispatch" not in since
+    for record in chunk_stage_log()[-2:]:
+        assert record["fit"] == stages.fit
+        assert not {"init_state", "pkg_import", "tokenize"} & set(record)
+        assert record["compile_programs"] == 0
