@@ -23,9 +23,20 @@ expert (those of absent experts last), the rows of the held ones gathered into
 one ``[T * k, d]`` buffer, and each expert multiplies its own contiguous group of
 rows (:func:`grouped_matmul`). The buffer has room for every assignment, so
 however uneven the routing, nothing is dropped. Only the products follow the
-rows that are live: the gather into the buffer, the gather back and their
-transposes move all ``T * k`` rows whatever share of them is held here, and at
-an eighth held they cost several times the products (PERF.md, section 5).
+rows that are live: the two row movements take all ``T * k`` rows whatever share
+of them is held here (PERF.md, section 5). Rows travel by GATHERS in both
+directions: the sort ``order`` and its inverse are made once, and each movement
+has a hand-written transpose (:func:`dispatch_rows`, :func:`combine_rows`), which
+is the other movement. Forward, the buffer reads ``tokens[order // k]``, and the
+way back gives token ``t`` the weighted sum of its own ``k`` buffer rows
+``mixed[inverse[t * k + j]]``. Backward, the buffer's cotangent reads
+``d_out[order // k]`` times its weight (a permutation: every row written once,
+nothing added), and a token's cotangent is the sum of its own ``k`` rows of the
+buffer's cotangent, masked by which assignments are held here. Both sums over
+``k`` are accumulated in float32 and cast once. Left to itself JAX transposes a
+gather into a scatter-add, which cannot know that ``inverse`` is a permutation,
+serialises over the rows (three times a gather's time on the TPU) and sums in
+bfloat16.
 ``expert_load`` (assignments per held expert) and ``dropped_assignments`` (held
 assignments whose buffer row lies outside their expert's group of rows, so that
 another expert's kernel, or none, would multiply them: 0 unless the sort, the
@@ -88,6 +99,84 @@ def route_softmax(logits: jnp.ndarray, top_k: int):
     a softmax over ALL experts, the ``top_k`` largest, renormalised to sum to 1."""
     weights, selected = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     return selected, weights / jnp.sum(weights, axis=-1, keepdims=True)
+
+
+@jax.custom_vjp
+def dispatch_rows(
+    tokens: jnp.ndarray, order: jnp.ndarray, inverse: jnp.ndarray, here: jnp.ndarray
+) -> jnp.ndarray:
+    """The expert buffer [T * k, d]: row ``r`` holds the token of assignment
+    ``order[r]`` (assignment ``t * k + j`` is token ``t``'s ``j``-th choice) where
+    that assignment is held ``here`` [T, k] (those sort first), zeros after them.
+    ``inverse`` is the inverse permutation of ``order``, used by the transpose:
+    ``d_tokens[t] = sum_j here[t, j] * d_rows[inverse[t * k + j]]``, gathers and a
+    float32 sum over ``k``. The mask is ``here`` and not the cotangent's content, so
+    whatever a dead row's cotangent holds reaches no token."""
+    del inverse
+    live = jnp.arange(order.shape[0]) < jnp.sum(here)
+    rows = tokens[order // here.shape[1]]
+    return jnp.where(live[:, None], rows, jnp.zeros((), tokens.dtype))
+
+
+def _dispatch_rows_fwd(tokens, order, inverse, here):
+    return dispatch_rows(tokens, order, inverse, here), (inverse, here)
+
+
+def _own_rows(rows: jnp.ndarray, inverse: jnp.ndarray, k: int):
+    """``k`` arrays [T, d]: the ``j``-th holds the buffer row of every token's
+    ``j``-th choice. ``k`` gathers of ``T`` rows and not one of ``T * k`` reshaped to
+    [T, k, d]: on the TPU that reshape is a copy of the whole buffer (``k`` lands in
+    a tiled axis) and a sum over it a second pass; these fuse with what reads them."""
+    row_of = inverse.reshape(-1, k)
+    return [rows[row_of[:, j]] for j in range(k)]
+
+
+def _dispatch_rows_bwd(saved, d_rows):
+    inverse, here = saved
+    d_tokens = jnp.zeros((here.shape[0], d_rows.shape[-1]), jnp.float32)
+    for j, own in enumerate(_own_rows(d_rows, inverse, here.shape[1])):
+        d_tokens += jnp.where(here[:, j, None], own, jnp.zeros((), own.dtype)).astype(jnp.float32)
+    return d_tokens.astype(d_rows.dtype), None, None, None
+
+
+dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(
+    mixed: jnp.ndarray, share: jnp.ndarray, order: jnp.ndarray, inverse: jnp.ndarray
+) -> jnp.ndarray:
+    """The way back, [T, d]: ``out[t] = sum_j share[t, j] * mixed[inverse[t * k + j]]``
+    with ``share`` [T, k] float32 (0 for an assignment that is not here), summed in
+    float32 and cast once. It is the dispatch's transpose with weights for a mask,
+    and its own transpose is the dispatch again:
+    ``d_mixed[r] = share.flat[order[r]] * d_out[order[r] // k]``, one gather of the
+    small [T, d] cotangent, every buffer row written once and nothing added;
+    ``d_share[t, j] = <d_out[t], mixed[inverse[t * k + j]]>`` from the rows the
+    forward pass gathered."""
+    return _combine_rows_fwd(mixed, share, order, inverse)[0]
+
+
+def _combine_rows_fwd(mixed, share, order, inverse):
+    own = _own_rows(mixed, inverse, share.shape[1])
+    out = jnp.zeros(own[0].shape, jnp.float32)
+    for j, rows in enumerate(own):
+        out += rows.astype(jnp.float32) * share[:, j, None]
+    return out.astype(mixed.dtype), (own, share, order)
+
+
+def _combine_rows_bwd(saved, d_out):
+    own, share, order = saved
+    spread = d_out[order // share.shape[1]].astype(jnp.float32)  # [T * k, d]
+    d_mixed = (spread * share.reshape(-1)[order][:, None]).astype(d_out.dtype)
+    d_wide = d_out.astype(jnp.float32)
+    d_share = jnp.stack(
+        [jnp.sum(d_wide * rows.astype(jnp.float32), axis=-1) for rows in own], axis=1
+    )
+    return d_mixed, d_share, None, None
+
+
+combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 def unserved(slot: jnp.ndarray, held_here: jnp.ndarray, row: jnp.ndarray, group_sizes: jnp.ndarray):
@@ -167,8 +256,9 @@ class SparseExperts(nn.Module):
             group_sizes = jnp.sum(
                 key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32
             )
-            live = jnp.arange(count * k) < jnp.sum(group_sizes)
-            rows = jnp.where(live[:, None], tokens[order // k], jnp.zeros((), tokens.dtype))
+            # the way back's permutation, made once: the dispatch's transpose reads by it too
+            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(count * k))  # [T * k] rows
+            rows = dispatch_rows(tokens, order, inverse, here)
 
         with jax.named_scope("experts"):
             cast = lambda w: w.astype(self.dtype)  # noqa: E731
@@ -178,13 +268,10 @@ class SparseExperts(nn.Module):
             mixed = grouped_matmul(hidden.astype(self.dtype), cast(out_kernel), group_sizes)
 
         with jax.named_scope("combine"):
-            # back to assignment order by the inverse permutation; the rows of
-            # assignments that are not here are zeros and weigh nothing
-            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(count * k))  # [T * k] rows
-            per_choice = mixed[inverse].reshape(count, k, dim)
+            # each token's weighted sum of its own k buffer rows, found by the inverse
+            # permutation; the rows of assignments that are not here weigh nothing
             share = jnp.where(here, weights, 0.0)  # float32, as the router made them
-            out = jnp.sum(per_choice.astype(jnp.float32) * share[..., None], axis=1)
-            out = out.astype(mixed.dtype)
+            out = combine_rows(mixed, share, order, inverse)
 
         latest = {"reduce_fn": lambda _, new: new, "init_fn": lambda: None}  # one value a step
         self.sow("counters", "expert_load", group_sizes, **latest)
