@@ -298,7 +298,8 @@ class GroupedQueryAttention(nn.Module):
     """Self-attention with fewer key/value heads than query heads, rotary
     positions and an RMS norm over each head's width on q and k (bias-free
     projections; the attention layer of the layer-pattern block stack,
-    replay_tpu.nn.blocks). Positions are the indices in the window: rotary
+    replay_tpu.nn.blocks); ``qk_norm=False`` leaves both norms out (the
+    ``ouro`` configuration carries none). Positions are the indices in the window: rotary
     scores depend on their differences only, so left padding shifts nothing.
     ``rope_scaling``: the layer type's ``rope_parameters`` (``rope_type`` ``yarn``
     with its factor, original length, betas and attention factor; ``None``: the
@@ -324,6 +325,7 @@ class GroupedQueryAttention(nn.Module):
     dtype: Any = jnp.float32
     window: Optional[int] = None
     rope_scaling: Optional[Mapping[str, Any]] = None
+    qk_norm: bool = True
 
     @nn.compact
     def __call__(
@@ -335,8 +337,11 @@ class GroupedQueryAttention(nn.Module):
             proj = nn.Dense(count * self.head_dim, use_bias=False, dtype=self.dtype, name=name)(x)
             return proj.reshape(*x.shape[:-1], count, self.head_dim)
 
-        q = RMSNorm(self.norm_eps, dtype=self.dtype, name="q_norm")(heads_of("query", self.num_heads))
-        k = RMSNorm(self.norm_eps, dtype=self.dtype, name="k_norm")(heads_of("key", self.num_kv_heads))
+        def normed(name, t):
+            return RMSNorm(self.norm_eps, dtype=self.dtype, name=name)(t) if self.qk_norm else t
+
+        q = normed("q_norm", heads_of("query", self.num_heads))
+        k = normed("k_norm", heads_of("key", self.num_kv_heads))
         v = heads_of("value", self.num_kv_heads)
         positions = jnp.arange(length)
         q, k, v = (t.swapaxes(-3, -2) for t in (q, k, v))  # [B, H, L, D]

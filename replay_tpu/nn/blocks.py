@@ -2,10 +2,14 @@
 
 A block is (mixer kind, feed-forward kind) around a pre-norm residual stream:
 
-    h = x + mixer(rms(x))          mixer: "full_attention" | "sliding_attention" | "conv"
+    h = x + post(mixer(rms(x)))    mixer: "full_attention" | "sliding_attention" | "conv"
                                           | "latent_attention"
-    y = h + ffn(rms(h))            ffn:   dense SwiGLU | sparse experts [+ shared expert]
+    y = h + post(ffn(rms(h)))      ffn:   dense SwiGLU | sparse experts [+ shared expert]
 
+where ``post`` is the identity, or with ``sandwich`` an RMSNorm of its own after
+each sublayer (``mixer_post_norm``, ``ffn_post_norm``: the "sandwich" norms of the
+public ``ouro`` configuration, which also runs the whole stack several times over
+one set of weights: replay_tpu.nn.sequential.hybrid.model, ``loop_steps``),
 and a model is a list of mixer kinds (``layer_types``) with the number of
 leading layers whose feed-forward is dense (``num_dense_layers``, which may be
 0); every later layer routes over sparse experts. ``sliding_attention`` is
@@ -31,6 +35,11 @@ the positions it multiplied (``shared_expert_tokens`` in ``counters``).
 Padding: the stream is zero at padding positions on entry and is zeroed there
 again after every block, so a mixer never reads them (``rms(0) = 0``; attention
 masks them as keys besides) and the expert layer leaves them out of its dispatch.
+
+``remat`` recomputes each block from its input on the way back (one
+``jax.checkpoint`` a block, ``remat_policy`` choosing what it keeps:
+``Trainer(remat_policy=...)``): the stream at each block's input is what a step
+keeps of the stack.
 
 Each layer kind runs under a ``jax.named_scope`` of its own (``attention``,
 ``window_attention``, ``latent_attention``, ``conv``, ``dense_ffn``, ``moe`` and,
@@ -88,6 +97,8 @@ class PatternBlock(nn.Module):
     rope_head_dim: Optional[int] = None  # latent_attention: the rotary part of q and k
     value_head_dim: Optional[int] = None  # latent_attention: a value head (None: head_dim)
     shared_expert_dim: int = 0  # 0: a sparse layer is its routed experts alone
+    sandwich: bool = False  # an RMSNorm after each sublayer too (module docstring)
+    qk_norm: bool = True  # attention layers: an RMSNorm over each head of q and k
 
     @nn.compact
     def __call__(self, x, attention_mask, padding_mask):
@@ -104,7 +115,7 @@ class PatternBlock(nn.Module):
                     head_dim=self.head_dim, rope_theta=self.rope_theta,
                     norm_eps=self.norm_eps, dtype=self.dtype,
                     window=self.sliding_window if sliding else None,
-                    rope_scaling=self.rope_scaling, name="attention",
+                    rope_scaling=self.rope_scaling, qk_norm=self.qk_norm, name="attention",
                 )(h, None if sliding or self.fused_attention else attention_mask, padding_mask)
         elif self.mixer == "latent_attention":
             if not self.kv_latent_dim or not self.rope_head_dim:
@@ -124,6 +135,8 @@ class PatternBlock(nn.Module):
         else:
             msg = f"unknown layer type {self.mixer!r}; known: {MIXERS}"
             raise ValueError(msg)
+        if self.sandwich:
+            h = norm("mixer_post_norm")(h)
         x = x + h
         h = norm("ffn_norm")(x)
         if self.sparse:
@@ -147,6 +160,8 @@ class PatternBlock(nn.Module):
         else:
             with jax.named_scope("dense_ffn"):
                 h = SwiGLU(self.dense_dim, x.shape[-1], dtype=self.dtype, name="dense_ffn")(h)
+        if self.sandwich:
+            h = norm("ffn_post_norm")(h)
         keep = padding_mask[..., None].astype(x.dtype)
         return shard_activation((x + h) * keep, "batch", "length", "embed")
 
@@ -179,12 +194,17 @@ class LayerPatternEncoder(nn.Module):
     rope_head_dim: Optional[int] = None
     value_head_dim: Optional[int] = None
     shared_expert_dim: int = 0
+    sandwich: bool = False
+    qk_norm: bool = True
+    remat: bool = False  # recompute each block from its input on the way back
+    remat_policy: Any = None  # what a block's checkpoint keeps (None: nothing)
 
     @nn.compact
     def __call__(self, x, attention_mask, padding_mask):
         held = self.num_experts if self.experts_held is None else self.experts_held
+        block = nn.remat(PatternBlock, policy=self.remat_policy) if self.remat else PatternBlock
         for i, mixer in enumerate(self.layer_types):
-            x = PatternBlock(
+            x = block(
                 mixer=mixer, sparse=i >= self.num_dense_layers,
                 num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
                 head_dim=self.head_dim, rope_theta=self.rope_theta,
@@ -197,6 +217,6 @@ class LayerPatternEncoder(nn.Module):
                 rope_scaling=(self.rope_scaling or {}).get(mixer),
                 kv_latent_dim=self.kv_latent_dim, rope_head_dim=self.rope_head_dim,
                 value_head_dim=self.value_head_dim, shared_expert_dim=self.shared_expert_dim,
-                name=f"layer_{i}",
+                sandwich=self.sandwich, qk_norm=self.qk_norm, name=f"layer_{i}",
             )(x, attention_mask, padding_mask)
         return x

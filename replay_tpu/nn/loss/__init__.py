@@ -1,6 +1,15 @@
 from .base import LossBase, broadcast_negatives, mask_negative_logits, masked_mean
 from .bce import BCE, BCESampled, GBCE
-from .ce import CE, CEFused, CEFusedTP, CESampled, CESampledWeighted, CEWeighted
+from .ce import (
+    CE,
+    CEFused,
+    CEFusedTP,
+    CESampled,
+    CESampledWeighted,
+    CEWeighted,
+    ExitWeightedCE,
+    exit_distribution,
+)
 from .login_ce import LogInCE, LogInCESampled
 from .logout_ce import LogOutCE, LogOutCEWeighted
 from .sce import SCE, ScalableCrossEntropyLoss, SCEParams
@@ -22,6 +31,7 @@ __all__ = [
     "GBCE",
     "CESampledWeighted",
     "CEWeighted",
+    "ExitWeightedCE",
     "LogInCE",
     "LogInCESampled",
     "LogOutCE",
@@ -33,6 +43,7 @@ __all__ = [
     "SCEParams",
     "ScalableCrossEntropyLoss",
     "broadcast_negatives",
+    "exit_distribution",
     "mask_negative_logits",
     "masked_mean",
 ]

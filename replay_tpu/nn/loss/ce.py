@@ -192,6 +192,20 @@ class CE(LossBase):
             vocab_axis=self.axis_name,
         )
 
+    def position_nll(self, model_embeddings, positive_labels):
+        """``(labels [B, L], nll [B, L])``: the label's negative log-likelihood at
+        every position, by the route :func:`full_softmax_route` chooses (sets
+        :attr:`route`); no mask, no mean."""
+        if positive_labels.shape[-1] != 1:
+            msg = "Multi-positive labels are not supported by the CE loss"
+            raise NotImplementedError(msg)
+        self.route = self._choose_route(model_embeddings, positive_labels.shape[-1])
+        if self.route == PLAIN:
+            logits = self.logits_callback(model_embeddings)  # [B, L, I]
+            labels = jnp.clip(positive_labels[..., 0], 0, logits.shape[-1] - 1)
+            return labels, _softmax_nll(logits, labels)
+        return self._fused_nll(model_embeddings, positive_labels)
+
     def __call__(
         self,
         model_embeddings,
@@ -201,16 +215,7 @@ class CE(LossBase):
         padding_mask,
         target_padding_mask,
     ) -> jnp.ndarray:
-        if positive_labels.shape[-1] != 1:
-            msg = "Multi-positive labels are not supported by the CE loss"
-            raise NotImplementedError(msg)
-        self.route = self._choose_route(model_embeddings, positive_labels.shape[-1])
-        if self.route == PLAIN:
-            logits = self.logits_callback(model_embeddings)  # [B, L, I]
-            labels = jnp.clip(positive_labels[..., 0], 0, logits.shape[-1] - 1)
-            nll = _softmax_nll(logits, labels)
-        else:
-            labels, nll = self._fused_nll(model_embeddings, positive_labels)
+        labels, nll = self.position_nll(model_embeddings, positive_labels)
         weights = self._label_weights(labels, nll.dtype)
         mask = target_padding_mask[..., 0].astype(nll.dtype) * weights
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
@@ -369,6 +374,82 @@ class CEFusedTP(CEFused):
         super().__init__(tile, item_tile, interpret)
         self.axis_name = axis_name
         self.data_axis = data_axis
+
+
+def exit_distribution(gate_logits: jnp.ndarray) -> jnp.ndarray:
+    """``p`` [T, ...] from the exit gates' logits [T, ...]: the chance that the
+    loop stops after step t, ``lambda_t prod_{j<t} (1 - lambda_j)`` with
+    ``lambda = sigmoid(logits)``, and all that is left at the last step
+    (``prod_{j<T} (1 - lambda_j)``; the last gate is never read). Sums to 1."""
+    stops = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    steps, survived, out = gate_logits.shape[0], jnp.ones_like(stops[0]), []
+    for t in range(steps - 1):
+        out.append(stops[t] * survived)
+        survived = survived * (1.0 - stops[t])
+    return jnp.stack(out + [survived])
+
+
+class ExitWeightedCE(CE):
+    """Full-softmax CE at EVERY exit of a looped model, weighted by the exit
+    gate's distribution, minus ``entropy_weight`` times that distribution's
+    entropy (the entropy-regularised objective of "Scaling Latent Reasoning via
+    Looped Language Models", 2025; the model: ``HybridRec(loop_steps=T)``,
+    T > 1). Per valid target, in float32:
+
+        loss = mean [ sum_t p(t) * CE(h_t . table^T, y)  -  beta * H(p) ]
+
+    ``p`` from :func:`exit_distribution`. The trainer binds :attr:`exits` (the
+    model's sown ``hidden`` [T, B, L, d] and ``gate_logits`` [T, B, L]); each
+    exit's head takes :class:`CE`'s route (:meth:`CE.position_nll`, under the
+    scope ``exit_head``), so a narrow model gets the fused head at every exit.
+    The hidden states handed as ``model_embeddings`` (the last step's) are not
+    read. ``step_counters`` (the trainer folds them into the step's
+    ``counters``): ``exit_mass`` [T], the mean of ``p(t)`` over the valid
+    targets, and ``exit_loss`` [T], each exit's mean CE there.
+    """
+
+    sows_counters = True
+
+    def __init__(self, entropy_weight: float = 0.1) -> None:
+        super().__init__()
+        self.entropy_weight = entropy_weight
+        self.exits = None  # bound by the trainer for a model that sows them
+        self.step_counters = {}
+
+    def __call__(
+        self,
+        model_embeddings,
+        feature_tensors,
+        positive_labels,
+        negative_labels,
+        padding_mask,
+        target_padding_mask,
+    ) -> jnp.ndarray:
+        if self.exits is None:
+            msg = (
+                "ExitWeightedCE needs the exits of a looped model: train "
+                "HybridRec(loop_steps > 1) through replay_tpu.nn.Trainer, which "
+                "binds loss.exits"
+            )
+            raise AttributeError(msg)
+        hidden = self.exits["hidden"]
+        losses = []
+        for t in range(hidden.shape[0]):
+            with jax.named_scope("exit_head"):
+                labels, nll = self.position_nll(hidden[t], positive_labels)
+            losses.append(nll.astype(jnp.float32))
+        nll = jnp.stack(losses)  # [T, B, L]
+        mask = target_padding_mask[..., 0].astype(jnp.float32)
+        mask = mask * self._label_weights(labels, jnp.float32)
+        count = jnp.maximum(jnp.sum(mask), 1.0)
+        p = exit_distribution(self.exits["gate_logits"])
+        entropy = -jnp.sum(p * jnp.log(jnp.maximum(p, jnp.finfo(jnp.float32).tiny)), axis=0)
+        per_target = jnp.sum(p * nll, axis=0) - self.entropy_weight * entropy
+        self.step_counters = {
+            "exit_mass": jnp.sum(p * mask, axis=(1, 2)) / count,
+            "exit_loss": jnp.sum(nll * mask, axis=(1, 2)) / count,
+        }
+        return jnp.sum(per_target * mask) / count
 
 
 class CEWeighted(CE):

@@ -34,6 +34,29 @@ that Moonlight publishes (https://huggingface.co/moonshotai/Moonlight-16B-A3B/bl
 ``value_head_dim`` (``v_head_dim``), one leading dense layer, the sigmoid router
 with ``routed_scale``, and ``shared_expert_dim``: a dense SwiGLU beside the
 routed share of every sparse layer (replay_tpu.nn.blocks).
+
+And the looped stack of the public ``ouro`` configuration
+(https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/config.json, ``total_ut_steps``):
+``loop_steps`` T applies the SAME stack T times, the final norm closing every
+step and its output carried into the next, and after each step an exit gate (a
+``Dense(d -> 1)`` with bias, in float32, named ``exit_gate``) scores how likely
+the model is to stop there; with it ``sandwich_norms`` (an RMSNorm after each
+sublayer too) and ``qk_norm=False``:
+
+    h0 = table[items] * keep
+    for t in 1..T:  h_t = rms(LayerPatternEncoder(h_{t-1}));  g_t = w . h_t + b
+    lambda_t = sigmoid(g_t);  p(t) = lambda_t prod_{j<t} (1 - lambda_j),  p(T) = prod_{j<T} (1 - lambda_j)
+
+The steps are ONE ``nn.scan`` body over the broadcast parameters (the paths stay
+``encoder/...``, ``final_norm``, ``exit_gate``), under the scopes ``recurrence``
+(the stack) and ``exit_gate`` (final norm and gate). The training forward returns
+h_T, as inference and predict read it; it also sows ``exits``
+(``hidden`` [T, B, L, d], ``gate_logits`` [T, B, L] in float32) for the loss that
+weights a loss at every exit (replay_tpu.nn.loss.ExitWeightedCE; ``Trainer``
+binds them), and ``loop_layer_applications`` into ``counters``: the blocks the
+scan applied, a step. ``remat`` recomputes each block application from its input
+on the way back (``Trainer(remat_policy=...)``). ``loop_steps`` 1 (the default)
+is the one-pass model: no scan, no gate, its program and parameter paths.
 """
 
 from __future__ import annotations
@@ -89,9 +112,19 @@ class HybridRec(nn.Module):
     value_head_dim: Optional[int] = None
     shared_expert_dim: int = 0  # 0: no shared expert beside the routed ones
     tie_embeddings: bool = True
+    loop_steps: int = 1  # T: passes of the whole stack over one set of weights, each gated
+    sandwich_norms: bool = False  # an RMSNorm after each sublayer too (replay_tpu.nn.blocks)
+    qk_norm: bool = True
+    remat: bool = False  # one checkpoint per block application (Trainer(remat_policy=...))
+    remat_policy: Any = None
     excluded_features: tuple = ()
     dtype: Any = jnp.float32
     embedding_init: Any = None
+
+    @property
+    def sows_exits(self) -> bool:
+        """Whether the training forward sows ``exits`` for the loss (it loops)."""
+        return self.loop_steps > 1
 
     def setup(self) -> None:
         self.embedder = SequenceEmbedding(
@@ -112,9 +145,12 @@ class HybridRec(nn.Module):
             fused_attention=self.fused_attention, rope_scaling=self.rope_scaling,
             kv_latent_dim=self.kv_latent_dim, rope_head_dim=self.rope_head_dim,
             value_head_dim=self.value_head_dim, shared_expert_dim=self.shared_expert_dim,
-            name="encoder",
+            sandwich=self.sandwich_norms, qk_norm=self.qk_norm, remat=self.remat,
+            remat_policy=self.remat_policy, name="encoder",
         )
         self.final_norm = RMSNorm(self.norm_eps, dtype=self.dtype, name="final_norm")
+        if self.loop_steps > 1:
+            self.gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate")
         self.head = EmbeddingTyingHead()
         if not self.tie_embeddings:
             items = self.schema[self.schema.item_id_feature_name].cardinality
@@ -135,9 +171,34 @@ class HybridRec(nn.Module):
             mask = None
             if needs_mask(self.layer_types, self.fused_attention):
                 mask = causal_attention_mask(padding_mask, dtype=self.dtype)
+            if self.loop_steps > 1:
+                return self._loop(x, mask, padding_mask)
             x = self.encoder(x, mask, padding_mask)
         with jax.named_scope("final_norm"):
             return shard_activation(self.final_norm(x), "batch", "length", "embed")
+
+    def _loop(self, x, mask, padding_mask):
+        """``loop_steps`` passes as ONE scanned body (module docstring)."""
+
+        def one_step(model, carry, _):
+            h, applied = carry
+            with jax.named_scope("recurrence"):
+                u = model.encoder(h, mask, padding_mask)
+            with jax.named_scope("exit_gate"):
+                out = shard_activation(model.final_norm(u), "batch", "length", "embed")
+                gate = model.gate(out.astype(jnp.float32))[..., 0]
+            return (out, applied + len(model.layer_types)), (out, gate)
+
+        steps = nn.scan(
+            one_step, variable_broadcast="params", split_rngs={"params": False},
+            variable_axes={"counters": 0}, length=self.loop_steps,
+        )
+        (last, applied), (hidden, gate_logits) = steps(self, (x, jnp.int32(0)), None)
+        latest = {"reduce_fn": lambda _, new: new, "init_fn": lambda: None}  # one value a step
+        self.sow("counters", "loop_layer_applications", applied, **latest)
+        self.sow("exits", "hidden", hidden, **latest)
+        self.sow("exits", "gate_logits", gate_logits, **latest)
+        return last
 
     def get_logits(
         self, hidden: jnp.ndarray, candidates_to_score: Optional[jnp.ndarray] = None

@@ -565,7 +565,8 @@ class Trainer:
         ``shard_vocab``. EVERY placement (params, optimizer state, batches,
         activation constraints, the CEFusedTP table layout) derives from this
         one table (docs/distributed_and_serving.md "One rule table").
-    :param remat_policy: activation checkpointing for the encoder stack:
+    :param remat_policy: activation checkpointing for the encoder stack (one
+        checkpoint a block; a looped HybridRec's a block application):
         ``None`` (off) / ``"full"`` (save nothing across blocks) / ``"dots"``
         (save MXU outputs only) / ``"dots_no_batch"`` / a
         ``jax.checkpoint_policies`` callable. The model is cloned with
@@ -656,8 +657,8 @@ class Trainer:
             if not hasattr(self.model, "remat"):
                 msg = (
                     f"remat_policy={self.remat_policy!r} needs a model with a "
-                    f"remat field (SasRec/Bert4Rec); {type(self.model).__name__} "
-                    "has none"
+                    f"remat field (SasRec, Bert4Rec, HybridRec); "
+                    f"{type(self.model).__name__} has none"
                 )
                 raise ValueError(msg)
             policy = _resolve_remat_policy(self.remat_policy)
@@ -923,9 +924,17 @@ class Trainer:
         pad_f = self.padding_mask_field
         # python-static, like `health`: which collections the forward hands back
         counts = bool(getattr(model, "sows_counters", False))
-        collected = ["counters"] * counts + ["intermediates"] * bool(
+        # a looped model's per-step hidden states and exit-gate logits (HybridRec
+        # with ``loop_steps`` > 1): sown into `exits` and bound to the loss, which
+        # weights a loss at every exit (nn.loss.ExitWeightedCE)
+        exits = bool(getattr(model, "sows_exits", False))
+        # a loss that counts (ExitWeightedCE: per-exit mass and loss) hands its
+        # counters over after its call; they join the model's in the step metrics
+        loss_counts = bool(getattr(loss, "sows_counters", False))
+        collected = ["counters"] * counts + ["exits"] * exits + ["intermediates"] * bool(
             health is not None and health.capture_intermediates
         )
+        with_aux = bool(collected) or health is not None or loss_counts
 
         # `health` branches below are python-static (resolved at trace time,
         # like the models' sow guards): health=None lowers to byte-identical
@@ -980,6 +989,8 @@ class Trainer:
                         )
                         intermediates = variables.get("intermediates", {})
                         counters = variables.get("counters", {})
+                        if exits:
+                            loss.exits = variables["exits"]
                     else:
                         hidden = model.apply(
                             {"params": params}, rngs={"dropout": dropout_rng}, **kwargs
@@ -1014,16 +1025,17 @@ class Trainer:
                         batch[pad_f],
                         target_mask,
                     )
-                if not collected and health is None:
+                if not with_aux:
                     return loss_value
-                return loss_value, (hidden, intermediates, counters)
+                counted = dict(loss.step_counters) if loss_counts else {}
+                return loss_value, (hidden, intermediates, counters, counted)
 
-            if not collected and health is None:
+            if not with_aux:
                 loss_value, grads = jax.value_and_grad(loss_fn)(state.params)
             else:
-                (loss_value, (hidden, intermediates, counters)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(state.params)
+                (loss_value, (hidden, intermediates, counters, loss_counters)), grads = (
+                    jax.value_and_grad(loss_fn, has_aux=True)(state.params)
+                )
             # non-finite sentinel: one fused flag decides, in-jit, whether this
             # update may touch the state. A NaN/Inf loss or gradient norm keeps
             # the previous params/opt_state (jnp.where select — no host round
@@ -1035,8 +1047,8 @@ class Trainer:
             params = optax.apply_updates(state.params, updates)
 
             metrics = {"loss": loss_value, "good": good, "grad_norm": grad_norm}
-            if counts:
-                metrics["counters"] = _fold_counters(counters)
+            if counts or loss_counts:
+                metrics["counters"] = {**_fold_counters(counters), **loss_counters}
             if health is not None:
                 logits = None
                 streamed_stats = None

@@ -113,6 +113,40 @@ def test_fused_route_matches_the_plain_route(monkeypatch, make_loss):
     np.testing.assert_allclose(got_dw, want_dw, rtol=1e-4, atol=1e-6)
 
 
+def parents_ce(loss, model_embeddings, positive_labels, target_padding_mask):
+    """``CE.__call__`` as it read before ``position_nll`` was factored out of it."""
+    loss.route = loss._choose_route(model_embeddings, positive_labels.shape[-1])
+    if loss.route == PLAIN:
+        logits = loss.logits_callback(model_embeddings)
+        labels = jnp.clip(positive_labels[..., 0], 0, logits.shape[-1] - 1)
+        nll = ce._softmax_nll(logits, labels)
+    else:
+        labels, nll = loss._fused_nll(model_embeddings, positive_labels)
+    weights = loss._label_weights(labels, nll.dtype)
+    mask = target_padding_mask[..., 0].astype(nll.dtype) * weights
+    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+@pytest.mark.parametrize("on_a_tpu", [False, True], ids=["plain", "fused"])
+def test_factoring_out_the_per_position_nll_leaves_ces_program_as_it_was(monkeypatch, on_a_tpu):
+    hidden, table, labels, mask = head_inputs()
+    loss = as_on_a_tpu(monkeypatch, CE()) if on_a_tpu else CE()
+
+    def traced(call):
+        def f(hidden, table, labels, padding, mask):
+            loss.logits_callback = lambda h: jnp.einsum("...e,ie->...i", h, table)
+            loss.item_embeddings_callback = lambda: table
+            return call(hidden, labels, padding, mask)
+
+        args = (hidden, table, labels, mask[..., 0], mask)
+        return str(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1)))(*args))
+
+    now = traced(lambda h, labels, padding, mask: loss(h, {}, labels, None, padding, mask))
+    before = traced(lambda h, labels, padding, mask: parents_ce(loss, h, labels, mask))
+    assert loss.route == (FUSED if on_a_tpu else PLAIN)
+    assert now == before
+
+
 def test_without_a_bound_table_the_head_stays_plain_on_a_tpu(monkeypatch):
     loss = as_on_a_tpu(monkeypatch, CE())
     value_and_grads(loss, *head_inputs(), bind_table=False)
