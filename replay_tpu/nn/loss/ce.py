@@ -50,26 +50,27 @@ _softmax_nll.defvjp(_softmax_nll_fwd, _softmax_nll_bwd)
 
 # The routes of the full-softmax head. PLAIN writes the ``[B, L, I]`` logits
 # (``get_logits`` + :func:`_softmax_nll`); FUSED keeps them in VMEM
-# (``ops.fused_ce.fused_lse``: three Pallas kernels, the product formed again on the
-# way back); SHARDED is FUSED under the trainer's mesh, each device's rows (and
-# catalog shard) inside one ``shard_map`` (``parallel.sharded_ce.sharded_fused_lse``).
+# (``ops.fused_ce.fused_lse``: two Pallas kernels, two sweeps over the catalog: the
+# forward's also gives ``softmax · W`` for ``dh``, the backward's gives ``dW``);
+# SHARDED is FUSED under the trainer's mesh, each device's rows (and catalog shard)
+# inside one ``shard_map`` (``parallel.sharded_ce.sharded_fused_lse``).
 PLAIN, FUSED, SHARDED = "plain", "fused", "fused_sharded"
 
 # The widest embedding at which :class:`CE` takes the fused route. Both routes cost
 # ~ positions x items, so the width decides: the plain route's four passes over the
-# float32 logits do not grow with it, the fused route's five float32 products do (a
+# float32 logits do not grow with it, the fused route's four products do (a
 # contraction of up to 128 is one pass of the MXU, up to 256 two, 300 three).
-# TPU v5e, 25,600 positions x 27,278 items, SASRec's train_scan at that width, device
-# ms a step under the ``loss`` scope and its transpose (my chip runs, PR 34; PERF.md,
-# Findings PR 34; d 64 is the benchmark cell itself, d 128's fused reading the head alone):
+# TPU v5e, 25,600 positions x 27,278 items, each head alone (value and both gradients,
+# the label's logit and the mean included; 512 rows a program), ms a call, both rows
+# from one run (PERF.md, Findings):
 #
 #     width    64      128     192     256     300
-#     plain    16.46   16.44   16.39   15.73   15.77
-#     fused     6.54    6.58   12.13   12.84   17.90
+#     plain    15.61   15.75   15.86   16.00   16.22
+#     fused     5.41    5.47    9.90    9.92   14.20
 #
-# At 256 the fused route is still 18% under (2.9 ms of a 26.5 ms step, and 2.8 GB of
-# HBM it never holds); at 300 it loses by 13%. No width between was read, so the
-# limit stays at the last one measured to win.
+# The fused head is under the plain one at every width read, 300 included; the limit
+# stays at 256, the widest at which a whole training step has been measured to gain,
+# until a step at 300 is.
 FUSED_MAX_WIDTH = 256
 
 

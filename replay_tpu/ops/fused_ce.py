@@ -9,17 +9,23 @@ ML-20M scale it is gigabytes. This kernel computes
 online max/sum over catalog tiles, so neither axis is ever resident in full:
 HBM sees only the hidden states, the table, and one scalar per row.
 
-Training works through ``jax.custom_vjp`` with rematerialization: the forward
-saves only ``lse`` alongside the inputs, and two backward kernels recompute
-each ``[row_tile, item_tile]`` logits block on the fly —
+Training works through ``jax.custom_vjp`` with two sweeps over the catalog:
+the gradients need ``softmax @ W`` for ``dh`` and ``softmaxᵀ @ (g · h)`` for
+``dW``, and each sweep forms every ``[row_tile, item_tile]`` logits block once —
 
-- ``dh = (g · softmax) @ W`` gridded (rows, items) so the dh block accumulates
-  over the consecutive inner item axis;
-- ``dW = (g · softmax)ᵀ @ h`` gridded (items, rows) so the dW block accumulates
-  over the consecutive inner row axis.
+- the differentiated forward, gridded (rows, items), keeps beside the running
+  max/sum a ``[row_tile, E]`` accumulator of ``exp(logits − max) @ W_j`` (as
+  flash attention's forward keeps ``P @ V``), rescaled by the same
+  ``exp(m_old − m_new)`` as the sum, and writes
+  ``PW = acc / sum`` beside ``lse``; the backward's ``dh`` is then ``g · PW``,
+  one elementwise op on ``[N, E]``;
+- ``dW = softmaxᵀ @ (g · h)`` gridded (items, rows) so the dW block accumulates
+  over the consecutive inner row axis; the row weight ``g`` scales the
+  ``[row_tile, E]`` rows, not the logits block.
 
-(TPU pallas grids execute sequentially, which is what makes same-block
-accumulation across the inner axis well-defined.)
+An undifferentiated call runs the same forward and drops ``PW``. (TPU pallas
+grids execute sequentially, which is what makes same-block accumulation across
+the inner axis well-defined.)
 
 Two provisions for callers beyond the single-device case:
 
@@ -76,12 +82,15 @@ def _pad_to(value: int, multiple: int) -> int:
 
 
 def _working_set_bytes(tile: int, item_tile: int, embed: int) -> int:
-    """Estimated peak VMEM of one grid step of the WORST kernel (the
-    backwards): pipeline blocks (h/w/g/lse in, dh-or-dw out — double-buffered
-    by Mosaic) plus the f32 [tile, item_tile] logits intermediate (its
-    softmax-weighted successor reuses the buffer), all f32."""
-    blocks = 2 * (tile * embed + item_tile * embed) + 2 * tile
-    return 4 * (2 * blocks + tile * item_tile)
+    """Estimated peak VMEM of one grid step of the larger of the two kernels a
+    gradient runs, all f32: the pipeline's blocks, double-buffered by Mosaic
+    (forward: h, W in, lse and PW out; dW: h, W, g, lse in, dW out), what the
+    step keeps beside them (the forward's [tile, E] accumulator and max/sum
+    scratch, dW's g-weighted rows) and the [tile, item_tile] logits block (its
+    exp reuses the buffer)."""
+    forward = 2 * (2 * tile * embed + item_tile * embed + tile) + tile * embed + 2 * tile
+    dw = 2 * (tile * embed + 2 * item_tile * embed + 2 * tile) + tile * embed
+    return 4 * (max(forward, dw) + tile * item_tile)
 
 
 def _resolve_item_tile(num_items: int, item_tile, tile: int, embed: int) -> int:
@@ -124,25 +133,26 @@ def row_tile(rows: int, tile: Optional[int]) -> int:
     return min(_DEFAULT_ROWS, _pad_to(max(rows, 1), 8)) if tile is None else tile
 
 
-def _masked_logits(num_valid_ref, h_ref, w_ref, item_tile: int):
-    """One [T, item_tile] logits block with catalog padding masked to _MASK.
+def _masked_logits(num_valid_ref, h, w, item_block):
+    """One [T, item_tile] logits block of f32 rows ``h`` and catalog tile ``w``
+    (the ``item_block``-th), with catalog padding masked to _MASK.
 
     The mask is a [1, item_tile] row vector (a few KB) rather than a full-size
     iota compare, which would cost as much VMEM as the logits block itself.
     """
-    from jax.experimental import pallas as pl
-
-    h = h_ref[...].astype(jnp.float32)  # [T, E]
-    w = w_ref[...].astype(jnp.float32)  # [item_tile, E]
+    item_tile = w.shape[0]
     logits = jnp.dot(h, w.T, preferred_element_type=jnp.float32)
-    col = pl.program_id(1) * item_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (1, item_tile), 1
-    )
+    col = item_block * item_tile + jax.lax.broadcasted_iota(jnp.int32, (1, item_tile), 1)
     return logits + jnp.where(col < num_valid_ref[0], 0.0, _MASK).astype(jnp.float32)
 
 
-def _lse_kernel(num_valid_ref, h_ref, w_ref, lse_ref, m_ref, s_ref):
-    """Online logsumexp: running max/sum scratch across the inner item grid."""
+def _lse_kernel(num_valid_ref, h_ref, w_ref, lse_ref, pw_ref, m_ref, s_ref, acc_ref):
+    """Online logsumexp: running max/sum scratch across the inner item grid.
+
+    The exp block that feeds the sum also feeds ``acc += exp(logits − max) @ W_j``,
+    rescaled with the sum when the max moves; ``pw = acc / s`` is written beside
+    ``lse``.
+    """
     from jax.experimental import pallas as pl
 
     j, num_j = pl.program_id(1), pl.num_programs(1)
@@ -151,59 +161,37 @@ def _lse_kernel(num_valid_ref, h_ref, w_ref, lse_ref, m_ref, s_ref):
     def _reset():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         s_ref[...] = jnp.zeros_like(s_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    logits = _masked_logits(num_valid_ref, h_ref, w_ref, w_ref.shape[0])
+    w = w_ref[...].astype(jnp.float32)
+    logits = _masked_logits(num_valid_ref, h_ref[...].astype(jnp.float32), w, j)
     tile_max = jnp.max(logits, axis=-1, keepdims=True)  # finite even for a
     new_max = jnp.maximum(m_ref[...], tile_max)  # fully-masked tile (_MASK)
-    s_ref[...] = s_ref[...] * jnp.exp(m_ref[...] - new_max) + jnp.sum(
-        jnp.exp(logits - new_max), axis=-1, keepdims=True
-    )
+    rescale = jnp.exp(m_ref[...] - new_max)
+    probs = jnp.exp(logits - new_max)
+    s_ref[...] = s_ref[...] * rescale + jnp.sum(probs, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * rescale + jnp.dot(probs, w, preferred_element_type=jnp.float32)
     m_ref[...] = new_max
 
     @pl.when(j == num_j - 1)
     def _finalize():
         lse_ref[...] = m_ref[...] + jnp.log(s_ref[...])
-
-
-def _dh_kernel(num_valid_ref, h_ref, w_ref, g_ref, lse_ref, dh_ref):
-    """dh[i] = sum_j (g * softmax_block_j) @ W_j — inner item axis accumulates."""
-    from jax.experimental import pallas as pl
-
-    logits = _masked_logits(num_valid_ref, h_ref, w_ref, w_ref.shape[0])
-    weighted = jnp.exp(logits - lse_ref[...]) * g_ref[...].astype(jnp.float32)
-    # f32 accumulation across catalog tiles (dh_ref is f32; the caller casts to
-    # hidden.dtype once after the kernel, mirroring the dW path)
-    contrib = jnp.dot(
-        weighted, w_ref[...].astype(jnp.float32), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        dh_ref[...] = contrib
-
-    @pl.when(pl.program_id(1) != 0)
-    def _accumulate():
-        dh_ref[...] += contrib
+        pw_ref[...] = acc_ref[...] / s_ref[...]
 
 
 def _dw_kernel(num_valid_ref, h_ref, w_ref, g_ref, lse_ref, dw_ref):
-    """dW[j] = sum_i (g * softmax_block)ᵀ @ h_i — inner row axis accumulates.
+    """dW[j] = sum_i softmax_blockᵀ @ (g_i · h_i) — inner row axis accumulates.
 
     Grid is (items, rows): program_id(0) is the item tile, program_id(1) the
-    row tile, so the column offset uses program_id(0) here.
+    row tile. The row weight scales the [T, E] rows, one pass fewer over the
+    [T, item_tile] block.
     """
     from jax.experimental import pallas as pl
 
     h = h_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    logits = jnp.dot(h, w.T, preferred_element_type=jnp.float32)
-    item_tile = w.shape[0]
-    col = pl.program_id(0) * item_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (1, item_tile), 1
-    )
-    logits = logits + jnp.where(col < num_valid_ref[0], 0.0, _MASK).astype(jnp.float32)
-    weighted = jnp.exp(logits - lse_ref[...]) * g_ref[...].astype(jnp.float32)
-    contrib = jnp.dot(weighted.T, h, preferred_element_type=jnp.float32)
+    logits = _masked_logits(num_valid_ref, h, w_ref[...].astype(jnp.float32), pl.program_id(0))
+    softmax = jnp.exp(logits - lse_ref[...])
+    contrib = jnp.dot(softmax.T, h * g_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -254,10 +242,11 @@ def fused_lse(
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _fused_lse(hidden, table, num_valid, tile, item_tile, interpret):
-    return _run_forward(hidden, table, num_valid, tile, item_tile, interpret)
+    return _run_forward(hidden, table, num_valid, tile, item_tile, interpret)[0]
 
 
 def _run_forward(hidden, table, num_valid, tile, item_tile, interpret):
+    """``lse`` ``[N]`` and ``PW = softmax @ W`` ``[N, E]`` float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -265,69 +254,52 @@ def _run_forward(hidden, table, num_valid, tile, item_tile, interpret):
     hidden_p, table_p, n, n_pad, items_pad, embed, _ = _prepare(
         hidden, table, tile, item_tile
     )
-    grid = (n_pad // tile, items_pad // item_tile)
-    lse = pl.pallas_call(
+    rows = lambda i, j, *_: (i, 0)  # noqa: E731
+    lse, pw = pl.pallas_call(
         _lse_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(n_pad // tile, items_pad // item_tile),
             in_specs=[
-                pl.BlockSpec((tile, embed), lambda i, j, *_: (i, 0)),
+                pl.BlockSpec((tile, embed), rows),
                 pl.BlockSpec((item_tile, embed), lambda i, j, *_: (j, 0)),
             ],
-            out_specs=pl.BlockSpec((tile, 1), lambda i, j, *_: (i, 0)),
+            out_specs=[pl.BlockSpec((tile, 1), rows), pl.BlockSpec((tile, embed), rows)],
             scratch_shapes=[
                 pltpu.VMEM((tile, 1), jnp.float32),
                 pltpu.VMEM((tile, 1), jnp.float32),
+                pltpu.VMEM((tile, embed), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+        out_shape=[
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_pad, embed), jnp.float32),
+        ],
         interpret=interpret,
     )(jnp.reshape(num_valid, (1,)), hidden_p, table_p)
-    return lse[:n, 0]
+    return lse[:n, 0], pw[:n]
 
 
 def _fused_lse_fwd(hidden, table, num_valid, tile, item_tile, interpret):
-    lse = _run_forward(hidden, table, num_valid, tile, item_tile, interpret)
-    return lse, (hidden, table, num_valid, lse)
+    lse, pw = _run_forward(hidden, table, num_valid, tile, item_tile, interpret)
+    return lse, (hidden, table, num_valid, lse, pw)
 
 
 def _fused_lse_bwd(tile, item_tile, interpret, residuals, grad_lse):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    hidden, table, num_valid, lse = residuals
+    hidden, table, num_valid, lse, pw = residuals
+    g = grad_lse.astype(jnp.float32)
     item_tile = _resolve_item_tile(table.shape[0], item_tile, tile, hidden.shape[1])
     hidden_p, table_p, n, n_pad, items_pad, embed, num_rows = _prepare(
         hidden, table, tile, item_tile
     )
-    rows, items = n_pad // tile, items_pad // item_tile
-    g = jnp.pad(grad_lse.astype(jnp.float32), (0, n_pad - n)).reshape(n_pad, 1)
-    lse_p = jnp.pad(lse, (0, n_pad - n)).reshape(n_pad, 1)
-    scalar = jnp.reshape(num_valid, (1,))
-
-    dh = pl.pallas_call(
-        _dh_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(rows, items),
-            in_specs=[
-                pl.BlockSpec((tile, embed), lambda i, j, *_: (i, 0)),
-                pl.BlockSpec((item_tile, embed), lambda i, j, *_: (j, 0)),
-                pl.BlockSpec((tile, 1), lambda i, j, *_: (i, 0)),
-                pl.BlockSpec((tile, 1), lambda i, j, *_: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec((tile, embed), lambda i, j, *_: (i, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_pad, embed), jnp.float32),
-        interpret=interpret,
-    )(scalar, hidden_p, table_p, g, lse_p)
-
     dw = pl.pallas_call(
         _dw_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(items, rows),
+            grid=(items_pad // item_tile, n_pad // tile),
             in_specs=[
                 pl.BlockSpec((tile, embed), lambda j, i, *_: (i, 0)),
                 pl.BlockSpec((item_tile, embed), lambda j, i, *_: (j, 0)),
@@ -338,10 +310,16 @@ def _fused_lse_bwd(tile, item_tile, interpret, residuals, grad_lse):
         ),
         out_shape=jax.ShapeDtypeStruct((items_pad, embed), jnp.float32),
         interpret=interpret,
-    )(scalar, hidden_p, table_p, g, lse_p)
+    )(
+        jnp.reshape(num_valid, (1,)),
+        hidden_p,
+        table_p,
+        jnp.pad(g, (0, n_pad - n)).reshape(n_pad, 1),
+        jnp.pad(lse, (0, n_pad - n)).reshape(n_pad, 1),
+    )
 
     return (
-        dh[:n].astype(hidden.dtype),
+        (g[:, None] * pw).astype(hidden.dtype),
         dw[:num_rows].astype(table.dtype),
         # num_valid is an int scalar: its cotangent is the symbolic float0 zero
         np.zeros(np.shape(num_valid), jax.dtypes.float0),

@@ -1,5 +1,7 @@
 """Pallas fused catalog logsumexp == plain jnp (interpret mode on CPU)."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -26,20 +28,118 @@ def test_forward_matches_logsumexp(data):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
-def test_gradients_match(data):
-    h, w = data
-    g = jnp.asarray(np.random.default_rng(1).standard_normal(h.shape[0]), jnp.float32)
+# each case reaches one corner of the two-sweep schedule (PW from the forward's
+# accumulator, the row weight on the dW kernel's rows); unnamed keys take BASE's
+BASE = dict(n=64, items=1000, embed=32, tile=64, item_tile=256, dtype=jnp.float32,
+            zero_rows=False, rising=False, num_valid=None, masked_shard=False)
+GRAD_CASES = {
+    "normal": dict(n=300, embed=64, tile=128, item_tile=None),
+    "zero-cotangents": dict(n=300, embed=64, tile=128, item_tile=None, zero_rows=True),
+    "padded-rows": dict(n=13, items=200, embed=16, tile=8, item_tile=128),
+    "ragged-catalog": {},
+    # every catalog tile's logits outgrow the last: the running max moves at each
+    # tile, and with it the rescale of the PW accumulator
+    "rising-logits": dict(item_tile=128, rising=True),
+    "bf16": dict(n=300, embed=64, tile=128, dtype=jnp.bfloat16),
+    "num-valid-tail": dict(num_valid=700),
+    "fully-masked-shard": dict(zero_rows=True, masked_shard=True),
+}
 
-    def ref(h, w):
-        return jnp.sum(jax.nn.logsumexp(h @ w.T, axis=-1) * g)
 
-    def fused(h, w):
-        return jnp.sum(fused_lse(h, w, 128, None, True) * g)
+@pytest.mark.parametrize("case", list(GRAD_CASES.values()), ids=list(GRAD_CASES))
+def test_gradients_match(case):
+    """dh and dW of ``sum(g · lse)`` against ``jax.grad`` of the plain logsumexp,
+    under a cotangent that is not uniform over the rows."""
+    c = {**BASE, **case}
+    n, items, dtype, tile, item_tile = c["n"], c["items"], c["dtype"], c["tile"], c["item_tile"]
+    rng = np.random.default_rng(1)
+    h = jnp.asarray(rng.standard_normal((n, c["embed"])), dtype)
+    w = rng.standard_normal((items, c["embed"]))
+    if c["rising"]:
+        w *= 0.25 * (1 + np.arange(items)[:, None] // item_tile)
+    w = jnp.asarray(w, dtype)
+    shard = jnp.asarray(rng.standard_normal((items // 2, c["embed"])), dtype)
+    g = rng.standard_normal(n)
+    if c["zero_rows"]:
+        g[::3] = 0.0
+    g = jnp.asarray(g, jnp.float32)
+    valid = items if c["num_valid"] is None else c["num_valid"]
 
-    ref_dh, ref_dw = jax.grad(ref, argnums=(0, 1))(h, w)
-    got_dh, got_dw = jax.grad(fused, argnums=(0, 1))(h, w)
-    np.testing.assert_allclose(np.asarray(got_dh), np.asarray(ref_dh), rtol=2e-4, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(got_dw), np.asarray(ref_dw), rtol=2e-4, atol=2e-5)
+    def ref(h, w, shard):
+        logits = h.astype(jnp.float32) @ w.astype(jnp.float32)[:valid].T
+        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) * g)
+
+    def empty_shard_lse(h, shard):
+        return fused_lse(h, shard, tile, item_tile, True, num_valid=0)
+
+    def fused(h, w, shard):
+        lse = fused_lse(h, w, tile, item_tile, True, num_valid=valid)
+        if c["masked_shard"]:
+            # the sharded wrapper's combine beside a shard that is all padding:
+            # its softmax share, the cotangent it gets, is 0
+            lse = jax.nn.logsumexp(jnp.stack([lse, empty_shard_lse(h, shard)]), axis=0)
+        return jnp.sum(lse * g)
+
+    ref_dh, ref_dw, _ = jax.jit(jax.grad(ref, argnums=(0, 1, 2)))(h, w, shard)
+    got_dh, got_dw, got_dshard = jax.jit(jax.grad(fused, argnums=(0, 1, 2)))(h, w, shard)
+    assert got_dh.dtype == dtype and got_dw.dtype == dtype
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == jnp.float32 else dict(rtol=2e-2, atol=2e-3)
+    np.testing.assert_allclose(np.asarray(got_dh, np.float32), np.asarray(ref_dh, np.float32), **tol)
+    np.testing.assert_allclose(np.asarray(got_dw, np.float32), np.asarray(ref_dw, np.float32), **tol)
+    assert not np.asarray(got_dw[valid:]).any()  # masked rows get exactly nothing
+    assert not np.asarray(got_dh)[np.asarray(g) == 0].any()
+    if c["masked_shard"]:
+        assert np.isfinite(np.asarray(jax.jit(empty_shard_lse)(h, shard))).all()
+        assert not np.asarray(got_dshard).any()
+
+
+def test_undifferentiated_lse_keeps_its_bits():
+    """The forward that also writes PW keeps the max/sum recurrence of the
+    single-output kernel it replaced: in interpret mode its ``lse`` bits are the
+    ones that kernel gave (values pinned from it), called alone or under a vjp.
+    (On the chip Mosaic may round the last bit otherwise: PERF.md, Findings.)"""
+    rng = np.random.default_rng(0)
+    # small dyadic values: every product and sum of the logits is exact, so only
+    # the kernel's exp/log recurrence sets the bits; later catalog tiles are larger
+    h = rng.integers(-3, 4, (12, 16)).astype(np.float32) / 4
+    w = rng.integers(-3, 4, (300, 16)).astype(np.float32) / 4 * (1 + np.arange(300)[:, None] // 128)
+    h, w = jnp.asarray(h), jnp.asarray(w)
+    pinned = [
+        0x40E7B00A, 0x40FF47CC, 0x410B671B, 0x40F24AE6, 0x40DE4CF0, 0x40D4EA6C,
+        0x4100A7D8, 0x4103A513, 0x4126BC27, 0x40E7E61C, 0x40FC479B, 0x4123BCF3,
+    ]
+    lse = jax.jit(lambda h, w: fused_lse(h, w, 8, 128, True))(h, w)
+    primal, _ = jax.jit(lambda h, w: jax.vjp(lambda h: fused_lse(h, w, 8, 128, True), h))(h, w)
+    assert np.asarray(lse).view(np.uint32).tolist() == pinned
+    assert np.asarray(primal).view(np.uint32).tolist() == pinned
+
+
+def pallas_calls(jaxpr):
+    """The ``pallas_call`` equations of ``jaxpr``, nested programs included."""
+    found = sum(eqn.primitive.name == "pallas_call" for eqn in jaxpr.eqns)
+    for eqn in jaxpr.eqns:
+        found += sum(pallas_calls(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+    return found
+
+
+@pytest.mark.parametrize("route", ["fused", "sharded"])
+def test_gradient_sweeps_the_catalog_twice(route):
+    """The value and both gradients take two kernels: the forward that writes lse
+    and PW, and dW; dh is an elementwise op on PW. An evaluation forward takes one."""
+    from jax.sharding import Mesh
+
+    from replay_tpu.parallel import sharded_fused_lse
+
+    h = jax.ShapeDtypeStruct((32, 16), jnp.float32)
+    w = jax.ShapeDtypeStruct((37, 16), jnp.float32)
+    if route == "fused":
+        lse = partial(fused_lse, tile=8, interpret=True)
+    else:
+        mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("data", "model"))
+        lse = partial(sharded_fused_lse, mesh=mesh, tile=8, interpret=True)
+    grads = jax.grad(lambda h, w: lse(h, w).sum(), (0, 1))
+    assert pallas_calls(jax.make_jaxpr(grads)(h, w).jaxpr) == 2
+    assert pallas_calls(jax.make_jaxpr(lse)(h, w).jaxpr) == 1
 
 
 def test_bf16_inputs_accumulate_in_f32(data):
@@ -163,6 +263,15 @@ def test_vmem_guard_keeps_small_configs_unchanged():
     assert _resolve_item_tile(1000, None, 128, 64) == 1024  # lane-padded catalog
     assert _resolve_item_tile(27278, None, 256, 64) == 4096  # the default tile
     assert _resolve_item_tile(1000, 256, 128, 64) == 256  # explicit, in budget
+
+
+@pytest.mark.parametrize("embed, item_tile", [(64, 4096), (128, 2048), (192, 2048), (256, 2048), (300, 1024)])
+def test_vmem_guard_tile_by_width(embed, item_tile):
+    """The catalog tile CE's head gets at 512 rows a program on ML-20M's 27,278
+    items, at each width of the width table (``nn/loss/ce.py``)."""
+    from replay_tpu.ops.fused_ce import _resolve_item_tile
+
+    assert _resolve_item_tile(27278, None, 512, embed) == item_tile
 
 
 def test_vmem_guard_shrinks_explicit_item_tile(caplog):
