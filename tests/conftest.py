@@ -2,6 +2,12 @@
 
 Mirrors the reference's trick of faking torch.distributed (SURVEY.md §4): the multi-chip
 sharding paths are validated on a host-only mesh, no TPUs required.
+
+The suite spends most of its time in XLA compiling some thousands of small CPU
+programs, and little running them: LLVM's optimisation of the code XLA emits
+(``--xla_backend_optimization_level``) is left at 0, which takes about a tenth off
+that compile time and leaves every result the tests read within what they hold
+it to, bit-for-bit parities included.
 """
 
 import os
@@ -9,7 +15,10 @@ import os
 os.environ["JAX_PLATFORMS"] = "cpu"
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
-    os.environ["XLA_FLAGS"] = (xla_flags + " --xla_force_host_platform_device_count=8").strip()
+    xla_flags += " --xla_force_host_platform_device_count=8"
+if "xla_backend_optimization_level" not in xla_flags:
+    xla_flags += " --xla_backend_optimization_level=0"
+os.environ["XLA_FLAGS"] = xla_flags.strip()
 
 import numpy as np  # noqa: E402
 import pandas as pd  # noqa: E402
@@ -72,12 +81,29 @@ def pytest_collection_modifyitems(config, items):
 
 
 @pytest.fixture(scope="session", autouse=True)
+def shared_bytecode_cache(tmp_path_factory):
+    """Python's bytecode cache, in a directory of this session's own, for the
+    processes the tests start (servers, killed workers, ranks): where the
+    environment forbids writing bytecode, each of them compiles jax, flax and
+    this package from source again, some seconds a process. With a shared
+    prefix the first child writes the bytecode and the others read it; nothing
+    is written beside the sources."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("PYTHONPYCACHEPREFIX", str(tmp_path_factory.mktemp("pycache")))
+        patch.delenv("PYTHONDONTWRITEBYTECODE", raising=False)
+        yield
+
+
+@pytest.fixture(scope="session", autouse=True)
 def shared_compile_cache(tmp_path_factory):
     """JAX's persistent compilation cache, in a directory of this session's own,
     for every test: the suite builds hundreds of trainers of a few tiny models,
     each with jitted closures of its own, so every one traces and lowers the same
     program again; with the cache XLA compiles it once a session and the others
     load it. Tracing still happens, so ``compile_tracker`` counts what it counted.
+    The processes the tests start (a server, a killed worker) are pointed at the
+    same directory through the environment, so the second server of a model
+    loads what the first compiled.
     A test that asserts on the cache's own settings sets and restores its own
     (``tests/ops/test_tpu_compile.py`` turns the cache off around its compiles)."""
     import jax
@@ -90,6 +116,11 @@ def shared_compile_cache(tmp_path_factory):
     # JAX looks at the directory once, at the process's first compile: have it
     # look again in case something compiled while the tests were collected
     compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
+        patch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+        patch.setenv("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
+        yield
 
 
 class SharedPrograms:

@@ -53,11 +53,12 @@ MODELS = {
         tie_embeddings=False)),
     "sasrec": (SasRec, dict(embedding_dim=D, num_blocks=2, num_heads=2, max_sequence_length=LENGTH)),
 }
-# sha256 of the parent's lowered text, first 16 hex digits
+# sha256 of the parent's lowered text, first 16 hex digits; the three expert
+# models' since the expert layer's passes walk a rung of its buffer (nn.moe)
 PARENT = {
-    "lfm2": "a83e9ac12dc9fc35",
-    "mellum2": "4467a61b44549670",
-    "moonlight": "7e77c59b2968a60e",
+    "lfm2": "f5ff52c4298a34d0",
+    "mellum2": "73641d6fb51c69d5",
+    "moonlight": "d481180513621dc4",
     "sasrec": "c39c3e3d12c59659",
 }
 

@@ -50,10 +50,10 @@ def test_matches_reference(batch, heads, length, dim, block, causal):
     v = jnp.asarray(rng.normal(size=(batch, heads, length, dim)).astype(np.float32))
     lengths = rng.integers(1, length + 1, batch)
     padding_mask = jnp.asarray(np.arange(length)[None, :] < lengths[:, None])
-    got = flash_attention_tiled(
+    got = jax.jit(flash_attention_tiled, static_argnums=(4, 5, 6, 7))(
         q, k, v, padding_mask_bias(padding_mask), causal, block, block, True
     )
-    want = reference(q, k, v, padding_mask, causal)
+    want = jax.jit(reference, static_argnums=4)(q, k, v, padding_mask, causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
@@ -91,8 +91,8 @@ def test_gradients_match_reference(causal):
         return jnp.sum(out**2)
 
     # dbias included: the kv_bias cotangent is part of the custom VJP
-    got = jax.grad(tiled_loss, argnums=(0, 1, 2, 3))(q, k, v, bias)
-    want = jax.grad(ref_loss, argnums=(0, 1, 2, 3))(q, k, v, bias)
+    got = jax.jit(jax.grad(tiled_loss, argnums=(0, 1, 2, 3)))(q, k, v, bias)
+    want = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2, 3)))(q, k, v, bias)
     for g, w, name in zip(got, want, ["q", "k", "v", "bias"]):
         np.testing.assert_allclose(
             np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-5, err_msg=name
@@ -139,10 +139,10 @@ def test_model_tiled_route_matches_default(model_kind):
     for b, n in enumerate(lengths):
         ids[b, seq_len - n:] = rng.integers(0, num_items, n)
     mask = ids != num_items
-    params = plain.init(jax.random.PRNGKey(0), {"item_id": ids}, mask)["params"]
+    params = jax.jit(plain.init)(jax.random.PRNGKey(0), {"item_id": ids}, mask)["params"]
 
-    want = plain.apply({"params": params}, {"item_id": ids}, mask)
-    got = tiled.apply({"params": params}, {"item_id": ids}, mask)
+    want = jax.jit(plain.apply)({"params": params}, {"item_id": ids}, mask)
+    got = jax.jit(tiled.apply)({"params": params}, {"item_id": ids}, mask)
     # padded rows differ only by the diagonal-rescue convention and are zeroed
     # by the keep-mask between blocks; real rows must match
     np.testing.assert_allclose(
@@ -235,6 +235,18 @@ def banded_inputs(length, heads=4, kv_heads=2, dim=8, batch=2, padded=True, seed
     return draw(heads), draw(kv_heads), draw(kv_heads), mask
 
 
+def output_and_grads(attention, weight, *args):
+    """``attention(*args)`` and the gradients of ``sum(attention(*args) * weight)``
+    to every argument, as one program."""
+
+    @jax.jit
+    def run(weight, *args):
+        out, pull = jax.vjp(attention, *args)
+        return out, pull(weight)
+
+    return run(weight, *args)
+
+
 # (length, block, window): smaller than / equal to / no multiple of the block,
 # window >= L (the causal route), L no multiple of the block, no window at all
 BANDS = [(32, 8, 3), (32, 8, 8), (32, 8, 13), (32, 8, 32), (32, 8, 100), (29, 8, 11),
@@ -250,15 +262,10 @@ def test_grouped_banded_route_matches_the_plain_banded_softmax(length, block, wi
         return flash_attention_tiled(q, k, v, bias, True, block, block, True, window)
 
     keep = np.asarray(mask)[:, None, :, None]  # padded query rows are zeroed by the model
-    got = jax.jit(fused)(q, k, v, bias)
-    want = jax.jit(partial(banded_reference, window=window))(q, k, v, bias)
-    np.testing.assert_allclose(got * keep, want * keep, rtol=2e-5, atol=2e-5)
-
     weight = jnp.asarray(np.random.default_rng(4).normal(size=q.shape).astype(np.float32)) * keep
-    grads = jax.jit(jax.grad(lambda *a: jnp.sum(fused(*a) * weight), argnums=(0, 1, 2, 3)))(q, k, v, bias)
-    wanted = jax.jit(jax.grad(lambda *a: jnp.sum(banded_reference(*a, window) * weight), argnums=(0, 1, 2, 3)))(
-        q, k, v, bias
-    )
+    got, grads = output_and_grads(fused, weight, q, k, v, bias)
+    want, wanted = output_and_grads(partial(banded_reference, window=window), weight, q, k, v, bias)
+    np.testing.assert_allclose(got * keep, want * keep, rtol=2e-5, atol=2e-5)
     for g, w, name in zip(grads, wanted, "qkvb"):
         assert g.shape == w.shape
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
@@ -289,11 +296,14 @@ def test_blocks_outside_the_band_are_never_multiplied(window):
     q, k, v, mask = banded_inputs(length, padded=False)
     bias = padding_mask_bias(mask)
 
+    @jax.jit
     def fused(q, k, v):
         return flash_attention_tiled(q, k, v, bias, True, block, block, True, window)
 
+    # the query gradient of the loss that reads the query rows ``only`` weighs
+    dq_of = jax.jit(jax.grad(lambda q, k, v, only: jnp.sum(fused(q, k, v) ** 2 * only)))
     clean = fused(q, k, v)
-    clean_dq = jax.grad(lambda q: jnp.sum(fused(q, k, v) ** 2))(q)
+    clean_dq = dq_of(q, k, v, jnp.ones_like(q))
     first, last = kv_block_range(np.arange(length // block), block, block, length // block, True, window, np)
     for i in (0, 2, 5):
         outside = np.ones(length, bool)
@@ -303,7 +313,7 @@ def test_blocks_outside_the_band_are_never_multiplied(window):
         out = fused(q, poison(k), poison(v))
         np.testing.assert_array_equal(np.asarray(out[:, :, rows]), np.asarray(clean[:, :, rows]))
         only = jnp.zeros_like(q).at[:, :, rows].set(1.0)  # the loss reads this query block alone
-        dq = jax.grad(lambda q: jnp.sum(fused(q, poison(k), poison(v)) ** 2 * only))(q)
+        dq = dq_of(q, poison(k), poison(v), only)
         np.testing.assert_allclose(dq[:, :, rows], clean_dq[:, :, rows], rtol=1e-5, atol=1e-6)
     if window is not None:
         assert (last - first + 1).max() <= -(-(window - 1) // block) + 1 < length // block
@@ -374,16 +384,12 @@ def test_value_width_apart_from_key_width_matches_the_dense_formula(
         return flash_attention_tiled(q, k, v, bias, True, block, block, True, window)
 
     keep = np.asarray(mask)[:, None, :, None]
-    got = jax.jit(fused)(q, k, v, bias)
+    weight = jnp.asarray(np.random.default_rng(4).normal(size=(*q.shape[:-1], value_dim)).astype(np.float32))
+    weight = weight * keep
+    got, grads = output_and_grads(fused, weight, q, k, v, bias)
     assert got.shape == (*q.shape[:-1], value_dim)
-    want = jax.jit(partial(banded_reference, window=window))(q, k, v, bias)
+    want, wanted = output_and_grads(partial(banded_reference, window=window), weight, q, k, v, bias)
     np.testing.assert_allclose(got * keep, want * keep, rtol=2e-5, atol=2e-5)
-
-    weight = jnp.asarray(np.random.default_rng(4).normal(size=got.shape).astype(np.float32)) * keep
-    grads = jax.jit(jax.grad(lambda *a: jnp.sum(fused(*a) * weight), argnums=(0, 1, 2, 3)))(q, k, v, bias)
-    wanted = jax.jit(jax.grad(lambda *a: jnp.sum(banded_reference(*a, window) * weight), argnums=(0, 1, 2, 3)))(
-        q, k, v, bias
-    )
     for g, w, name in zip(grads, wanted, "qkvb"):
         assert g.shape == w.shape
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
@@ -397,14 +403,14 @@ def test_narrow_values_equal_the_equal_width_route_on_zero_padded_values(window)
     q, k, v, mask = narrow_value_inputs(32, 12, 8, 4, 2)
     bias = padding_mask_bias(mask)
     padded = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, 4)))
-    route = lambda values: flash_attention_tiled(q, k, values, bias, True, 8, 8, True, window)  # noqa: E731
-    np.testing.assert_array_equal(np.asarray(route(v)), np.asarray(route(padded))[..., :8])
+    route = jax.jit(lambda q, k, values: flash_attention_tiled(q, k, values, bias, True, 8, 8, True, window))
     weight = jnp.asarray(np.random.default_rng(6).normal(size=(*q.shape[:-1], 8)).astype(np.float32))
-    narrow = jax.grad(lambda q, k, v: jnp.sum(
-        flash_attention_tiled(q, k, v, bias, True, 8, 8, True, window) * weight), argnums=(0, 1, 2))(q, k, v)
-    wide = jax.grad(lambda q, k, v: jnp.sum(
-        flash_attention_tiled(q, k, v, bias, True, 8, 8, True, window)[..., :8] * weight), argnums=(0, 1, 2))(
-        q, k, padded)
+    # each width's gradients of the loss that reads the first 8 columns of the output
+    gradients = jax.jit(jax.grad(
+        lambda q, k, values: jnp.sum(route(q, k, values)[..., :8] * weight), argnums=(0, 1, 2)
+    ))
+    narrow, wide = gradients(q, k, v), gradients(q, k, padded)
+    np.testing.assert_array_equal(np.asarray(route(q, k, v)), np.asarray(route(q, k, padded))[..., :8])
     for g, w in zip(narrow, (wide[0], wide[1], wide[2][..., :8])):
         np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
     with pytest.raises(ValueError, match="contract over one width"):

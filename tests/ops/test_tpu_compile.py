@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from replay_tpu.nn.attention import dot_product_attention
-from replay_tpu.nn.moe import grouped_matmul
+from replay_tpu.nn.moe import SparseExperts, grouped_matmul
 from replay_tpu.ops.flash_attention import flash_attention
 from replay_tpu.ops.flash_tiled import flash_attention_tiled
 from replay_tpu.ops.fused_ce import fused_lse
@@ -141,6 +141,30 @@ def test_grouped_matmul_lowers_forward_and_backward(one_chip, rows, inner, cols)
     # the gradient's program holds the forward kernel and both backward ones
     compiled = partial(grouped_matmul, interpret=False)  # as on the chip: here the CPU would interpret
     assert_mosaic(fwd_or_grad(compiled, True, (0, 1)), lhs, rhs, sizes)
+
+
+def test_the_expert_layer_lowers_on_every_rung_at_the_published_widths(one_chip, monkeypatch):
+    """lfm2's sparse layer (d 2048, expert width 1536, 8 held of 64, top 4) on 2,048
+    tokens, forward and backward: four rungs of 512 to 8,192 rows, each pass's
+    switch, the unwritten rows past a rung, the products outside the switches."""
+    import importlib
+
+    kernels = importlib.import_module("replay_tpu.ops.flash_attention")
+    monkeypatch.setattr(kernels, "pallas_interpret", lambda: False)  # as on the chip
+    layer = SparseExperts(num_experts=64, experts_held=8, expert_offset=0, top_k=4,
+                          hidden_dim=1536, dtype=bf16)
+    x = jax.ShapeDtypeStruct((1, 2048, 2048), bf16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, 2048), jnp.bool_, sharding=one_chip)
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x, mask)["params"]
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+
+    def loss(p, x, m):
+        out, _ = layer.apply({"params": p}, x, m, mutable=["counters"])
+        return jnp.sum(out.astype(f32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x, mask).compile().as_text()
+    assert text.count("tpu_custom_call") >= 9  # three products, two kernels back for each
+    assert "conditional(" in text
 
 
 def test_flash_single_block_lowers_at_its_bound(one_chip):
